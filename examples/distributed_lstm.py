@@ -14,7 +14,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from machine_learning_apache_spark_tpu import Session
 from machine_learning_apache_spark_tpu.launcher import Distributor
-from _common import dist_platform
 
 spark = (
     Session.builder.appName("DistributedLSTM")
@@ -23,7 +22,7 @@ spark = (
 )
 
 out = Distributor(
-    num_processes=spark.conf.executor_instances, local_mode=True, platform=dist_platform()
+    num_processes=spark.conf.executor_instances, local_mode=True
 ).run(
     "machine_learning_apache_spark_tpu.recipes.lstm:train_lstm",
     data_root=sys.argv[2] if len(sys.argv) > 2 else None,

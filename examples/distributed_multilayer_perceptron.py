@@ -16,7 +16,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from machine_learning_apache_spark_tpu import Session
 from machine_learning_apache_spark_tpu.launcher import Distributor
-from _common import dist_platform
 
 spark = (
     Session.builder.appName("DistributedMLP")
@@ -26,7 +25,7 @@ spark = (
 executors_n = spark.conf.executor_instances
 
 distributor = Distributor(
-    num_processes=executors_n, local_mode=True, platform=dist_platform()
+    num_processes=executors_n, local_mode=True
 )
 out = distributor.run(
     "machine_learning_apache_spark_tpu.recipes.mlp:train_mlp",

@@ -83,14 +83,6 @@ class SessionConfig(ConfigBase):
     process_id: int = -1  # RANK analogue; -1 = derive
     num_processes: int = 0  # WORLD_SIZE analogue; 0 = derive
     platform: str = ""  # "", "tpu", "cpu" — "" lets JAX pick
-    # Persistent XLA compilation cache directory: compiles are written
-    # keyed by program+backend fingerprint and reused by later processes
-    # (utils.compilation_cache) — the startup-latency lever for repeat
-    # runs, worth 20-60s/program on remote-controller topologies. "" means
-    # "don't enable here" — it does NOT tear down a cache another session
-    # already enabled in this process (process-global JAX config); use
-    # utils.compilation_cache.disable_compilation_cache for that.
-    compilation_cache_dir: str = ""
 
 
 @dataclass

@@ -254,11 +254,18 @@ class Translator:
     ):
         import flax.linen as nn
 
+        from machine_learning_apache_spark_tpu.parallel.mesh import (
+            on_one_device,
+        )
+
         self.model = model
         # Plain-array params: a mesh-less training run leaves the Flax
         # Partitioned boxes on (shard_state strips them only under a mesh),
-        # and boxed trees neither apply nor serialize uniformly.
-        self.params = nn.unbox(params)
+        # and boxed trees neither apply nor serialize uniformly. On one
+        # device: decoding is a one-device program, and params left
+        # replicated over a training mesh would run it on every chip —
+        # which its Pallas kernels cannot do without a mesh to shard over.
+        self.params, _ = on_one_device(nn.unbox(params))
         self.src_pipe = src_pipe
         self.trg_pipe = trg_pipe
 

@@ -83,17 +83,9 @@ def initialize_from_env(conf: SessionConfig | None = None) -> RendezvousSpec | N
     spec = RendezvousSpec.from_env(conf)
     if spec is None or _initialized:
         return spec
-    # The CPU backend has no native cross-process collectives ("Multiprocess
-    # computations aren't implemented on the CPU backend") — gloo is its
-    # gloo. Opt in before the backend initializes so CPU gangs (the
-    # reference's local_mode bring-up path AND the fault-drill test gangs)
-    # can run real psums/allgathers; TPU backends ignore the setting.
-    platforms = os.environ.get("JAX_PLATFORMS", jax.config.jax_platforms or "")
-    if "cpu" in str(platforms).split(","):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - older/newer jax: name moved/absent
-            pass
+    # CPU gangs (local_mode bring-up, the fault-drill test gangs) run real
+    # cross-process psums/allgathers through gloo, which is
+    # ``jax_cpu_collectives_implementation``'s default on the installed jax.
     jax.distributed.initialize(
         coordinator_address=spec.coordinator_address,
         num_processes=spec.num_processes,

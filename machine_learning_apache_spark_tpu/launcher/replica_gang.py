@@ -41,6 +41,7 @@ from machine_learning_apache_spark_tpu.launcher.distributor import (
     _register_gang,
     _unregister_gang,
     fn_reference,
+    tpu_pinning_env,
 )
 from machine_learning_apache_spark_tpu.launcher.monitor import (
     _signal_proc,
@@ -213,6 +214,10 @@ class ReplicaGang:
         if self.platform:
             env["JAX_PLATFORMS"] = self.platform
             env["MLSPARK_PLATFORM"] = self.platform
+        # Replica k serves from chip k alone (visibility only — replicas
+        # run no collectives). This supervisor never touches a JAX
+        # backend, so the chips are free for the children.
+        env.update(tpu_pinning_env(rank, env.get("JAX_PLATFORMS", "")))
         env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
         cmd = [
             sys.executable,

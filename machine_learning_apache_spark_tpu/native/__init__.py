@@ -10,14 +10,19 @@ chain on the AG_NEWS-format corpus, exact-parity-tested).
 
 Build model: compiled on demand with ``g++ -O3 -shared -fPIC`` into a cached
 shared library next to the sources (atomic rename, safe under multi-process
-gangs). No pybind11 — plain C ABI + ctypes (the image has no pybind11; see
-build contract). Everything degrades gracefully: callers catch ImportError
-and fall back to the pure-Python paths.
+gangs). The library's file name carries a hash of the sources' content, so
+a binary is only ever loaded for the sources it was built from — a copied
+tree (mtimes rewritten) or an edited source cannot pick up a stale one. No
+pybind11 — plain C ABI + ctypes (the image has no pybind11; see build
+contract). Everything degrades gracefully: callers catch ImportError and
+fall back to the pure-Python paths; ``status()`` says which happened.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -27,20 +32,21 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ("libsvm_parser.cpp", "batch_gather.cpp", "text_encode.cpp")
-_SO_NAME = "_mlspark_native.so"
+_SO_PREFIX = "_mlspark_native_"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _build_error: Exception | None = None
+_status = "unloaded"
 
 
-def _needs_build(so_path: str) -> bool:
-    if not os.path.exists(so_path):
-        return True
-    so_mtime = os.path.getmtime(so_path)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, s)) > so_mtime for s in _SOURCES
-    )
+def _so_path() -> str:
+    """``_mlspark_native_<hash of the sources' bytes>.so``."""
+    digest = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_DIR, name), "rb") as f:
+            digest.update(f.read())
+    return os.path.join(_DIR, f"{_SO_PREFIX}{digest.hexdigest()[:16]}.so")
 
 
 def _build(so_path: str) -> None:
@@ -59,6 +65,9 @@ def _build(so_path: str) -> None:
             cmd, check=True, capture_output=True, text=True, timeout=300
         )
         os.replace(tmp, so_path)
+        for stale in glob.glob(os.path.join(_DIR, f"{_SO_PREFIX}*.so")):
+            if stale != so_path:
+                os.unlink(stale)  # built from sources that no longer exist
     except (subprocess.SubprocessError, OSError) as e:
         # covers compile errors, timeouts, and a missing g++ alike
         detail = getattr(e, "stderr", "") or str(e)
@@ -69,21 +78,25 @@ def _build(so_path: str) -> None:
 
 
 def _load() -> ctypes.CDLL:
-    """Build (if stale) and load the shared library, memoized."""
-    global _lib, _build_error
+    """Build (unless a library for these sources exists) and load the
+    shared library, memoized."""
+    global _lib, _build_error, _status
     with _lock:
         if _lib is not None:
             return _lib
         if _build_error is not None:
             raise ImportError("native library unavailable") from _build_error
-        so_path = os.path.join(_DIR, _SO_NAME)
         try:
-            if _needs_build(so_path):
+            so_path = _so_path()
+            built = not os.path.exists(so_path)
+            if built:
                 _build(so_path)
             lib = ctypes.CDLL(so_path)
         except (ImportError, OSError) as e:
             _build_error = e
+            _status = "python-fallback"
             raise ImportError("native library unavailable") from e
+        _status = "built" if built else "loaded"
 
         lib.mlspark_libsvm_parse.restype = ctypes.c_void_p
         lib.mlspark_libsvm_parse.argtypes = [
@@ -131,6 +144,15 @@ def available() -> bool:
         return True
     except ImportError:
         return False
+
+
+def status() -> str:
+    """How this process got its native code: ``"built"`` (compiled here),
+    ``"loaded"`` (a library for these exact sources already existed) or
+    ``"python-fallback"`` (build or load failed; callers use the Python
+    paths). Loads on first call."""
+    available()
+    return _status
 
 
 class libsvm_native:

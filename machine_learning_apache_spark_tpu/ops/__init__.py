@@ -22,6 +22,7 @@ from machine_learning_apache_spark_tpu.ops.masks import (
 from machine_learning_apache_spark_tpu.ops.positional import sinusoidal_encoding
 from machine_learning_apache_spark_tpu.ops.attention import (
     attention_impl,
+    kernel_mesh,
     dot_product_attention,
     ragged_paged_attention,
     scaled_dot_product_attention,
@@ -31,6 +32,7 @@ from machine_learning_apache_spark_tpu.ops.attention import (
 
 __all__ = [
     "attention_impl",
+    "kernel_mesh",
     "dot_product_attention",
     "ragged_paged_attention",
     "make_causal_mask",

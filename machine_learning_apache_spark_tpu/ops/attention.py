@@ -28,7 +28,21 @@ import contextlib
 import jax
 import jax.numpy as jnp
 
+from machine_learning_apache_spark_tpu import telemetry
+
 NEG_INF = -1e30  # finite -inf stand-in: keeps fully-masked rows NaN-free
+
+
+def record_dispatch(site: str, impl: str, reason: str, **shape) -> None:
+    """Trace-time breadcrumb: which implementation an attention site
+    compiled to, and why. The dispatch gates below choose silently; this
+    runs in the Python body under ``jit``, so it leaves one
+    ``ops.attention_dispatch`` annotation per site per traced program
+    (never per call) for a bring-up or a flight dump to read."""
+    telemetry.annotate(
+        "ops.attention_dispatch", site=site, impl=impl, reason=reason, **shape
+    )
+
 
 # Active sequence-parallel context (a stack so contexts nest): while set,
 # ``dot_product_attention`` routes self-attention through the ppermute ring
@@ -76,6 +90,32 @@ def sequence_parallel(
 
 def _active_seq_mesh():
     return _SEQ_PARALLEL_CTX[-1] if _SEQ_PARALLEL_CTX else None
+
+
+# Mesh the surrounding jitted program spans (a stack so contexts nest).
+# A Pallas kernel cannot be partitioned by XLA, so its launcher must know
+# the mesh at trace time to run itself per shard
+# (``ops.pallas_attention._per_shard``).
+_KERNEL_MESH: list = []
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh):
+    """While active, Pallas kernel launches traced under ``jit`` run per
+    shard of ``mesh`` (batch over its data axis, heads over its model
+    axis) instead of as one unpartitionable call. ``fit`` and ``evaluate``
+    enter it for the mesh they are given; code that jits the zoo models
+    over sharded batches itself (``bench.py``) does the same. ``None``
+    (a mesh-less ``fit``) means direct launches."""
+    _KERNEL_MESH.append(mesh)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.pop()
+
+
+def active_kernel_mesh():
+    return _KERNEL_MESH[-1] if _KERNEL_MESH else None
 
 
 # Forced implementation override for ``dot_product_attention``'s auto
@@ -200,13 +240,33 @@ def ragged_paged_attention(
         raise ValueError("k_scale and v_scale must be given together")
     if use_pallas is None:
         # int8 pages tile at (32, 128) on TPU, fp32 at (8, 128) — the
-        # page_size divisibility gate follows the store dtype.
+        # page_size divisibility gate follows the store dtype. bfloat16
+        # pages (the chip's store: the model dtype) share the 8 gate:
+        # Mosaic compiled 8-slot bf16 pages and agreed with the gather
+        # path on a v5e (PERF.md "Bring-up").
         min_sublanes = 32 if k_pages.dtype == jnp.int8 else 8
-        use_pallas = (
-            jax.default_backend() == "tpu"
-            and head_dim % 128 == 0
-            and page_size % min_sublanes == 0
-        )
+        if jax.default_backend() != "tpu":
+            reason = f"backend {jax.default_backend()}"
+        elif head_dim % 128:
+            reason = f"head_dim {head_dim} % 128 != 0"
+        elif page_size % min_sublanes:
+            reason = (
+                f"page_size {page_size} % {min_sublanes} != 0 "
+                f"({k_pages.dtype} pages)"
+            )
+        else:
+            reason = ""
+        use_pallas = not reason
+        reason = reason or "tpu, lane-aligned heads, tile-aligned pages"
+    else:
+        reason = "caller-selected"
+    record_dispatch(
+        "ragged_paged_decode",
+        "pallas_ragged_paged" if use_pallas else "xla_gather",
+        reason,
+        rows=num_rows, heads=num_heads, head_dim=head_dim,
+        page_size=page_size, store=str(k_pages.dtype),
+    )
     if use_pallas:
         from machine_learning_apache_spark_tpu.ops.pallas_attention import (
             ragged_paged_attention_kernel,
@@ -309,6 +369,10 @@ def dot_product_attention(
                 ulysses_attention,
             )
 
+            record_dispatch(
+                "dot_product", "ulysses", "sequence_parallel context",
+                q=query.shape, kv_len=key.shape[2],
+            )
             return ulysses_attention(
                 query, key, value, mesh,
                 causal=causal, kv_valid=kv_valid,
@@ -318,17 +382,31 @@ def dot_product_attention(
             ring_attention,
         )
 
+        record_dispatch(
+            "dot_product", "ring", "sequence_parallel context",
+            q=query.shape, kv_len=key.shape[2],
+        )
         return ring_attention(
             query, key, value, mesh,
             causal=causal, kv_valid=kv_valid,
             seq_axis=seq_axis, batch_axis=batch_axis,
         )
-    if use_pallas is None:
-        if _FORCED_IMPL:
-            use_pallas = _FORCED_IMPL[-1] == "flash" and mask is None
-        else:
-            use_pallas = jax.default_backend() == "tpu" and mask is None
-    if use_pallas and mask is None:
+    if mask is not None:
+        reason = "dense mask"
+    elif use_pallas is not None:
+        reason = "caller-selected"
+    elif _FORCED_IMPL:
+        reason = f"attention_impl({_FORCED_IMPL[-1]!r})"
+        use_pallas = _FORCED_IMPL[-1] == "flash"
+    else:
+        reason = f"structured mask, backend {jax.default_backend()}"
+        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = bool(use_pallas) and mask is None
+    record_dispatch(
+        "dot_product", "pallas_flash" if use_pallas else "xla_dense", reason,
+        q=query.shape, kv_len=key.shape[2],
+    )
+    if use_pallas:
         from machine_learning_apache_spark_tpu.ops.pallas_attention import (
             flash_attention,
         )

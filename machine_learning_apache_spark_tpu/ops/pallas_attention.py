@@ -29,10 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-from machine_learning_apache_spark_tpu.utils.jax_compat import (
-    pallas_tpu_compiler_params,
-)
 
 NEG_INF = -1e30
 
@@ -129,7 +127,15 @@ def _flash_kernel(
             # rows so the backward masks them out entirely.
             lse_ref[0] = jnp.where(
                 l == 0.0, NEG_INF, m_scr[:] + jnp.log(safe_l)
-            )[:, 0]
+            )
+
+
+def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """``out_shape`` entry for a kernel whose result varies over the same
+    manual mesh axes as its operands — inside a ``shard_map`` that checks
+    varying-ness, ``pallas_call`` wants it stated."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
@@ -202,6 +208,24 @@ def _use_pallas_bwd(q_len: int, kv_len: int) -> bool:
     return q_len * kv_len >= PALLAS_BWD_MIN_SCORES
 
 
+def _choose_bwd(q_len: int, kv_len: int) -> bool:
+    """``_use_pallas_bwd`` for the custom_vjp forward rules, which run once
+    per traced differentiated program: records the choice."""
+    from machine_learning_apache_spark_tpu.ops.attention import (
+        record_dispatch,
+    )
+
+    use = _use_pallas_bwd(q_len, kv_len)
+    record_dispatch(
+        "flash_backward",
+        "pallas_flash_bwd" if use else "xla_dense_recompute",
+        f"{q_len}x{kv_len} scores {'>=' if use else '<'} "
+        f"{PALLAS_BWD_MIN_SCORES}",
+        q_len=q_len, kv_len=kv_len,
+    )
+    return use
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _flash_vjp_nomask(cfg, query, key, value):
     return _flash_forward(query, key, value, None, *cfg)
@@ -211,7 +235,7 @@ def _flash_nomask_fwd(cfg, query, key, value):
     # The out/lse residuals are only kept when the pallas backward will read
     # them (shape-static decision); the short-sequence dense fallback keeps
     # the lean (q, k, v) residuals and skips the lse output entirely.
-    if _use_pallas_bwd(query.shape[2], key.shape[2]):
+    if _choose_bwd(query.shape[2], key.shape[2]):
         out, lse = _flash_forward(
             query, key, value, None, *cfg, return_lse=True
         )
@@ -239,7 +263,7 @@ def _flash_vjp_masked(cfg, query, key, value, kv_valid):
 
 
 def _flash_masked_fwd(cfg, query, key, value, kv_valid):
-    if _use_pallas_bwd(query.shape[2], key.shape[2]):
+    if _choose_bwd(query.shape[2], key.shape[2]):
         out, lse = _flash_forward(
             query, key, value, kv_valid, *cfg, return_lse=True
         )
@@ -401,12 +425,79 @@ def _flash_bwd_dkv_kernel(
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+# -- per-shard launch --------------------------------------------------------
+#
+# A Mosaic custom call is opaque to the SPMD partitioner: under ``fit``'s
+# implicit data parallelism (batch sharded over the mesh, XLA does the rest)
+# lowering fails with "Mosaic kernels cannot be automatically partitioned.
+# Please wrap the call in a shard_map". Attention is independent per
+# (batch, head), so under an ``ops.attention.kernel_mesh(mesh)`` context —
+# ``fit``/``evaluate`` enter it for the mesh they were given — the launchers
+# run inside a ``shard_map`` over the mesh's data axis (batch) and model
+# axis (heads): each device launches the kernel on its own
+# [B/n, H/m, S, d] block and nothing is gathered. Without a context the
+# kernel is called directly, which is right for a single-device program
+# and for code already inside a fully-manual ``shard_map``.
+
+
+def _per_shard(local_fn, operands, kinds, out_kinds):
+    """``local_fn(*operands)``, per (batch, head) shard of the active kernel
+    mesh. ``kinds`` name each operand's layout — ``"bhsd"`` ([B, H, S, d]),
+    ``"bs"`` ([B, S]), ``"bhs"`` ([B, H, S]) — or None for an absent one."""
+    from machine_learning_apache_spark_tpu.ops.attention import (
+        active_kernel_mesh,
+    )
+    from machine_learning_apache_spark_tpu.parallel.mesh import (
+        DATA_AXIS,
+        MODEL_AXIS,
+    )
+
+    mesh = active_kernel_mesh()
+    manual = frozenset(jax.sharding.get_abstract_mesh().manual_axes)
+    if mesh is None or not frozenset(mesh.axis_names) - manual:
+        return local_fn(*operands)
+    batch, heads = operands[0].shape[:2]
+
+    def axis_for(name, size):
+        # An axis that does not divide the dim (a ragged eval tail) leaves
+        # it whole: every shard then computes all of it.
+        ok = name in mesh.shape and name not in manual
+        return name if ok and size % mesh.shape[name] == 0 else None
+
+    b, h = axis_for(DATA_AXIS, batch), axis_for(MODEL_AXIS, heads)
+    specs = {
+        "bhsd": P(b, h, None, None), "bs": P(b, None), "bhs": P(b, h, None),
+        None: None,
+    }
+    out_specs = tuple(specs[k] for k in out_kinds)
+    return jax.shard_map(
+        local_fn,
+        mesh=mesh,
+        in_specs=tuple(specs[k] for k in kinds),
+        out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
+        # The kernel's outputs depend on nothing but its operands' shards;
+        # there is no replication for the checker to infer through an
+        # opaque custom call.
+        check_vma=False,
+    )(*operands)
+
+
 def _flash_backward(cfg, query, key, value, kv_valid, out, lse, g):
+    return _per_shard(
+        functools.partial(_flash_backward_local, cfg),
+        (query, key, value, kv_valid, out, lse, g),
+        ("bhsd", "bhsd", "bhsd", None if kv_valid is None else "bs",
+         "bhsd", "bhs", "bhsd"),
+        ("bhsd", "bhsd", "bhsd"),
+    )
+
+
+def _flash_backward_local(cfg, query, key, value, kv_valid, out, lse, g):
     """Blockwise dq/dk/dv (flash-2): two kernel launches, O(S) memory.
 
-    ``lse`` arrives [B*H, q_pad] from the forward (same block clamping, so
-    the padded length matches); ``delta = rowsum(dO ∘ O)`` is a cheap fused
-    XLA reduction computed here, not a kernel.
+    ``lse`` arrives [B, H, q_pad] from the forward (same block clamping,
+    so the padded length matches); ``delta = rowsum(dO ∘ O)`` is a cheap
+    fused XLA reduction computed here, not a kernel.
     """
     causal, block_q, block_k, interpret = cfg
     b, h, q_len, d = query.shape
@@ -436,6 +527,7 @@ def _flash_backward(cfg, query, key, value, kv_valid, out, lse, g):
     # Column ([.., q_pad, 1]) and row ([.., 1, q_pad]) layouts of the per-row
     # statistics: the dq kernel broadcasts them down k columns, the dkv
     # kernel across q columns — Mosaic-friendly 2D blocks either way.
+    lse = lse.reshape(bh, q_pad)
     lse_col, delta_col = lse[:, :, None], delta[:, :, None]
     lse_row, delta_row = lse[:, None, :], delta[:, None, :]
 
@@ -455,7 +547,7 @@ def _flash_backward(cfg, query, key, value, kv_valid, out, lse, g):
         pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0)),
         pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
     ]
-    compiler_params = pallas_tpu_compiler_params(
+    compiler_params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
 
@@ -478,7 +570,7 @@ def _flash_backward(cfg, query, key, value, kv_valid, out, lse, g):
         grid=(bh, num_q_blocks, num_k_blocks),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, q_pad, d_pad), query.dtype),
+        out_shape=_out_struct((bh, q_pad, d_pad), query.dtype, *dq_operands),
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         compiler_params=compiler_params,
         interpret=interpret,
@@ -511,8 +603,8 @@ def _flash_backward(cfg, query, key, value, kv_valid, out, lse, g):
             pl.BlockSpec((1, block_k, d_pad), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, k_pad, d_pad), key.dtype),
-            jax.ShapeDtypeStruct((bh, k_pad, d_pad), value.dtype),
+            _out_struct((bh, k_pad, d_pad), key.dtype, *dkv_operands),
+            _out_struct((bh, k_pad, d_pad), value.dtype, *dkv_operands),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d_pad), jnp.float32),
@@ -538,6 +630,24 @@ def _block_sizes(q_len: int, kv_len: int, block_q: int, block_k: int):
 def _flash_forward(
     query, key, value, kv_valid, causal, block_q, block_k, interpret,
     return_lse: bool = False,
+):
+    def local(query, key, value, kv_valid):
+        return _flash_forward_local(
+            query, key, value, kv_valid, causal, block_q, block_k,
+            interpret, return_lse,
+        )
+
+    return _per_shard(
+        local,
+        (query, key, value, kv_valid),
+        ("bhsd", "bhsd", "bhsd", None if kv_valid is None else "bs"),
+        ("bhsd", "bhs") if return_lse else ("bhsd",),
+    )
+
+
+def _flash_forward_local(
+    query, key, value, kv_valid, causal, block_q, block_k, interpret,
+    return_lse,
 ):
     b, h, q_len, d = query.shape
     kv_len = key.shape[2]
@@ -590,10 +700,18 @@ def _flash_forward(
         scale=scale,
     )
     out_specs = [pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((bh, q_pad, d_pad), query.dtype)]
+    out_shape = [_out_struct((bh, q_pad, d_pad), query.dtype, *operands)]
     if return_lse:
-        out_specs.append(pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, q_pad), jnp.float32))
+        # Column layout [B*H, q_pad, 1]: the kernel's running statistics
+        # are (block_q, 1) columns, and a (1, block_q) block over a 2-D
+        # [B*H, q_pad] array has a second-minor dim of 1 that neither
+        # divides by 8 nor equals the array dim -- Mosaic rejects it.
+        out_specs.append(
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+        )
+        out_shape.append(
+            _out_struct((bh, q_pad, 1), jnp.float32, *operands)
+        )
     res = pl.pallas_call(
         kernel,
         grid=(bh, num_q_blocks, num_k_blocks),
@@ -610,7 +728,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d_pad), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -618,7 +736,9 @@ def _flash_forward(
 
     out = res[0].reshape(b, h, q_pad, d_pad)[:, :, :q_len, :d]
     if return_lse:
-        return out, res[1]  # lse stays [B*H, q_pad] for the backward kernels
+        # lse leaves as [B, H, q_pad] so a per-shard launch can split its
+        # batch and head dims; the backward flattens it again.
+        return out, res[1].reshape(b, h, q_pad)
     return out
 
 
@@ -728,8 +848,8 @@ def _ragged_paged_kernel(
             # Dequantize the page *before* the dots — same order as the
             # XLA fallback, so kernel and fallback agree to float
             # rounding. Scales are per page-slot, broadcast over lanes.
-            keys = keys.astype(jnp.float32) * ks_ref[0][:, None]
-            values = values.astype(jnp.float32) * vs_ref[0][:, None]
+            keys = keys.astype(jnp.float32) * ks_ref[0]
+            values = values.astype(jnp.float32) * vs_ref[0]
         _fold(
             _scores(keys, page_size),
             k_idx < length,
@@ -807,15 +927,20 @@ def ragged_paged_attention_kernel(
         v_pages,
     ]
     if k_scale is not None:
+        # Column layout [num_pages, page_size, 1]: one scale per sublane
+        # of the page block it dequantizes, and every block dim equals
+        # its array dim (a (1, page_size) block over [num_pages,
+        # page_size] has an illegal second-minor dim of 1).
         operands += [
-            k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)
+            k_scale.astype(jnp.float32)[:, :, None],
+            v_scale.astype(jnp.float32)[:, :, None],
         ]
         in_specs += [
             pl.BlockSpec(
-                (1, page_size), lambda r, p, tbl, lens: (tbl[r, p], 0)
+                (1, page_size, 1), lambda r, p, tbl, lens: (tbl[r, p], 0, 0)
             ),
             pl.BlockSpec(
-                (1, page_size), lambda r, p, tbl, lens: (tbl[r, p], 0)
+                (1, page_size, 1), lambda r, p, tbl, lens: (tbl[r, p], 0, 0)
             ),
         ]
     if cur_k is not None:
@@ -851,10 +976,10 @@ def ragged_paged_attention_kernel(
             scale=1.0 / math.sqrt(head_dim),
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (num_rows, heads_padded, head_dim), query.dtype
+        out_shape=_out_struct(
+            (num_rows, heads_padded, head_dim), query.dtype, *operands
         ),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

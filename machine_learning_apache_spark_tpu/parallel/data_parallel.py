@@ -31,10 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from machine_learning_apache_spark_tpu.parallel.mesh import DATA_AXIS
 from machine_learning_apache_spark_tpu.train.state import TrainState
-from machine_learning_apache_spark_tpu.utils.jax_compat import (
-    implicit_replicated_grad_reduce,
-    shard_map,
-)
 
 
 def make_data_parallel_step(
@@ -64,18 +60,13 @@ def make_data_parallel_step(
         # params enter replicated (in_spec P()), so shard_map's transpose
         # inserts the psum-of-cotangents across `axis` automatically — with
         # the 1/axis_size loss scaling above, `grads` IS the global-mean
-        # gradient, as one compiled collective over ICI. On pre-graduation
-        # jax the shim runs check_rep=False, which disables that transpose
-        # rewrite, so the psum must be spelled out; on current jax adding
-        # one would be a redundant (if numerically no-op) collective —
-        # tests/test_data_parallel.py pins this parity on both.
-        if not implicit_replicated_grad_reduce:
-            grads = jax.tree.map(lambda g: jax.lax.psum(g, axis), grads)
+        # gradient, as one compiled collective over ICI. An explicit psum
+        # here would reduce a second time.
         loss = jax.lax.pmean(loss, axis)
         aux = jax.tree.map(lambda x: jax.lax.pmean(x, axis), aux)
         return grads, loss, aux
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(), P(axis), P()),
@@ -97,7 +88,7 @@ def make_data_parallel_eval_step(loss_fn: Callable, mesh: Mesh, *, axis: str = D
             lambda x: jax.lax.pmean(x, axis), aux
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh, in_specs=(P(), P(axis), P()), out_specs=(P(), P())
     )
 

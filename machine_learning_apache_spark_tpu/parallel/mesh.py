@@ -142,6 +142,24 @@ def shard_batch_stack(mesh: Mesh, batches: list, *, axis: str = DATA_AXIS):
     return jax.tree.map(lambda x: jax.device_put(x, sharding), stacked)
 
 
+def on_one_device(tree):
+    """``(tree, device)`` with every array of ``tree`` on one device: the
+    lowest-id device any of them touches (None, and ``tree`` unchanged,
+    when it holds no device arrays). Inference runs where its params are,
+    and params trained under a mesh arrive replicated over all of it —
+    a serving engine or a one-shot decode is a one-device program."""
+    devices = {
+        d
+        for leaf in jax.tree.leaves(tree)
+        if isinstance(leaf, jax.Array)
+        for d in leaf.sharding.device_set
+    }
+    device = min(devices, key=lambda d: d.id, default=None)
+    if len(devices) > 1:
+        tree = jax.device_put(tree, device)
+    return tree, device
+
+
 def device_prefetch(batches, mesh: Mesh, *, depth: int = 2,
                     axis: str = DATA_AXIS):
     """Shard batches onto the mesh ``depth`` ahead of consumption.
@@ -149,9 +167,8 @@ def device_prefetch(batches, mesh: Mesh, *, depth: int = 2,
     ``jax.device_put`` only *enqueues* a transfer, so issuing the next
     batches' transfers before the current step is consumed lets host→device
     copies overlap device compute — the input-pipeline double-buffering
-    every TPU workload wants, and worth far more on remote-controller
-    topologies where each transfer is an RPC. Bounded at ``depth``
-    in-flight batches to cap HBM staging memory. Values are unchanged
+    every TPU workload wants. Bounded at ``depth`` in-flight batches to
+    cap HBM staging memory. Values are unchanged
     (pinned by ``tests/test_train.py::TestDevicePrefetch``).
     """
     if depth < 1:

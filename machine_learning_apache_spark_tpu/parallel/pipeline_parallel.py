@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from machine_learning_apache_spark_tpu.parallel.mesh import PIPELINE_AXIS
-from machine_learning_apache_spark_tpu.utils.jax_compat import pcast_varying, shard_map
 
 
 def _pipeline_shard_fn(
@@ -59,7 +58,7 @@ def _pipeline_shard_fn(
     ticks = n_micro + n_stages - 1
     # Fresh carries are replicated constants; mark them device-varying over
     # the pipeline axis so the scan carry type stays uniform after ppermute.
-    varying = lambda v: pcast_varying(v, mesh_axes)
+    varying = lambda v: jax.lax.pcast(v, tuple(mesh_axes), to="varying")
     state = varying(jnp.zeros_like(x[0]))  # activation held by this stage
     outputs = varying(jnp.zeros_like(x))
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -195,7 +194,7 @@ def pipeline_apply(
         if aux is not None
         else None
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _pipeline_shard_fn,
             stage_fn=stage_fn,

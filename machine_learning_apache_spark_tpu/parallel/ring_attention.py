@@ -43,7 +43,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from machine_learning_apache_spark_tpu.ops.attention import NEG_INF
 from machine_learning_apache_spark_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
-from machine_learning_apache_spark_tpu.utils.jax_compat import pcast_varying, shard_map
 
 
 def _block_update(q, k, v, m, l, acc, bias, scale):
@@ -77,7 +76,7 @@ def _ring_shard_fn(q, k, v, kv_valid, *, axis, causal, scale, mesh_axes):
     # over exactly the axes q varies over (the in_specs axes — NOT every mesh
     # axis: varying over an axis absent from out_specs is a trace error on
     # e.g. a dp×tp×sp mesh) so the scan carry type stays uniform.
-    varying = lambda x: pcast_varying(x, mesh_axes)
+    varying = lambda x: jax.lax.pcast(x, tuple(mesh_axes), to="varying")
     m = varying(jnp.full((b, h, s_q), NEG_INF, jnp.float32))
     l = varying(jnp.zeros((b, h, s_q), jnp.float32))
     acc = varying(jnp.zeros((b, h, s_q, d), jnp.float32))
@@ -185,7 +184,7 @@ def ring_attention(
     spec = P(batch, None, seq_axis, None)
     valid_spec = P(batch, seq_axis)
     spec_axes = (seq_axis,) if batch is None else (batch, seq_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_shard_fn,
             axis=seq_axis,

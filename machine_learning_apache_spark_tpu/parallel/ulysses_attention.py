@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from machine_learning_apache_spark_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
-from machine_learning_apache_spark_tpu.utils.jax_compat import shard_map
 
 
 def _inner_attention(q, k, v, kv_valid, *, causal):
@@ -134,7 +133,7 @@ def ulysses_attention(
     batch = batch_axis if batch_axis in mesh.shape else None
     spec = P(batch, None, seq_axis, None)
     valid_spec = P(batch, seq_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_shard_fn, axis=seq_axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec, valid_spec if kv_valid is not None else P()),

@@ -87,7 +87,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from machine_learning_apache_spark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from machine_learning_apache_spark_tpu.utils import env as envcfg
-from machine_learning_apache_spark_tpu.utils.jax_compat import shard_map
 
 # Environment contract (launcher gang plumbing: the driver sets these on
 # the Distributor, workers' fit() picks them up — docs/PARALLELISM.md).
@@ -724,11 +723,21 @@ def make_zero1_step(
 
     flat_spec = jax.ShapeDtypeStruct((plan.padded,), jnp.float32)
     opt_specs = _opt_spec_tree(jax.eval_shape(tx.init, flat_spec), axis)
-    sharded = shard_map(
+    # check_vma=False, at this one call: (1) the sharded update needs each
+    # shard's *local* gradient to feed ``psum_scatter`` -- under vma typing,
+    # differentiating w.r.t. the replicated (``P()``) params psums the
+    # cotangents first, which is the full allreduce this step exists to
+    # avoid, and the scatter would then sum it a second time; (2) the new
+    # params come out of ``all_gather(tiled=True)`` and loss/aux out of
+    # ``pmean`` -- replicated by construction, but the public ``all_gather``
+    # is typed varying, so the ``P()`` out_specs cannot be inferred. The
+    # bit-identity gates in tests/test_zero.py check the replication claim.
+    sharded = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P(), opt_specs, P(axis), P()),
         out_specs=(P(), opt_specs, P(), P()),
+        check_vma=False,
     )
 
     @functools.partial(jax.jit, donate_argnums=0)

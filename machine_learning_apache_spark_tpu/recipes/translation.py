@@ -580,9 +580,16 @@ def train_translator(
         kw = dict(pad_id=cfg.pad_id, sos_id=SOS_ID, eos_id=EOS_ID)
         cands: list[list[int]] = []
         refs: list[list[int]] = []
-        for src_b, trg_b in val_loader:
-            cands.extend(strip_special_ids(decode(result.state.params, src_b), **kw))
-            refs.extend(strip_special_ids(trg_b, **kw))
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            kernel_mesh,
+        )
+
+        with kernel_mesh(mesh):  # params live on the mesh; so does decode
+            for src_b, trg_b in val_loader:
+                cands.extend(strip_special_ids(
+                    decode(result.state.params, src_b), **kw
+                ))
+                refs.extend(strip_special_ids(trg_b, **kw))
         extra["bleu"] = corpus_bleu(cands, refs)
 
     out = summarize(

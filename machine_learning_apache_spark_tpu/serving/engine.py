@@ -410,11 +410,10 @@ class ServingEngine:
         )
         return len(self.boundaries)
 
-    def compile_count(self) -> int | None:
+    def compile_count(self) -> int:
         """Total compiled programs across every jitted callable the
         engine owns — bucket decoders plus, in paged mode, the runtime's
-        prefill/launch programs (None if the jax build doesn't expose
-        the probe)."""
+        prefill/launch programs."""
         from machine_learning_apache_spark_tpu.utils.compilation_cache import (
             jit_cache_size,
         )
@@ -422,19 +421,15 @@ class ServingEngine:
         fns = list(self._decoders.values())
         if self.runtime is not None:
             fns += self.runtime.jit_fns()
-        sizes = [jit_cache_size(f) for f in fns]
-        if any(s is None for s in sizes):
-            return None
-        return sum(sizes)
+        return sum(jit_cache_size(f) for f in fns)
 
     @property
     def recompiles_after_warmup(self) -> int | None:
         """Programs compiled since ``warmup()`` — 0 in healthy steady
-        state (the demo/bench acceptance gate)."""
-        n = self.compile_count()
-        if n is None or self._compiles_at_warmup is None:
+        state (the demo/bench acceptance gate); None before warmup."""
+        if self._compiles_at_warmup is None:
             return None
-        return n - self._compiles_at_warmup
+        return self.compile_count() - self._compiles_at_warmup
 
     # -- live plane providers (called from HTTP scrape threads) --------------
     def _health_snapshot(self) -> dict:

@@ -65,11 +65,13 @@ behaviour that greedy decoding does not produce).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from machine_learning_apache_spark_tpu.parallel.mesh import on_one_device
 from machine_learning_apache_spark_tpu.serving.kv_pages import (
     NULL_PAGE,
     KVPagePool,
@@ -147,7 +149,12 @@ class PagedDecodeRuntime:
                 "'int8')"
             )
         self.model = model
-        self.params = params
+        # One runtime lives on one device. Params trained under a mesh
+        # arrive replicated over all of its devices; left there, every
+        # program would run on all of them, and the stores — committed to
+        # the params' devices by the first call — would change sharding
+        # between warm-up and the first request: a recompile per program.
+        self.params, self.device = on_one_device(params)
         self.max_active = max_active
         self.max_new_tokens = max_new_tokens
         self.page_size = page_size
@@ -208,16 +215,8 @@ class PagedDecodeRuntime:
             cfg.num_layers, 2, self.num_self_pages, page_size
         )
         self._mem_scale_shape = (cfg.num_layers, 2, num_pages, page_size)
-        self.kv_self = jnp.zeros(self._self_shape, self._self_store_dtype)
-        self.kv_mem = jnp.zeros(self._mem_shape, self._mem_store_dtype)
-        self.self_scale = (
-            jnp.zeros(self._self_scale_shape, jnp.float32)
-            if self._self_quant else None
-        )
-        self.mem_scale = (
-            jnp.zeros(self._mem_scale_shape, jnp.float32)
-            if self._mem_quant else None
-        )
+        self.self_scale = self.mem_scale = None
+        self._zero_stores()
 
         # Dtype-aware byte accounting: a page costs its payload plus (for
         # quantized stores) one fp32 scale per slot, across every layer's
@@ -251,16 +250,16 @@ class PagedDecodeRuntime:
         self._reset_host_state()
 
     def _zero_stores(self) -> None:
-        """Fresh zero payload + scale arrays — identical shapes/dtypes to
-        the live ones, so compiled programs stay valid."""
-        self.kv_self = jnp.zeros(self._self_shape, self._self_store_dtype)
-        self.kv_mem = jnp.zeros(self._mem_shape, self._mem_store_dtype)
+        """Fresh zero payload + scale arrays on the runtime's device —
+        identical shapes/dtypes/placement to the live ones, so compiled
+        programs stay valid."""
+        zeros = functools.partial(jnp.zeros, device=self.device)
+        self.kv_self = zeros(self._self_shape, self._self_store_dtype)
+        self.kv_mem = zeros(self._mem_shape, self._mem_store_dtype)
         if self._self_quant:
-            self.self_scale = jnp.zeros(
-                self._self_scale_shape, jnp.float32
-            )
+            self.self_scale = zeros(self._self_scale_shape, jnp.float32)
         if self._mem_quant:
-            self.mem_scale = jnp.zeros(self._mem_scale_shape, jnp.float32)
+            self.mem_scale = zeros(self._mem_scale_shape, jnp.float32)
 
     def _reset_host_state(self) -> None:
         R, Ps, Pm = self.max_active, self.self_pages, self.mem_pages

@@ -61,11 +61,11 @@ class SessionBuilder:
             if _ACTIVE_SESSION is not None and self._conf:
                 # Spark semantics: getOrCreate() returns the existing
                 # session and conf on the builder is NOT applied. Silent
-                # drops are expensive (e.g. a compilation_cache_dir that
-                # never enables costs its full compile time) — but only
-                # keys that actually DIFFER from the active session are
-                # dropped in any meaningful sense; idempotent re-creation
-                # with identical conf should stay quiet.
+                # drops are expensive (e.g. a platform request that never
+                # applies) — but only keys that actually DIFFER from the
+                # active session are dropped in any meaningful sense;
+                # idempotent re-creation with identical conf should stay
+                # quiet.
                 active = _ACTIVE_SESSION.conf
                 fields = {f.name: f for f in dataclasses.fields(SessionConfig)}
 
@@ -136,12 +136,6 @@ class Session:
 
     def __init__(self, conf: SessionConfig | None = None) -> None:
         self.conf = conf or SessionConfig()
-        if self.conf.compilation_cache_dir:
-            from machine_learning_apache_spark_tpu.utils.compilation_cache import (
-                enable_compilation_cache,
-            )
-
-            enable_compilation_cache(self.conf.compilation_cache_dir)
         if self.conf.platform:
             # Respect an explicit platform request (e.g. tests force "cpu").
             # Env vars are unreliable here — jax may already be imported — so

@@ -60,33 +60,41 @@ def _per_rank_multiprocessing_options():
 
 
 class _AnyProcessNumpyHandler(ocp.type_handlers.NumpyHandler):
-    """NumpyHandler whose write path ignores the global process index.
+    """NumpyHandler that writes from whichever process owns the directory.
 
-    Upstream ``NumpyHandler._background_serialize`` only issues tensorstore
-    writes from global process 0 — a baked-in ``process_index() == 0``
-    check that no public option reaches (``NumpyHandler`` has no
-    ``primary_host``). In a per-rank orbax group the manager's
-    ``active_processes={rank}`` means THIS process is the sole writer, so
-    non-zero ranks would finalize step directories containing metadata and
-    no data. The override is the upstream body minus that check."""
+    ``NumpyHandler._background_serialize`` issues its tensorstore writes
+    only when ``process_index() == 0``, and takes no ``primary_host``
+    (``ArrayHandler(primary_host=None, replica_id=None)``, orbax's own
+    per-host writer, refuses the host-local arrays a rank checkpoints).
+    In a per-rank group (``active_processes={rank}``) this process is the
+    sole writer of its directory, so a non-zero rank would finalize a step
+    that holds metadata and no data. The write spec comes from the public
+    ``type_handlers.get_json_tspec_write`` + ``get_cast_tspec_serialize``;
+    with OCDBT the rank writes under its own ``ocdbt.process_<rank>``
+    subdirectory, which the manager's finalize merges."""
 
     async def _background_serialize(self, values, infos, args=None):
-        write_coros = []
-        for value, info, arg in zip(values, infos, args):
-            tspec = self._get_json_tspec_write(
+        def write_spec(value, info, arg):
+            spec = ocp.type_handlers.get_json_tspec_write(
                 info,
-                value,
                 use_ocdbt=info.is_ocdbt_checkpoint,
-                process_index=ocp.type_handlers.get_process_index_for_subdir(
-                    use_ocdbt=info.is_ocdbt_checkpoint,
-                    override_ocdbt_process_id=self._override_ocdbt_process_id,
+                global_shape=value.shape,
+                local_shape=value.shape,
+                dtype=value.dtype,
+                process_index=(
+                    jax.process_index() if info.is_ocdbt_checkpoint else None
                 ),
+                metadata_key=self._metadata_key,
                 arg=arg,
             )
-            write_coros.append(
-                self._open_and_write(value, tspec, info.ts_context)
+            return ocp.type_handlers.get_cast_tspec_serialize(spec, value, arg)
+
+        await asyncio.gather(*(
+            self._open_and_write(
+                value, write_spec(value, info, arg), info.ts_context
             )
-        await asyncio.gather(*write_coros)
+            for value, info, arg in zip(values, infos, args)
+        ))
 
 
 class _AnyProcessScalarHandler(

@@ -101,9 +101,9 @@ def register(
 register(
     "MLSPARK_PLATFORM", type="str", default=None, subsystem="core",
     description="JAX platform override applied through the config API at "
-    "first package import (reliable where the JAX_PLATFORMS env var is "
-    "not, e.g. images whose sitecustomize pre-registers a TPU plugin). "
-    "Example: `cpu`, `tpu`.",
+    "first package import (the spelling of JAX_PLATFORMS that still works "
+    "when the embedding program imported jax first; the launcher sets "
+    "both on its children). Example: `cpu`, `tpu`.",
 )
 register(
     "MLSPARK_CPU_DEVICES", type="int", default=None, subsystem="core",
@@ -148,11 +148,6 @@ register(
     "MLSPARK_COORDINATOR_ADDRESS", type="str", default="", subsystem="session",
     description="SessionConfig rendezvous override (`host:port`); the "
     "launcher's MLSPARK_COORDINATOR is the usual channel.",
-)
-register(
-    "MLSPARK_COMPILATION_CACHE_DIR", type="path", default="", subsystem="session",
-    description="Persistent XLA compilation-cache directory (compiles "
-    "reused across processes; 20-60s/program on remote controllers).",
 )
 register(
     "MLSPARK_BATCH_SIZE", type="int", default=32, subsystem="train",
@@ -461,11 +456,6 @@ register(
     "MLSPARK_WORKDIR", type="path", default=None, subsystem="examples",
     description="Example scripts' scratch directory (default: a fresh "
     "tempdir).",
-)
-register(
-    "MLSPARK_DIST_PLATFORM", type="str", default="cpu", subsystem="examples",
-    description="Platform the distributed example scripts pass to "
-    "Distributor(platform=...); empty = let each worker pick.",
 )
 
 

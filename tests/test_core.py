@@ -93,39 +93,6 @@ class TestSession:
         assert s.device_count == 8
         s.stop()
 
-    def test_compilation_cache_conf(self, tmp_path):
-        """``spark.compilation.cache.dir`` conf: the session enables the
-        persistent XLA cache, and a compiled program actually writes
-        entries under the dir (reused by later processes — the startup
-        lever for repeat runs on remote-controller topologies)."""
-        import os
-
-        d = str(tmp_path / "xla-cache")
-        s = (
-            mlspark.Session.builder.appName("cache-test")
-            .config("spark.compilation.cache.dir", d)
-            .getOrCreate()
-        )
-        try:
-            assert s.conf.compilation_cache_dir == d
-            assert os.path.isdir(d)
-            # Force min-compile-time to 0 so this tiny program qualifies.
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.jit(lambda x: (x @ x.T).sum())(
-                jnp.ones((64, 64))
-            ).block_until_ready()
-            entries = [f for _, _, fs in os.walk(d) for f in fs]
-            assert entries, "no persistent cache entries written"
-        finally:
-            # Cache settings are process-global JAX config: restore ALL of
-            # them or later tests silently run different cache semantics.
-            from machine_learning_apache_spark_tpu.utils.compilation_cache import (
-                disable_compilation_cache,
-            )
-
-            disable_compilation_cache()
-            s.stop()
-
     def test_stop_clears_singleton(self):
         s = mlspark.Session.builder.get_or_create()
         s.stop()
@@ -221,3 +188,76 @@ class TestUtils:
             pass
         out = capsys.readouterr().out
         assert "Training Time" in out and "sec" in out
+
+
+class TestCompileCacheRule:
+    """``utils.compilation_cache``: JAX_COMPILATION_CACHE_DIR places the
+    cache when set (code sets no directory); unset, it is
+    ``<checkout>/.xla_cache``. Checked in subprocesses — conftest turns the
+    cache off for the test process itself."""
+
+    SCRIPT = """
+import jax
+import machine_learning_apache_spark_tpu as mlspark
+seen = [jax.config.jax_compilation_cache_dir]
+session = mlspark.Session.builder.appName("cache-rule").getOrCreate()
+seen.append(jax.config.jax_compilation_cache_dir)
+import jax.numpy as jnp
+from machine_learning_apache_spark_tpu.models import MLP
+from machine_learning_apache_spark_tpu.train import (
+    TrainState, classification_loss, fit, make_optimizer,
+)
+model = MLP(layers=(4, 5, 3))
+x, y = jnp.ones((8, 4)), jnp.zeros((8,), jnp.int32)
+state = TrainState.create(
+    apply_fn=model.apply,
+    params=model.init(jax.random.key(0), x[:1])["params"],
+    tx=make_optimizer("sgd", 0.1),
+)
+fit(state, classification_loss(model.apply), [(x, y)], epochs=1, log_every=0)
+seen.append(jax.config.jax_compilation_cache_dir)
+assert len(set(seen)) == 1, seen
+print("CACHE_DIR=" + str(seen[0]))
+"""
+
+    def _cache_dirs(self, env_dir, n=1):
+        import os
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {
+            k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR",
+                         "JAX_ENABLE_COMPILATION_CACHE", "XLA_FLAGS")
+        }
+        if env_dir is not None:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        procs = [
+            subprocess.Popen(
+                [sys.executable, "-c", self.SCRIPT], cwd=repo, env=env,
+                stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            )
+            for _ in range(n)
+        ]
+        dirs = []
+        for p in procs:
+            out, err = p.communicate(timeout=300)
+            assert p.returncode == 0, err[-2000:]
+            dirs.append(
+                [l for l in out.splitlines() if l.startswith("CACHE_DIR=")]
+                [-1].split("=", 1)[1]
+            )
+        return dirs
+
+    def test_env_var_places_the_cache(self, tmp_path):
+        d = str(tmp_path / "placed-from-outside")
+        assert self._cache_dirs(d) == [d]
+
+    def test_unset_means_checkout_and_processes_agree(self):
+        import os
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".xla_cache")
+        assert self._cache_dirs(None, n=2) == [want, want]
+

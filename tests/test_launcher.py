@@ -500,3 +500,82 @@ class TestElasticShrinkTraining:
         # group-durable checkpoint, never from scratch.
         assert out["resumed_step"] in (2, 4)
         assert np.isfinite(out["final_loss"])
+
+
+class TestTpuChipPinning:
+    """``tpu_pinning_env``: local children of a TPU host get chip ``rank``
+    through their environment (checked on a four-chip v5e, PERF.md
+    "Bring-up"); here the host's chip count is faked."""
+
+    @pytest.fixture
+    def four_chips(self, monkeypatch):
+        from machine_learning_apache_spark_tpu.launcher import distributor
+
+        monkeypatch.setattr(distributor, "local_tpu_chips", lambda: 4)
+        monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+        return distributor
+
+    def test_cpu_children_and_chipless_hosts_get_nothing(self, four_chips):
+        d = four_chips
+        assert d.tpu_pinning_env(0, "cpu") == {}
+        assert d.tpu_pinning_env(0, "cpu", gang_ports=[1, 2]) == {}
+
+    def test_no_chips_no_pinning(self, monkeypatch):
+        from machine_learning_apache_spark_tpu.launcher import distributor as d
+
+        monkeypatch.setattr(d, "local_tpu_chips", lambda: 0)
+        assert d.tpu_pinning_env(3, "") == {}
+
+    def test_replica_sees_exactly_its_chip(self, four_chips):
+        env = four_chips.tpu_pinning_env(2, "tpu,cpu")
+        assert env == {
+            "TPU_VISIBLE_CHIPS": "2",
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }
+        with pytest.raises(ValueError, match="chip 4.*4 chip"):
+            four_chips.tpu_pinning_env(4, "")
+
+    def test_gang_forms_one_runtime_over_the_host_grid(self, four_chips):
+        ports = [9001, 9002, 9003, 9004]
+        envs = [
+            four_chips.tpu_pinning_env(k, "", gang_ports=ports)
+            for k in range(4)
+        ]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == list("0123")
+        assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+        assert {e["TPU_PROCESS_ADDRESSES"] for e in envs} == {
+            "localhost:9001,localhost:9002,localhost:9003,localhost:9004"
+        }
+        assert [e["TPU_PROCESS_PORT"] for e in envs] == [
+            "9001", "9002", "9003", "9004"
+        ]
+        assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == list("0123")
+
+    def test_gang_of_another_size_fails_fast_with_the_reason(self, four_chips):
+        with pytest.raises(ValueError, match="one process per chip"):
+            four_chips.tpu_pinning_env(0, "", gang_ports=[9001, 9002])
+
+
+def test_heartbeat_survives_a_half_imported_faults_module(
+    tmp_path, monkeypatch
+):
+    """The beat thread peeks at ``utils.faults`` through sys.modules while
+    the main thread may be half-way through importing it; a module object
+    that has no ``heartbeats_suspended`` yet must not kill the thread (a
+    dead heartbeat reads as a stall)."""
+    import sys
+    import time
+    import types
+
+    from machine_learning_apache_spark_tpu.launcher import runner
+
+    name = "machine_learning_apache_spark_tpu.utils.faults"
+    monkeypatch.setitem(sys.modules, name, types.ModuleType(name))
+    path = tmp_path / "heartbeat_0"
+    thread = runner._start_heartbeat(str(path), 0.05)
+    deadline = time.monotonic() + 5
+    while not path.exists() and time.monotonic() < deadline:
+        time.sleep(0.02)
+    assert path.exists() and thread.is_alive()
+

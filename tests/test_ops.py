@@ -417,6 +417,93 @@ class TestFlashBackward:
             np.testing.assert_allclose(np.asarray(a), np.asarray(e), atol=1e-4)
 
 
+class TestFlashPerShardLaunch:
+    """A Mosaic custom call is opaque to the SPMD partitioner (on the chip
+    an unwrapped kernel under a sharded batch fails to compile), so under
+    ``kernel_mesh(mesh)`` the launchers run inside a ``shard_map`` over the
+    mesh's data/model axes. On the CPU mesh the kernels interpret, but the
+    wrapping is the same program structure the chip compiles."""
+
+    def _mesh(self):
+        import jax
+        from jax.sharding import Mesh
+
+        return Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
+
+    @pytest.mark.parametrize(
+        "seq,spec",
+        [(64, ("data",)), (512, ("data", "model")), (512, ())],
+        ids=["batch-sharded", "batch+heads-pallas-bwd", "replicated"],
+    )
+    def test_sharded_inputs_match_unsharded(self, seq, spec):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            kernel_mesh,
+        )
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            flash_attention,
+        )
+
+        mesh = self._mesh()
+        b, h, d = 8, 2, 32
+        q, k, v = (
+            jax.random.normal(jax.random.key(i), (b, h, seq, d), jnp.float32)
+            for i in range(3)
+        )
+        valid = jnp.arange(seq)[None, :] < (seq - 7 * jnp.arange(b)[:, None])
+
+        def loss(q, k, v, valid):
+            out = flash_attention(
+                q, k, v, causal=True, kv_valid=valid, interpret=True
+            )
+            return (out ** 2).sum()
+
+        want = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v, valid)
+        sharded = NamedSharding(mesh, P(*spec))
+        args = (
+            *(jax.device_put(a, sharded) for a in (q, k, v)),
+            jax.device_put(valid, NamedSharding(mesh, P(*spec[:1]))),
+        )
+        f = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        with kernel_mesh(mesh):  # tracing happens inside
+            got = f(*args)
+            text = f.lower(*args).compile().as_text()
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+        if spec == ("data", "model"):
+            # inputs already laid out the way the launch shards them:
+            # nothing is gathered, each device works on its own block
+            assert "all-gather" not in text
+            assert got[0].sharding.is_equivalent_to(sharded, 4)
+
+    def test_inside_a_manual_shard_map_the_kernel_is_called_directly(self):
+        """The ZeRO-1 / data-parallel steps are already fully manual; the
+        launcher must not nest a second shard_map over the same axes."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            kernel_mesh,
+        )
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            flash_attention,
+        )
+
+        mesh = self._mesh()
+        q = jax.random.normal(jax.random.key(0), (8, 2, 64, 32), jnp.float32)
+        body = lambda q: flash_attention(q, q, q, causal=True, interpret=True)
+        spec = P("data", "model")
+        with kernel_mesh(mesh):
+            got = jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=spec, out_specs=spec
+            ))(q)
+        np.testing.assert_allclose(got, body(q), rtol=1e-5, atol=1e-6)
+
+
 class TestRaggedPagedAttention:
     """Decode-step attention over a paged KV store: the XLA gather
     fallback (CPU tier-1 route), the Pallas kernel in interpret mode, and

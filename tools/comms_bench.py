@@ -50,13 +50,16 @@ import time
 # The virtual 8-device CPU mesh must be requested BEFORE jax import
 # (tests/conftest.py contract).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "xla_force_host_platform_device_count" not in os.environ.get(
-    "XLA_FLAGS", ""
+# ... and codegen capped at AVX (no FMA contraction), without which the
+# bit-identity gates measure the host's fusion choices (tests/conftest.py).
+for _flag in (
+    "--xla_force_host_platform_device_count=8",
+    "--xla_cpu_max_isa=AVX",
 ):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    ).strip()
+    if _flag.split("=")[0] not in os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "") + " " + _flag
+        ).strip()
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -89,9 +92,6 @@ from machine_learning_apache_spark_tpu.train.loop import (  # noqa: E402
 from machine_learning_apache_spark_tpu.train.state import (  # noqa: E402
     TrainState,
     make_optimizer,
-)
-from machine_learning_apache_spark_tpu.utils.jax_compat import (  # noqa: E402
-    shard_map,
 )
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
@@ -315,11 +315,16 @@ def bench_collectives(mesh, config, reps: int) -> dict:
             offset += piece_len
         return jnp.concatenate(segments)
 
-    rs = jax.jit(shard_map(
-        rs_shard, mesh=mesh, in_specs=(P(),), out_specs=P(axis)
+    # check_vma=False as in zero.make_zero1_step: the standalone
+    # collectives mirror that step's body, whose all_gather(tiled=True)
+    # output is replicated by construction but typed varying.
+    rs = jax.jit(jax.shard_map(
+        rs_shard, mesh=mesh, in_specs=(P(),), out_specs=P(axis),
+        check_vma=False,
     ))
-    ag = jax.jit(shard_map(
-        ag_shard, mesh=mesh, in_specs=(P(axis),), out_specs=P()
+    ag = jax.jit(jax.shard_map(
+        ag_shard, mesh=mesh, in_specs=(P(axis),), out_specs=P(),
+        check_vma=False,
     ))
     flat = jnp.ones((plan.padded,), jnp.float32)
     shard = jax.block_until_ready(rs(flat))  # also compiles

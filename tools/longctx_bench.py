@@ -9,8 +9,11 @@ the recorded failure is direct evidence for the flash kernel's O(S)
 memory claim. Batch sizes halve as length doubles (constant token budget
 per step).
 
-Run on a live TPU (`python tools/longctx_bench.py` from the repo root);
-writes one JSON line per (seq, impl) plus a summary line.
+Chip-only, like bench.py: run it through the chip tool
+(`python tools/longctx_bench.py` from the repo root); without a TPU it
+exits non-zero, and any failure but the dense path's expected
+out-of-memory ends the run. Writes one JSON line per (seq, impl) plus a
+summary line.
 """
 
 import json
@@ -23,10 +26,7 @@ import bench
 
 
 def main() -> None:
-    jax = bench._init_backend()
-    if jax.devices()[0].platform != "tpu":
-        print(json.dumps({"error": "needs the live TPU chip"}))
-        return
+    jax = bench.init_chip()
     from machine_learning_apache_spark_tpu.ops.attention import attention_impl
 
     def _hbm_gb():
@@ -36,32 +36,20 @@ def main() -> None:
         # cumulative over the PROCESS (no reset API), so it is labeled as
         # such: the first config's peak is exact; later configs' peaks
         # are a running max and only meaningful when they RISE. Current
-        # bytes_in_use accompanies it. memory_stats is optional per
-        # backend; absence degrades to null, never fails the config.
-        try:
-            stats = jax.local_devices()[0].memory_stats() or {}
-            out = {}
-            if stats.get("peak_bytes_in_use"):
-                out["peak_hbm_gb_cumulative"] = round(
-                    stats["peak_bytes_in_use"] / 2**30, 3
-                )
-            if stats.get("bytes_in_use"):
-                out["hbm_gb_in_use"] = round(
-                    stats["bytes_in_use"] / 2**30, 3
-                )
-            return out
-        except Exception:  # noqa: BLE001
-            return {}
+        # bytes_in_use accompanies it.
+        stats = jax.local_devices()[0].memory_stats()
+        return {
+            "peak_hbm_gb_cumulative": round(
+                stats["peak_bytes_in_use"] / 2**30, 3
+            ),
+            "hbm_gb_in_use": round(stats["bytes_in_use"] / 2**30, 3),
+        }
 
     def run(seq, bpc, impl):
         with attention_impl(impl):
-            r = bench._with_deadline(
-                lambda: bench.bench_transformer(
-                    jax, batch_per_chip=bpc, trials=3, steps=5, warmup=5,
-                    seq=seq,
-                ),
-                600,
-                f"longctx seq={seq} {impl}",
+            r = bench.bench_transformer(
+                jax, batch_per_chip=bpc, trials=3, steps=5, warmup=5,
+                seq=seq,
             )
         r.update(_hbm_gb())
         return r
@@ -80,31 +68,22 @@ def main() -> None:
                 for k in ("peak_hbm_gb_cumulative", "hbm_gb_in_use"):
                     if k in r:
                         out[k] = r[k]
-            except Exception as e:  # noqa: BLE001 — record and continue
+            except jax.errors.JaxRuntimeError as e:
+                # A dense OOM is an expected, *informative* failure (the
+                # [B,H,S,S] tensor outgrowing HBM) — recorded as evidence.
+                # Anything else, and any flash failure, ends the run.
+                if impl != "dense" or "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
                 out = {
                     "seq": seq, "batch_per_chip": bpc, "impl": impl,
-                    "error": repr(e),
+                    "error": repr(e), "oom": True,
                 }
                 # Peak-at-failure is the most informative memory reading
-                # the tool can take: for a dense OOM it shows how full
-                # HBM was when the [B,H,S,S] materialization broke.
+                # the tool can take: it shows how full HBM was when the
+                # [B,H,S,S] materialization broke.
                 out.update(_hbm_gb())
-                # A dense OOM is an expected, *informative* failure (the
-                # [B,H,S,S] tensor outgrowing HBM) — label it so the
-                # artifact reads as evidence, not as a broken run.
-                if "RESOURCE_EXHAUSTED" in out["error"] or "memory" in (
-                    out["error"].lower()
-                ):
-                    out["oom"] = True
             results.append(out)
             print(json.dumps(out), flush=True)
-            if "error" in out and "TimeoutError" in out["error"]:
-                # Same quarantine rule as bench.py: the abandoned thread
-                # may still land on the chip — later configs would measure
-                # contention, not the framework.
-                print(json.dumps({"stopped": "device quarantined after a "
-                                  "hung point"}), flush=True)
-                return
     print(json.dumps({"summary": _summarize(results)}), flush=True)
 
 
