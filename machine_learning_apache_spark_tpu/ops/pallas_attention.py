@@ -574,6 +574,7 @@ def _flash_backward_local(cfg, query, key, value, kv_valid, out, lse, g):
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         compiler_params=compiler_params,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_operands)
 
     # dkv grid: key blocks in the middle (parallel), query blocks innermost
@@ -612,6 +613,7 @@ def _flash_backward_local(cfg, query, key, value, kv_valid, out, lse, g):
         ],
         compiler_params=compiler_params,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_operands)
 
     dq = dq.reshape(b, h, q_pad, d_pad)[:, :, :q_len, :d]
@@ -732,6 +734,7 @@ def _flash_forward_local(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
 
     out = res[0].reshape(b, h, q_pad, d_pad)[:, :, :q_len, :d]
@@ -983,5 +986,6 @@ def ragged_paged_attention_kernel(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ragged_paged_decode",
     )(*operands)
     return out[:, :num_heads]

@@ -557,20 +557,62 @@ class ServingEngine:
         rows (chunk-budgeted prefill), launch ``steps_per_launch`` ragged
         decode steps over every occupied row, retire rows as they finish.
         A raised launch or admission quarantines the active set only —
-        same inner containment ring as the padded loop."""
+        same inner containment ring as the padded loop.
+
+        An engine with no active row blocks in ``serving.idle_wait`` until
+        work arrives; every other iteration is one ``serving.cycle``."""
         while not self._stop.is_set():
             try:
-                self.queue.expire_overdue()
-                idle = not self.runtime.any_active()
-                self._paged_admit(timeout=0.05 if idle else 0.0)
-                if self._stop.is_set():
-                    break
+                taken = None
                 if not self.runtime.any_active():
-                    continue
-                self._paged_step()
+                    taken = self._paged_idle_wait()
+                    if not taken:
+                        continue
+                self._paged_cycle(taken)
             except Exception as e:  # noqa: BLE001 — contain, keep serving
                 self._paged_quarantine(e)
         self._paged_fail_active(EngineStopped("serving engine stopped"))
+
+    def _paged_take(self, timeout: float) -> list[ServeRequest]:
+        return self.paged_batcher.take(
+            max_requests=self.pool.free,
+            token_budget=self.prefill_budget,
+            timeout=timeout,
+            cost_fn=self._admission_cost,
+        )
+
+    def _paged_idle_wait(self) -> list[ServeRequest]:
+        """Block until the queue hands over work (returned, for the cycle
+        to place) or the engine stops ([]). One span however long the
+        engine stays idle — a poll that comes back empty writes nothing,
+        so a quiet replica's flight-recorder tail keeps its history."""
+        with annotate("serving.idle_wait"):
+            while not self._stop.is_set():
+                taken = self._paged_take(timeout=0.05)
+                if taken:
+                    return taken
+                # No arrival will run the submit-side sweep, so burn
+                # deadlines down directly — a queued request must expire
+                # on time even on a quiet engine.
+                self.queue.expire_now()
+        return []
+
+    def _paged_cycle(self, taken: list[ServeRequest] | None) -> None:
+        """One iteration with work: the deadline sweep, admission, then —
+        if any row is occupied — grow, launch and retire. Every phase is
+        a child span of ``serving.cycle``, so the cycle's duration less
+        the union of its children is the loop's own time."""
+        with annotate("serving.cycle") as cycle:
+            with annotate("serving.expire") as phase:
+                phase.set(expired=self.queue.expire_overdue())
+            with annotate("serving.admit") as phase:
+                self._paged_admit(phase, taken)
+            if self._stop.is_set() or not self.runtime.any_active():
+                cycle.set(launched=0)
+                return
+            seq = self._batch_seq
+            self._batch_seq += 1
+            cycle.set(seq=seq, launched=int(self._paged_step(seq)))
 
     def _admission_cost(self, req) -> int:
         """Prefill tokens admitting ``req`` will actually compute: zero
@@ -583,150 +625,173 @@ class ServingEngine:
             return 0
         return self.paged_batcher.cost(req.ids)
 
-    def _paged_admit(self, timeout: float = 0.0) -> None:
+    def _paged_admit(self, phase, taken: list[ServeRequest] | None) -> None:
         """Move pending requests onto free rows, bounded by the prefill
         token budget (chunked-prefill pacing). On page-pool pressure the
         untaken tail goes back to the queue head — transient, not an
-        error."""
-        taken = self.paged_batcher.take(
-            max_requests=self.pool.free,
-            token_budget=self.prefill_budget,
-            timeout=timeout,
-            cost_fn=self._admission_cost,
-        )
+        error. ``taken`` is what the idle wait already took, or None to
+        take now; the round's counts go onto ``phase`` (``serving.admit``)
+        and its token slots into the ledger once, as sums."""
+        if taken is None:
+            taken = self._paged_take(timeout=0.0)
         if not taken:
-            # Empty admit round: no arrival will run the submit-side
-            # sweep, so burn deadlines down directly — a queued request
-            # must expire on time even on a quiet engine.
+            # Empty admit round: see ``_paged_idle_wait``.
             self.queue.expire_now()
+            phase.set(taken=0)
             return
-        for i, req in enumerate(taken):
-            if self._stop.is_set():
-                self.queue.requeue_front(taken[i:])
-                return
-            row = self.pool.try_acquire(req.id)
-            if row is None:  # unreachable: take() is bounded by free rows
-                self.queue.requeue_front(taken[i:])
-                return
-            res = self.runtime.admit(req, row)
-            if res is None:
-                # Page pool full even after cache eviction: give the row
-                # back and retry once in-flight rows free pages.
-                self.pool.release_owner(req.id)
-                self.queue.requeue_front(taken[i:])
-                return
-            kind, computed, real = res
-            req.admit_time = self.clock()
-            req.trace.mark(
-                "admit", req.admit_time,
-                kind=kind, prefill_tokens=computed, row=row,
-            )
-            self.metrics.on_token_slots(
-                real=0 if kind == "hit" else real, padded=computed
+        hits = misses = real_sum = computed_sum = requeued = 0
+        try:
+            for req in taken:
+                if self._stop.is_set():
+                    break
+                row = self.pool.try_acquire(req.id)
+                if row is None:  # unreachable: take() is bounded by free rows
+                    break
+                res = self.runtime.admit(req, row)
+                if res is None:
+                    # Page pool full even after cache eviction: give the
+                    # row back and retry once in-flight rows free pages.
+                    self.pool.release_owner(req.id)
+                    break
+                kind, computed, real = res
+                req.admit_time = self.clock()
+                req.trace.mark(
+                    "admit", req.admit_time,
+                    kind=kind, prefill_tokens=computed, row=row,
+                )
+                if kind == "hit":
+                    hits += 1
+                else:
+                    misses += 1
+                    real_sum += real
+                computed_sum += computed
+            # Taken and not placed (engine stopping, pool pressure): back
+            # to the head of the queue, in order.
+            back = taken[hits + misses:]
+            self.queue.requeue_front(back)
+            requeued = len(back)
+        finally:
+            # Also when a prefill raised: what was dispatched is counted.
+            if hits + misses:
+                self.metrics.on_token_slots(
+                    real=real_sum, padded=computed_sum
+                )
+            phase.set(
+                taken=len(taken), hits=hits, misses=misses,
+                prefill_tokens=computed_sum, requeued=requeued,
+                queue_depth=self.queue.depth,
             )
 
-    def _paged_step(self) -> None:
-        """One fault-injection point, one page-growth pass, one compiled
-        launch, then host-side retirement of every finished row."""
-        seq = self._batch_seq
-        self._batch_seq += 1
+    def _paged_step(self, seq: int) -> bool:
+        """One fault-injection point, one page-growth pass and deadline
+        sweep (``serving.grow``), one compiled launch (``serving.batch``),
+        then host-side retirement of every finished row
+        (``serving.retire``). False where the sweep left no row to
+        launch."""
         maybe_fault("decode_batch", batch=seq)
-        for row in self.runtime.grow():
-            req = self.runtime.retire(row)
-            self.pool.release_owner(req.id)
-            if not req.future.done():
-                req.trace.mark("failed", self.clock(), reason="pages_exhausted")
-                req.future.set_exception(InternalError(
-                    "kv page pool exhausted mid-decode; size num_pages "
-                    "for the worst case (the default does)"
-                ))
-                self.metrics.on_failure(1)
-                self.metrics.on_trace(req)
-        # Deadline sweep between launches: a row whose deadline passed
-        # (or was force-expired by /v1/cancel) retires NOW, freeing its
-        # pages and launch slot instead of decoding tokens no one will
-        # read. Same retire path as completion, outcome ``expired`` — the
-        # conservation ledger closes either way.
-        now = self.clock()
-        n_reaped = 0
-        for row, req in self.runtime.active_rows():
-            if not req.expired(now):
-                continue
-            self.runtime.retire(row)
-            self.pool.release_owner(req.id)
-            n_reaped += 1
-            if not req.future.done():
-                req.trace.mark("expire", now, reason="in_flight")
-                req.future.set_exception(DeadlineExceeded(
-                    f"request {req.id} expired mid-decode after "
-                    f"{now - req.submit_time:.3f}s"
-                ))
-                self.metrics.on_expire(1, in_flight=True)
-                self.metrics.on_slo(req.tier, True)
-                self.metrics.on_trace(req)
-        if n_reaped:
-            telemetry.annotate(
-                "serving.expire_in_flight", mode="paged", count=n_reaped
-            )
+        with annotate("serving.grow") as phase:
+            starved = self.runtime.grow()
+            for row in starved:
+                req = self.runtime.retire(row)
+                self.pool.release_owner(req.id)
+                if not req.future.done():
+                    req.trace.mark(
+                        "failed", self.clock(), reason="pages_exhausted"
+                    )
+                    req.future.set_exception(InternalError(
+                        "kv page pool exhausted mid-decode; size num_pages "
+                        "for the worst case (the default does)"
+                    ))
+                    self.metrics.on_failure(1)
+                    self.metrics.on_trace(req)
+            # Deadline sweep between launches: a row whose deadline passed
+            # (or was force-expired by /v1/cancel) retires NOW, freeing its
+            # pages and launch slot instead of decoding tokens no one will
+            # read. Same retire path as completion, outcome ``expired`` —
+            # the conservation ledger closes either way.
+            now = self.clock()
+            n_reaped = 0
+            for row, req in self.runtime.active_rows():
+                if not req.expired(now):
+                    continue
+                self.runtime.retire(row)
+                self.pool.release_owner(req.id)
+                n_reaped += 1
+                if not req.future.done():
+                    req.trace.mark("expire", now, reason="in_flight")
+                    req.future.set_exception(DeadlineExceeded(
+                        f"request {req.id} expired mid-decode after "
+                        f"{now - req.submit_time:.3f}s"
+                    ))
+                    self.metrics.on_expire(1, in_flight=True)
+                    self.metrics.on_slo(req.tier, True)
+                    self.metrics.on_trace(req)
+            if n_reaped:
+                telemetry.annotate(
+                    "serving.expire_in_flight", mode="paged", count=n_reaped
+                )
+            phase.set(starved=len(starved), reaped=n_reaped)
         active = self.runtime.active_requests()
         n_active = len(active)
         if n_active == 0:
-            return
+            return False
         t0 = self.clock()
         with telemetry.span(
             "serving.batch", mode="paged", rows=n_active,
-            steps=self.runtime.steps_per_launch,
-            requests=[r.trace.trace_id for r in active],
+            steps=self.runtime.steps_per_launch, seq=seq,
         ), annotate("serve_decode_paged"):
             result = self.runtime.launch()
         decode_done = self.clock()
         decode_s = decode_done - t0
-        for req in active:
-            req.trace.note_launch()
-        for req in result.first_emits:
-            req.decode_done_time = decode_done
-            req.trace.mark("first_token", decode_done)
-        vocab = self.translator.trg_pipe.vocab
-        n_completed = 0
-        for req, ids, row, saw_eos in result.completed:
-            self.runtime.retire(row)
-            self.pool.release_owner(req.id)
-            req.trace.mark("complete", decode_done, tokens=len(ids))
-            req.future.set_result(" ".join(vocab.lookup_tokens(ids)))
-            n_completed += 1
-            now = self.clock()
-            self.metrics.on_complete(
-                queue_wait=(req.admit_time or req.submit_time)
-                - req.submit_time,
-                ttft=(req.decode_done_time or now) - req.submit_time,
-                total=now - req.submit_time,
+        with annotate("serving.retire") as phase:
+            for req in active:
+                req.trace.note_launch(seq)
+            for req in result.first_emits:
+                req.decode_done_time = decode_done
+                req.trace.mark("first_token", decode_done)
+            vocab = self.translator.trg_pipe.vocab
+            n_completed = 0
+            for req, ids, row, saw_eos in result.completed:
+                self.runtime.retire(row)
+                self.pool.release_owner(req.id)
+                req.trace.mark("complete", decode_done, tokens=len(ids))
+                req.future.set_result(" ".join(vocab.lookup_tokens(ids)))
+                n_completed += 1
+                now = self.clock()
+                self.metrics.on_complete(
+                    queue_wait=(req.admit_time or req.submit_time)
+                    - req.submit_time,
+                    ttft=(req.decode_done_time or now) - req.submit_time,
+                    total=now - req.submit_time,
+                )
+                self.metrics.on_trace(req)
+                self.metrics.on_slo(
+                    req.tier, req.deadline is not None and now > req.deadline
+                )
+            # Token ledger parity with the padded path (len(content)+1 per
+            # request): real emits count EOS when emitted; a
+            # budget-exhausted row gets its implicit stop token here.
+            new_tokens = result.real_tokens + sum(
+                1 for *_ , saw_eos in result.completed if not saw_eos
             )
-            self.metrics.on_trace(req)
-            self.metrics.on_slo(
-                req.tier, req.deadline is not None and now > req.deadline
+            self.metrics.on_token_slots(
+                real=result.real_tokens, padded=result.computed_slots
             )
-        # Token ledger parity with the padded path (len(content)+1 per
-        # request): real emits count EOS when emitted; a budget-exhausted
-        # row gets its implicit stop token here.
-        new_tokens = result.real_tokens + sum(
-            1 for *_ , saw_eos in result.completed if not saw_eos
-        )
-        self.metrics.on_token_slots(
-            real=result.real_tokens, padded=result.computed_slots
-        )
-        if n_completed:
-            self.queue.note_serviced(n_completed, decode_s)
-        self.metrics.on_batch(
-            n_requests=n_active,
-            max_batch=self.max_active,
-            decode_s=decode_s,
-            new_tokens=new_tokens,
-            queue_depth=self.queue.depth,
-            slot_occupancy=self.runtime.mem_pool.occupancy,
-        )
-        # A launch completed without raising: the degraded window (if
-        # any) is over — /healthz flips back to ok.
-        self._health.note_ok_batch(decode_done)
+            if n_completed:
+                self.queue.note_serviced(n_completed, decode_s)
+            self.metrics.on_batch(
+                n_requests=n_active,
+                max_batch=self.max_active,
+                decode_s=decode_s,
+                new_tokens=new_tokens,
+                queue_depth=self.queue.depth,
+                slot_occupancy=self.runtime.mem_pool.occupancy,
+            )
+            # A launch completed without raising: the degraded window (if
+            # any) is over — /healthz flips back to ok.
+            self._health.note_ok_batch(decode_done)
+            phase.set(completed=n_completed, tokens=new_tokens)
+        return True
 
     def _paged_quarantine(self, exc: Exception) -> None:
         """Contain a failed launch/admission: the page store's contents
@@ -863,11 +928,10 @@ class ServingEngine:
         with telemetry.span(
             "serving.batch", mode="padded", boundary=batch.boundary,
             size=len(batch.requests),
-            requests=[r.trace.trace_id for r in batch.requests],
-        ):
-            self._run_batch_inner(batch)
+        ) as span:
+            self._run_batch_inner(batch, span)
 
-    def _run_batch_inner(self, batch: Batch) -> None:
+    def _run_batch_inner(self, batch: Batch, span) -> None:
         members = self._take_slots(batch)
         if not members:
             return
@@ -875,6 +939,7 @@ class ServingEngine:
         # exercises the full quarantine path, slot release included.
         seq = self._batch_seq
         self._batch_seq += 1
+        span.set(seq=seq)
         maybe_fault("decode_batch", batch=seq)
         batch_start = self.clock()
         for r in members:
@@ -911,7 +976,7 @@ class ServingEngine:
         real_decode = 0
         for r, row in zip(members, rows):
             r.decode_done_time = decode_done
-            r.trace.note_launch()
+            r.trace.note_launch(seq)
             r.trace.mark("first_token", decode_done)
             new_tokens += len(row) + 1  # emitted ids + the eos/stop token
             real_decode += min(len(row) + 1, self.max_new_tokens)

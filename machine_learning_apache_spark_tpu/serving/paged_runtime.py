@@ -78,6 +78,7 @@ from machine_learning_apache_spark_tpu.serving.kv_pages import (
     PrefixCache,
 )
 from machine_learning_apache_spark_tpu.utils.logging import get_logger
+from machine_learning_apache_spark_tpu.utils.profiling import annotate
 
 log = get_logger(__name__)
 
@@ -300,6 +301,10 @@ class PagedDecodeRuntime:
             kv = jnp.stack([k, v], axis=1)  # [L, 2, width, d]
             return kv.reshape(layers, 2, n_pages, page, d)
 
+        # The program's name is what a device trace's modules line reads
+        # (``jit_paged_prefill_c2``): prefill's device time apart from the
+        # launch's.
+        name = f"paged_prefill_c{chunks}"
         if not mem_quant:
             def fn(params, kv_mem, src, mem_table):
                 kv = project(params, src)
@@ -307,6 +312,7 @@ class PagedDecodeRuntime:
                     kv.astype(kv_mem.dtype)
                 )
 
+            fn.__name__ = fn.__qualname__ = name
             donate = (1,) if self._donate else ()
             return jax.jit(fn, donate_argnums=donate)
 
@@ -329,6 +335,7 @@ class PagedDecodeRuntime:
             mem_scale = mem_scale.at[:, :, mem_table].set(slot_s)
             return kv_mem, mem_scale
 
+        fn.__name__ = fn.__qualname__ = name
         donate = (1, 2) if self._donate else ()
         return jax.jit(fn, donate_argnums=donate)
 
@@ -340,8 +347,8 @@ class PagedDecodeRuntime:
         eos, pad = self.eos_id, self.pad_id
         self_quant = self._self_quant
 
-        def fn(params, kv_self, kv_mem, token, cursor, finished,
-               self_tbl, mem_tbl, mem_len, self_scale, mem_scale):
+        def paged_launch(params, kv_self, kv_mem, token, cursor, finished,
+                         self_tbl, mem_tbl, mem_len, self_scale, mem_scale):
             # Only the self store (and, when self-quantized, its scale
             # plane) rides the scan carry: the mem store and its scales
             # are read-only during decode, so they enter as closed-over
@@ -414,7 +421,7 @@ class PagedDecodeRuntime:
             return kv_self, self_scale, token, cursor, finished, emits
 
         donate = ((1, 9) if self_quant else (1,)) if self._donate else ()
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(paged_launch, donate_argnums=donate)
 
     def jit_fns(self) -> list:
         """Every jitted program, for the engine's compile counting."""
@@ -554,48 +561,55 @@ class PagedDecodeRuntime:
 
     def launch(self) -> LaunchResult:
         """Run one compiled multi-step decode over every row and fold the
-        emitted tokens into per-row transcripts."""
-        out = self._launch_fn(
-            self.params, self.kv_self, self.kv_mem, self._token,
-            self._cursor, self._finished, self._self_tbl, self._mem_tbl,
-            self._mem_len, self.self_scale, self.mem_scale,
-        )
+        emitted tokens into per-row transcripts. Three spans say what the
+        launch's host time is made of: the dispatch, the wait for the chip
+        (until every output is on the host) and the fold."""
+        with annotate("serving.launch.dispatch"):
+            out = self._launch_fn(
+                self.params, self.kv_self, self.kv_mem, self._token,
+                self._cursor, self._finished, self._self_tbl, self._mem_tbl,
+                self._mem_len, self.self_scale, self.mem_scale,
+            )
         self.kv_self = out[0]
         if self._self_quant:
             self.self_scale = out[1]
-        emits = np.asarray(jax.block_until_ready(out[5]))
-        # np.array (copy): host state is mutated by admit/retire, and a
-        # bare asarray view of a jax buffer is read-only.
-        self._token = np.array(out[2])
-        self._cursor = np.array(out[3])
-        self._finished = np.array(out[4])
-        completed, first_emits, real = [], [], 0
-        for r in range(self.max_active):
-            req = self._req_of_row[r]
-            if req is None:
-                continue
-            saw_eos = False
-            for e in emits[:, r]:
-                e = int(e)
-                if e == self.pad_id:
-                    break
-                if self._awaiting_first[r]:
-                    self._awaiting_first[r] = False
-                    first_emits.append(req)
-                real += 1
-                if e == self.eos_id:
-                    saw_eos = True
-                    break
-                self._emitted[r].append(e)
-            if self._finished[r]:
-                completed.append((req, self._emitted[r], r, saw_eos))
+        with annotate("serving.launch.wait"):
+            emits = np.asarray(jax.block_until_ready(out[5]))
+            # np.array (copy): host state is mutated by admit/retire, and
+            # a bare asarray view of a jax buffer is read-only.
+            self._token = np.array(out[2])
+            self._cursor = np.array(out[3])
+            self._finished = np.array(out[4])
+        completed, first_emits, real, rows = [], [], 0, 0
+        with annotate("serving.launch.fold") as phase:
+            for r in range(self.max_active):
+                req = self._req_of_row[r]
+                if req is None:
+                    continue
+                rows += 1
+                saw_eos = False
+                for e in emits[:, r]:
+                    e = int(e)
+                    if e == self.pad_id:
+                        break
+                    if self._awaiting_first[r]:
+                        self._awaiting_first[r] = False
+                        first_emits.append(req)
+                    real += 1
+                    if e == self.eos_id:
+                        saw_eos = True
+                        break
+                    self._emitted[r].append(e)
+                if self._finished[r]:
+                    completed.append((req, self._emitted[r], r, saw_eos))
+            phase.set(rows=rows, real_tokens=real, completed=len(completed))
         return LaunchResult(
             completed=completed,
             first_emits=first_emits,
             real_tokens=real,
             computed_slots=self.max_active * self.steps_per_launch,
             steps=self.steps_per_launch,
-            n_active=self.active_count(),
+            n_active=rows,
         )
 
     # -- retirement / containment -------------------------------------------

@@ -37,8 +37,9 @@ _TRACE_IDS = itertools.count()
 
 
 def _new_trace_id() -> str:
-    """Process-unique, gang-disambiguated request identity: the id a batch
-    span records, a flight dump carries, and /statusz exemplars key on."""
+    """Process-unique, gang-disambiguated request identity: the id a
+    ``serving.request`` annotation records, a flight dump carries, and
+    /statusz exemplars key on."""
     rank = telemetry_events._env_rank()
     prefix = f"r{rank}-" if rank is not None else ""
     return f"{prefix}{os.getpid():x}-{next(_TRACE_IDS):x}"
@@ -56,13 +57,16 @@ class RequestTrace:
 
     When a distributed trace context (``telemetry.tracectx``) is active
     on the submitting thread, the trace **adopts** its 128-bit trace id
-    — so the id a replica returns in its 200 payload, the id the batch
-    span links, and the id the router minted are all the same string —
+    — so the id a replica returns in its 200 payload, the id its
+    ``serving.request`` annotation carries, and the id the router minted
+    are all the same string —
     and keeps the context (``ctx``) so worker-thread emissions (the
     ``serving.request`` annotation) can re-activate it.
     """
 
-    __slots__ = ("trace_id", "marks", "launches", "ctx")
+    __slots__ = (
+        "trace_id", "marks", "launches", "ctx", "first_batch", "last_batch",
+    )
 
     def __init__(self, trace_id: str | None = None, *, ctx=None):
         if ctx is None:
@@ -73,12 +77,21 @@ class RequestTrace:
         self.trace_id = trace_id
         self.marks: list[tuple] = []
         self.launches = 0
+        # ``seq`` of the first and the last decode batch that served this
+        # request: the request's side of the batch-to-request join (a
+        # ``serving.batch`` / ``serving.cycle`` span carries its ``seq``).
+        self.first_batch: int | None = None
+        self.last_batch: int | None = None
 
     def mark(self, name: str, t: float, **attrs) -> None:
         self.marks.append((name, t, attrs or None))
 
-    def note_launch(self, n: int = 1) -> None:
-        self.launches += n
+    def note_launch(self, seq: int | None = None) -> None:
+        self.launches += 1
+        if seq is not None:
+            if self.first_batch is None:
+                self.first_batch = seq
+            self.last_batch = seq
 
     def t(self, name: str) -> float | None:
         """Timestamp of the first mark named ``name`` (None if absent)."""
@@ -101,6 +114,9 @@ class RequestTrace:
         t_first = self.t("first_token")
         t_done = self.t("complete") or self.t("failed") or self.t("expire")
         out: dict = {"trace_id": self.trace_id, "launches": self.launches}
+        if self.first_batch is not None:
+            out["first_batch"] = self.first_batch
+            out["last_batch"] = self.last_batch
         admit_attrs = self.attrs("admit")
         if "kind" in admit_attrs:
             out["prefill"] = admit_attrs["kind"]

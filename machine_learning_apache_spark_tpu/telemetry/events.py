@@ -132,6 +132,10 @@ class EventLog:
             maxlen=max_events
         )
         self.dropped = 0  # evicted-by-the-ring count (visible truncation)
+        # Read once a log, not once an event: ``reset()`` (the fork/spawn
+        # re-arm) drops the log, and its successor reads them again.
+        self.rank = _env_rank()
+        self.pid = os.getpid()
 
     def emit(
         self,
@@ -151,8 +155,8 @@ class EventLog:
             name=name,
             ts=time.monotonic(),
             wall=time.time(),
-            rank=_env_rank(),
-            pid=os.getpid(),
+            rank=self.rank,
+            pid=self.pid,
             span=span,
             parent=parent,
             value=value,

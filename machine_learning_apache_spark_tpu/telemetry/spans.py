@@ -63,6 +63,12 @@ class _Span:
         self.parent: int | None = None
         self._t0 = 0.0
 
+    def set(self, **attrs) -> None:
+        """Attributes known only once the region's work is done (counts):
+        they ride on the ``span_end``. A new dict, because the
+        ``span_start`` event already holds the old one."""
+        self.attrs = {**(self.attrs or {}), **attrs}
+
     def __enter__(self) -> "_Span":
         stack = _stack()
         self.parent = stack[-1] if stack else None
@@ -101,6 +107,9 @@ class _NoopSpan:
     id = None
     parent = None
     attrs = None
+
+    def set(self, **attrs) -> None:
+        return None
 
     def __enter__(self) -> "_NoopSpan":
         return self

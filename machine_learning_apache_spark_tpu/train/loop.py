@@ -26,6 +26,7 @@ from machine_learning_apache_spark_tpu.parallel.mesh import shard_batch
 from machine_learning_apache_spark_tpu.train.metrics import MetricBundle, logits_accuracy
 from machine_learning_apache_spark_tpu.train.state import TrainState
 from machine_learning_apache_spark_tpu.utils.logging import get_logger
+from machine_learning_apache_spark_tpu.utils.profiling import annotate
 from machine_learning_apache_spark_tpu.utils.timing import Timer
 
 log = get_logger(__name__)
@@ -559,6 +560,22 @@ def fit(
     )
 
 
+def _timed_batches(batches: Iterable):
+    """``batches``' items, each pulled inside a ``train.data_wait`` span:
+    the time the loop waited for its loader, one span a step. The pull
+    that finds the loader exhausted says so (``exhausted``) and is no
+    step's wait."""
+    it = iter(batches)
+    while True:
+        with annotate("train.data_wait") as wait:
+            try:
+                batch = next(it)
+            except StopIteration:
+                wait.set(exhausted=True)
+                return
+        yield batch
+
+
 def _run_epochs(
     state, step_fn, train_loader, epochs, rng, mesh, log_every, emit,
     tracer, checkpointer, checkpoint_every, span_timer, sink=None,
@@ -653,7 +670,7 @@ def _run_epochs(
             )
             tracer.on_step(global_step)
             prev = global_step
-            with telemetry.span(
+            with annotate(
                 "train.step_group", start=prev, count=len(group)
             ):
                 # The scanned dispatch covers steps [prev, prev+K): check
@@ -680,7 +697,7 @@ def _run_epochs(
                 batch = shard_batch(mesh, batch)
             rng, step_rng = jax.random.split(rng)
             tracer.on_step(global_step)
-            with telemetry.span("train.step", step=global_step):
+            with annotate("train.step", step=global_step):
                 maybe_fault("train_step", step=global_step)
                 state, loss, aux = step_fn(state, batch, step_rng)
             global_step += 1
@@ -693,7 +710,7 @@ def _run_epochs(
             if use_prefetch
             else train_loader
         )
-        for batch in epoch_iter:
+        for batch in _timed_batches(epoch_iter):
             if multi_fn is not None:
                 group.append(batch)
                 if len(group) == steps_per_call:

@@ -5,7 +5,6 @@ from machine_learning_apache_spark_tpu.utils.profiling import (
     StepWindowTracer,
     annotate,
     device_trace,
-    step_annotation,
 )
 
 __all__ = [
@@ -18,5 +17,4 @@ __all__ = [
     "StepWindowTracer",
     "annotate",
     "device_trace",
-    "step_annotation",
 ]

@@ -77,16 +77,17 @@ class _AnnotatedRegion:
         self._trace.__exit__(*exc)
         self._span.__exit__(*exc)
 
+    def set(self, **attrs) -> None:
+        """Counts known when the region ends, onto the span's
+        ``span_end``. The trace annotation keeps its bare name: a reader
+        of the trace finds a region by that name."""
+        self._span.set(**attrs)
+
 
 def annotate(name: str, **kwargs):
     """Named region annotation appearing on the trace timeline (and, when
     telemetry is enabled, as a span on the event log)."""
     return _AnnotatedRegion(name, **kwargs)
-
-
-def step_annotation(step: int):
-    """Marks one training step; XProf groups per-step statistics by these."""
-    return jax.profiler.StepTraceAnnotation("train_step", step_num=step)
 
 
 class StepWindowTracer:

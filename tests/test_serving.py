@@ -1042,8 +1042,8 @@ class TestObservabilityPlane:
     def test_request_trace_timeline_end_to_end(self, tiny_translator, kv_mode):
         """Every request carries a trace from submit to completion: the
         mark vocabulary is present in order, the derived breakdown is
-        sane, batch spans record their members' trace ids, and the
-        engine keeps the slowest traces as exemplars."""
+        sane, each request's annotation names the batches that served it,
+        and the engine keeps the slowest traces as exemplars."""
         from machine_learning_apache_spark_tpu import telemetry
 
         t, texts = tiny_translator
@@ -1067,16 +1067,32 @@ class TestObservabilityPlane:
                 assert bd["service_s"] > 0.0
                 assert bd["total_s"] >= bd["ttft_s"]
                 assert f.trace.launches >= 1
-            # decode spans name their members — the batch↔request join
-            spans_with_members = [
-                e for e in telemetry.get_log().snapshot()
-                if e.name == "serving.batch" and (e.attrs or {}).get("requests")
-            ]
-            assert spans_with_members
-            seen = set()
-            for e in spans_with_members:
-                seen.update(e.attrs["requests"])
-            assert ids <= seen
+            # the batch↔request join, kept from the request's side: every
+            # ``serving.request`` annotation names the first and the last
+            # batch (``seq``) that served it, and each is a batch the log
+            # holds.
+            events = telemetry.get_log().snapshot()
+            batch_seqs = {
+                e.attrs["seq"] for e in events
+                if e.kind == "span_end" and e.name == "serving.batch"
+            }
+            assert batch_seqs
+            assert not any(
+                "requests" in (e.attrs or {}) for e in events
+                if e.name == "serving.batch"
+            )
+            joined = {
+                e.attrs["trace_id"]: e.attrs for e in events
+                if e.kind == "annotation" and e.name == "serving.request"
+            }
+            assert ids <= set(joined)
+            for f in futs:
+                a = joined[f.trace.trace_id]
+                assert a["first_batch"] in batch_seqs
+                assert a["last_batch"] in batch_seqs
+                assert (
+                    a["last_batch"] - a["first_batch"] + 1 >= a["launches"] >= 1
+                )
             # slowest-request exemplars, sorted worst-first
             ex = eng.metrics.request_exemplars()
             assert 1 <= len(ex) <= 8

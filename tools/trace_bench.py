@@ -69,9 +69,10 @@ SMOKE_OVERHEAD_FLOOR = 0.75
 COMPLETE_FLOOR = 0.99
 
 #: Ring budget covering every event the traced sweep emits (3 events
-#: per request plus batch spans); sized so completeness is measured
-#: over the whole run, not a ring tail.
-EVENT_RING = 262144
+#: per request plus 24 per decode cycle: the cycle's phase spans and its
+#: token counters); sized so completeness is measured over the whole
+#: run, not a ring tail.
+EVENT_RING = 524288
 
 
 def _reset_tracing(value: str) -> None:
