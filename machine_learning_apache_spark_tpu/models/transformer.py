@@ -300,7 +300,9 @@ class MultiHeadAttention(nn.Module):
                 causal = False
 
         # Structured (causal/kv_valid) masks stream through the Pallas flash
-        # kernel on TPU; a dense mask falls back to the fused-XLA path.
+        # kernel on TPU once the site is long enough for it to pay
+        # (ops.attention.FLASH_MIN_SCORES scores a head); a shorter site and
+        # a dense mask take the fused-XLA path.
         out = dot_product_attention(
             split_heads(q, s_q),
             split_heads(k, s_kv),

@@ -12,8 +12,13 @@ Two implementations behind one signature:
   and tiles the matmuls onto the MXU. Works on every backend.
 - ``machine_learning_apache_spark_tpu.ops.pallas_attention.flash_attention`` —
   blockwise online-softmax Pallas kernel for TPU (never materializes the
-  [S, S] score matrix). ``dot_product_attention(..., use_pallas=True)``
-  dispatches to it on TPU.
+  [S, S] score matrix).
+
+``dot_product_attention`` chooses between them per site from the operand
+shapes: on TPU a structured-mask site of ``FLASH_MIN_SCORES`` scores a head
+or more runs the kernel, forward and backward; a shorter one runs the dense
+path in both directions (the kernel's launches cost more than the score
+matrix they avoid).
 
 The blockwise structure is the design seam for ring/sequence-parallel
 attention (SURVEY.md §5 long-context): the same per-block accumulator runs
@@ -24,6 +29,7 @@ under ``shard_map`` with K/V blocks rotating over ICI
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +37,24 @@ import jax.numpy as jnp
 from machine_learning_apache_spark_tpu import telemetry
 
 NEG_INF = -1e30  # finite -inf stand-in: keeps fully-masked rows NaN-free
+
+# Scores a head (q_len * kv_len) from which a structured-mask site runs the
+# flash kernel, forward and backward alike; under it the fused-XLA dense
+# path is both affordable and faster than the kernel's launches. A sweep on
+# a v5e (PERF.md section 6, PR 25; tools/attention_gate_sweep.py) read dense
+# the faster well past this number at the sweep's token budget, but the gate
+# sees scores a head, not rows: from here the float32 [B, H, Sq, Sk]
+# temporaries can decide, and the kernel's backward takes O(S) memory.
+FLASH_MIN_SCORES = 256 * 1024
+
+
+def flash_pays(q_len: int, kv_len: int) -> tuple[bool, str]:
+    """Whether a site of these lengths is at or over ``FLASH_MIN_SCORES``,
+    and the comparison spelled out for ``record_dispatch``."""
+    pays = q_len * kv_len >= FLASH_MIN_SCORES
+    return pays, (
+        f"{q_len}x{kv_len} scores {'>=' if pays else '<'} {FLASH_MIN_SCORES}"
+    )
 
 
 def record_dispatch(site: str, impl: str, reason: str, **shape) -> None:
@@ -119,8 +143,9 @@ def active_kernel_mesh():
 
 
 # Forced implementation override for ``dot_product_attention``'s auto
-# dispatch (a stack so contexts nest). None = auto (flash on TPU for
-# structured masks, dense-XLA otherwise).
+# dispatch (a stack so contexts nest). None = auto (on TPU, flash for
+# structured masks from ``FLASH_MIN_SCORES`` scores a head; dense-XLA
+# otherwise).
 _FORCED_IMPL: list[str] = []
 
 
@@ -144,6 +169,16 @@ def attention_impl(impl: str):
         _FORCED_IMPL.pop()
 
 
+def _weights_from_scores(scores, d_k: int, mask, dtype) -> jnp.ndarray:
+    scores = scores / jnp.sqrt(jnp.asarray(d_k, dtype=scores.dtype))
+    if mask is not None:
+        scores = jnp.where(mask, scores, NEG_INF)
+    # Softmax in float32 regardless of compute dtype: bfloat16 exp/renorm
+    # loses enough precision to hurt training at long sequence lengths.
+    weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    return weights.astype(dtype)
+
+
 def multi_head_attention_weights(
     query: jnp.ndarray,
     key: jnp.ndarray,
@@ -152,15 +187,8 @@ def multi_head_attention_weights(
     """``softmax(QKᵀ/√d)`` with boolean masking — the first half of
     ``scaled_dot_product`` (``transformer.py:17-24``), returned separately
     because the reference also returns the attention map."""
-    d_k = query.shape[-1]
     scores = jnp.einsum("...qd,...kd->...qk", query, key)
-    scores = scores / jnp.sqrt(jnp.asarray(d_k, dtype=scores.dtype))
-    if mask is not None:
-        scores = jnp.where(mask, scores, NEG_INF)
-    # Softmax in float32 regardless of compute dtype: bfloat16 exp/renorm
-    # loses enough precision to hurt training at long sequence lengths.
-    weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    return weights.astype(query.dtype)
+    return _weights_from_scores(scores, query.shape[-1], mask, query.dtype)
 
 
 def scaled_dot_product_attention(
@@ -331,11 +359,19 @@ def dot_product_attention(
       blockwise kernel);
     - structured ``causal`` + ``kv_valid`` (``[B, S_k]`` per-key validity,
       the padding-mask case) — exactly the masks the zoo Transformer needs,
-      streamed through the Pallas flash kernel on TPU without ever
-      materializing ``[B, Sq, Sk]``.
+      which the Pallas flash kernel streams without ever materializing
+      ``[B, Sq, Sk]``.
 
-    ``use_pallas=None`` auto-selects the flash kernel on TPU whenever the
-    mask is structured-only.
+    ``use_pallas=None`` chooses for a structured-mask site from its operand
+    shapes alone: on TPU, ``q_len * kv_len >= FLASH_MIN_SCORES`` runs the
+    flash kernel (forward and Pallas backward); a shorter site runs the
+    dense path in both directions; other backends always do. A
+    structured-mask site on the dense path, chosen or forced, is
+    rematerialized: it saves ``q``, ``k``, ``v`` and ``kv_valid`` for the
+    backward and recomputes the probabilities, as the kernel's
+    short-sequence backward always has, so no ``[B, H, Sq, Sk]`` array
+    outlives the forward. Such a site averages the values over a query row
+    whose keys are all masked, where the kernel emits zeros.
 
     Under an active ``sequence_parallel(mesh)`` context, structured-mask
     *self-attention* (Sq == Sk, divisible by the seq axis) dispatches to
@@ -398,9 +434,11 @@ def dot_product_attention(
     elif _FORCED_IMPL:
         reason = f"attention_impl({_FORCED_IMPL[-1]!r})"
         use_pallas = _FORCED_IMPL[-1] == "flash"
-    else:
+    elif jax.default_backend() != "tpu":
         reason = f"structured mask, backend {jax.default_backend()}"
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = False
+    else:
+        use_pallas, reason = flash_pays(query.shape[2], key.shape[2])
     use_pallas = bool(use_pallas) and mask is None
     record_dispatch(
         "dot_product", "pallas_flash" if use_pallas else "xla_dense", reason,
@@ -414,6 +452,53 @@ def dot_product_attention(
         return flash_attention(
             query, key, value, causal=causal, kv_valid=kv_valid
         )
+    dense = functools.partial(_dense_attention, causal=causal)
+    if mask is None:
+        # What the kernel's short-sequence backward always kept: q, k, v
+        # and kv_valid, probabilities recomputed. Plain autodiff would save
+        # a float32 and a compute-dtype [B, H, Sq, Sk] array a site.
+        dense = jax.checkpoint(dense)
+    return dense(query, key, value, mask, kv_valid)
+
+
+@jax.custom_vjp
+def _float32_scores(query: jnp.ndarray, key: jnp.ndarray) -> jnp.ndarray:
+    """``QKᵀ`` kept in float32 whatever the compute dtype, as the flash
+    kernel keeps it: the product accumulates in float32 anyway, and a site
+    that the shape gate takes from the kernel must not round it to bfloat16
+    ahead of the softmax. The backward rounds the scores' cotangent to the
+    operands' dtype before its two products, again as the kernels do
+    (``ds.astype(k.dtype)``): plain transposition would feed the MXU a
+    float32 ``[..., Sq, Sk]`` operand. Leading dims of ``query`` and
+    ``key`` must agree."""
+    return jnp.einsum(
+        "...qd,...kd->...qk", query, key,
+        preferred_element_type=jnp.result_type(query, key, jnp.float32),
+    )
+
+
+def _float32_scores_fwd(query, key):
+    return _float32_scores(query, key), (query, key)
+
+
+def _float32_scores_bwd(res, g):
+    query, key = res
+    return (
+        jnp.einsum("...qk,...kd->...qd", g.astype(key.dtype), key),
+        jnp.einsum("...qk,...qd->...kd", g.astype(query.dtype), query),
+    )
+
+
+_float32_scores.defvjp(_float32_scores_fwd, _float32_scores_bwd)
+
+
+def _dense_attention(query, key, value, mask, kv_valid, *, causal):
+    """The fused-XLA path of ``dot_product_attention``: the structured masks
+    folded into one dense boolean mask over the materialized scores, which
+    stay float32 from the product on. (``scaled_dot_product_attention``,
+    the paged decode's core, keeps the product in the compute dtype: one
+    query row a request is no MXU product, and float32 scores there cost a
+    float32 copy of every gathered page.)"""
     from machine_learning_apache_spark_tpu.ops.masks import (
         combine_masks,
         make_causal_mask,
@@ -425,4 +510,7 @@ def dot_product_attention(
         mask = combine_masks(
             mask, make_causal_mask(query.shape[-2], key.shape[-2])
         )
-    return scaled_dot_product_attention(query, key, value, mask)
+    weights = _weights_from_scores(
+        _float32_scores(query, key), query.shape[-1], mask, query.dtype
+    )
+    return jnp.einsum("...qk,...kd->...qd", weights, value)

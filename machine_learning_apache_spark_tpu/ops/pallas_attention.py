@@ -176,9 +176,12 @@ def flash_attention(
     saves per-row log-sum-exp statistics; the backward recomputes block
     probabilities from them in two more Pallas launches (flash-2 style dq
     and dk/dv kernels) — O(S) memory in both directions, which is what makes
-    long-context *training* affordable. Below ``PALLAS_BWD_MIN_SCORES``
-    score elements the backward falls back to the fused-XLA dense recompute
-    (cheaper than two kernel launches at short sequence lengths).
+    long-context *training* affordable. Below
+    ``ops.attention.FLASH_MIN_SCORES`` score elements a head the backward
+    falls back to the fused-XLA dense recompute (cheaper than two kernel
+    launches at short sequence lengths). ``dot_product_attention``'s
+    auto-dispatch sends such a site to the dense path in the forward too, so
+    that hybrid is reached only by calling this function directly.
     """
     cfg = (causal, block_q, block_k, interpret)
     if kv_valid is None:
@@ -197,30 +200,32 @@ def _dense_reference(query, key, value, causal, kv_valid):
     )
 
 
-# Below this many score-matrix elements the fused-XLA dense recompute is
-# both affordable and faster than a second kernel launch pair; above it the
-# blockwise backward avoids materializing [S_q, S_k] chains entirely (the
-# long-context training seam).
-PALLAS_BWD_MIN_SCORES = 256 * 1024
-
-
 def _use_pallas_bwd(q_len: int, kv_len: int) -> bool:
-    return q_len * kv_len >= PALLAS_BWD_MIN_SCORES
+    """Under ``ops.attention.FLASH_MIN_SCORES`` scores a head the fused-XLA
+    dense recompute is both affordable and faster than a second kernel
+    launch pair; from it on the blockwise backward avoids materializing
+    [S_q, S_k] chains entirely (the long-context training seam). The same
+    number gates the forward in ``dot_product_attention``'s auto-dispatch,
+    so only a caller that forces the kernel reaches the flash forward with
+    the dense backward."""
+    from machine_learning_apache_spark_tpu.ops.attention import flash_pays
+
+    return flash_pays(q_len, kv_len)[0]
 
 
 def _choose_bwd(q_len: int, kv_len: int) -> bool:
     """``_use_pallas_bwd`` for the custom_vjp forward rules, which run once
     per traced differentiated program: records the choice."""
     from machine_learning_apache_spark_tpu.ops.attention import (
+        flash_pays,
         record_dispatch,
     )
 
-    use = _use_pallas_bwd(q_len, kv_len)
+    use, reason = flash_pays(q_len, kv_len)
     record_dispatch(
         "flash_backward",
         "pallas_flash_bwd" if use else "xla_dense_recompute",
-        f"{q_len}x{kv_len} scores {'>=' if use else '<'} "
-        f"{PALLAS_BWD_MIN_SCORES}",
+        reason,
         q_len=q_len, kv_len=kv_len,
     )
     return use

@@ -62,6 +62,7 @@ def test_rehearsal_runs_every_phase_and_is_stamped_cpu(tmp_path):
     serve = report["phases"]["serve"]
     assert serve["recompiles_after_warmup"] == 0
     assert serve["token_agreement"] == 1.0  # float32: token-identical
+    assert serve["first_disagreements"] == []
     assert serve["engine_devices"] == [0]  # one engine, one device
     assert report["phases"]["kernels"]["mode"] == "interpret"
     assert report["phases"]["train"]["mesh_devices"] == 8

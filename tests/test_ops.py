@@ -220,6 +220,242 @@ class TestAttentionImplOverride:
                 pass
 
 
+def _last_dot_product_dispatch():
+    from machine_learning_apache_spark_tpu import telemetry
+
+    return [
+        e.attrs for e in telemetry.get_log().snapshot()
+        if e.kind == "annotation" and e.name == "ops.attention_dispatch"
+        and e.attrs["site"] == "dot_product"
+    ][-1]
+
+
+def _reference_attention(q, k, v, mask):
+    """Float32 attention with nothing of the package in it: the product,
+    the mask, the softmax, plain autodiff."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(jnp.float32(q.shape[-1]))
+    if mask is not None:
+        s = jnp.where(mask, s, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _sub_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _sub_eqns(inner)
+
+
+class TestFlashGate:
+    """``dot_product_attention``'s auto-dispatch on TPU: a structured-mask
+    site runs the flash kernel from ``FLASH_MIN_SCORES`` scores a head and
+    the rematerialized dense path under it, chosen from the shapes alone."""
+
+    @pytest.fixture
+    def on_tpu(self, monkeypatch):
+        """The dispatcher believes it is on a TPU; the kernel is a spy that
+        answers with the dense path (nothing here compiles for Mosaic)."""
+        import machine_learning_apache_spark_tpu.ops.pallas_attention as pa
+
+        calls = []
+
+        def fake_flash(q, k, v, **kw):
+            calls.append(kw)
+            return scaled_dot_product_attention(q, k, v)
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(pa, "flash_attention", fake_flash)
+        return calls
+
+    @pytest.mark.parametrize(
+        "q_len,kv_len,impl",
+        [
+            (200, 200, "xla_dense"),   # ref_train_1chip's sites
+            (256, 256, "xla_dense"),   # big_train_dp4's
+            (1, 200, "xla_dense"),     # a KV-cache decode step
+            (511, 512, "xla_dense"),   # 512 scores under
+            (128, 2047, "xla_dense"),  # 128 under, rectangular
+            (512, 512, "pallas_flash"),   # exactly at
+            (2048, 128, "pallas_flash"),  # exactly at, rectangular
+            (512, 4096, "pallas_flash"),
+        ],
+    )
+    def test_scores_a_head_choose_the_side(self, on_tpu, q_len, kv_len, impl):
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            FLASH_MIN_SCORES,
+            dot_product_attention,
+        )
+
+        q = jnp.ones((1, 1, q_len, 8), jnp.float32)
+        k = jnp.ones((1, 1, kv_len, 8), jnp.float32)
+        dot_product_attention(q, k, k, kv_valid=jnp.ones((1, kv_len), bool))
+        noted = _last_dot_product_dispatch()
+        assert noted["impl"] == impl
+        sign = ">=" if impl == "pallas_flash" else "<"
+        assert noted["reason"] == (
+            f"{q_len}x{kv_len} scores {sign} {FLASH_MIN_SCORES}"
+        )
+        assert len(on_tpu) == (impl == "pallas_flash")
+
+    @pytest.mark.parametrize(
+        "seq,forced,impl,reason",
+        [
+            (16, dict(use_pallas=True), "pallas_flash", "caller-selected"),
+            (16, dict(ctx="flash"), "pallas_flash", "attention_impl('flash')"),
+            (512, dict(use_pallas=False), "xla_dense", "caller-selected"),
+            (512, dict(ctx="dense"), "xla_dense", "attention_impl('dense')"),
+            (512, dict(mask=True), "xla_dense", "dense mask"),
+            (512, dict(mask=True, ctx="flash"), "xla_dense", "dense mask"),
+        ],
+    )
+    def test_forced_paths_win_over_the_gate(
+        self, on_tpu, seq, forced, impl, reason
+    ):
+        import contextlib
+
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            attention_impl,
+            dot_product_attention,
+        )
+
+        q = jnp.ones((1, 1, seq, 8), jnp.float32)
+        ctx = (
+            attention_impl(forced["ctx"]) if "ctx" in forced
+            else contextlib.nullcontext()
+        )
+        with ctx:
+            dot_product_attention(
+                q, q, q,
+                mask=make_causal_mask(seq) if forced.get("mask") else None,
+                causal=not forced.get("mask"),
+                use_pallas=forced.get("use_pallas"),
+            )
+        noted = _last_dot_product_dispatch()
+        assert (noted["impl"], noted["reason"]) == (impl, reason)
+        assert len(on_tpu) == (impl == "pallas_flash")
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("use_valid", [False, True])
+    def test_gated_grads_match_float32_reference(
+        self, rng, on_tpu, causal, use_valid
+    ):
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            dot_product_attention,
+        )
+
+        b, h, s, d = 2, 2, 64, 16
+        q, k, v = (
+            jnp.asarray(rng.standard_normal((b, h, s, d)), dtype=jnp.float32)
+            for _ in range(3)
+        )
+        kv_valid = jnp.asarray(rng.random((b, s)) < 0.8) if use_valid else None
+        if use_valid:
+            kv_valid = kv_valid.at[:, 0].set(True)  # no fully masked row
+        mask = combine_masks(
+            make_causal_mask(s) if causal else None,
+            kv_valid[:, None, None, :] if use_valid else None,
+        )
+
+        def grads(fn):
+            return jax.grad(
+                lambda q, k, v: jnp.sum(fn(q, k, v) ** 2), argnums=(0, 1, 2)
+            )(q, k, v)
+
+        gated = grads(lambda q, k, v: dot_product_attention(
+            q, k, v, causal=causal, kv_valid=kv_valid
+        ))
+        assert _last_dot_product_dispatch()["impl"] == "xla_dense"
+        assert on_tpu == []
+        reference = grads(lambda q, k, v: _reference_attention(q, k, v, mask))
+        for name, a, e in zip("qkv", gated, reference):
+            scale = float(jnp.max(jnp.abs(e))) + 1e-9
+            err = float(jnp.max(jnp.abs(a - e))) / scale
+            assert err < 1e-4, f"d{name} relative error {err}"
+
+    def test_dense_site_saves_no_score_matrix(self, on_tpu):
+        """The residuals of a structured-mask site on the dense path, gated
+        or forced, are what the kernel's dense backward kept — q, k, v,
+        kv_valid — and never a [B, H, Sq, Sk] array; a dense-mask site,
+        plain autodiff, does save them."""
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            dot_product_attention,
+        )
+
+        b, h, sq, sk, d = 2, 3, 40, 56, 8
+        q = jnp.ones((b, h, sq, d), jnp.bfloat16)
+        k = jnp.ones((b, h, sk, d), jnp.bfloat16)
+        kv_valid = jnp.ones((b, sk), bool)
+
+        def residual_shapes(**kw):
+            kw.setdefault("kv_valid", kv_valid)
+            _, vjp = jax.vjp(
+                lambda q, k, v: dot_product_attention(q, k, v, **kw), q, k, k
+            )
+            return [x.shape for x in jax.tree.leaves(vjp)]
+
+        gated = residual_shapes()
+        assert _last_dot_product_dispatch()["reason"].startswith("40x56 scores <")
+        forced = residual_shapes(use_pallas=False)
+        assert _last_dot_product_dispatch()["reason"] == "caller-selected"
+        for saved in (gated, forced):
+            assert (b, h, sq, d) in saved and (b, h, sk, d) in saved
+            assert not [shape for shape in saved if shape[-2:] == (sq, sk)]
+        masked = residual_shapes(
+            kv_valid=None, mask=jnp.ones((b, 1, sq, sk), bool)
+        )
+        assert (b, h, sq, sk) in masked
+
+    def test_score_product_is_float32_for_bfloat16_operands(self):
+        """``dot_product_attention``'s dense path keeps QK^T in float32 as
+        the kernel does, and its backward feeds its two products the
+        operands' dtype, as the kernels do; the pure-jnp core under the
+        paged decode keeps the compute dtype."""
+        from machine_learning_apache_spark_tpu.ops import (
+            multi_head_attention_weights,
+        )
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            dot_product_attention,
+        )
+
+        q = jnp.ones((2, 2, 24, 8), jnp.bfloat16)
+        k = jnp.ones((2, 2, 40, 8), jnp.bfloat16)
+        scores = (2, 2, 24, 40)
+
+        def dots(fn, *args):
+            return [
+                e for e in _sub_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+                if e.primitive.name == "dot_general"
+            ]
+
+        def dense(q, k, v):
+            return dot_product_attention(q, k, v, causal=True, use_pallas=False)
+
+        assert dense(q, k, k).dtype == jnp.bfloat16
+        assert {
+            e.outvars[0].aval.shape: e.outvars[0].aval.dtype
+            for e in dots(dense, q, k, k)
+        } == {scores: jnp.float32, (2, 2, 24, 8): jnp.bfloat16}
+        assert [
+            e.outvars[0].aval.dtype
+            for e in dots(multi_head_attention_weights, q, k)
+        ] == [jnp.bfloat16]
+
+        backward = dots(
+            lambda q, k, v, g: jax.vjp(dense, q, k, v)[1](g),
+            q, k, k, jnp.ones((2, 2, 24, 8), jnp.bfloat16),
+        )
+        fed_scores = [
+            e for e in backward
+            if scores in [v.aval.shape for v in e.invars]
+        ]
+        assert len(fed_scores) == 4  # the forward's PV, then dv, dq and dk
+        for e in fed_scores:
+            assert {v.aval.dtype for v in e.invars} == {jnp.dtype(jnp.bfloat16)}
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_xla_path(self, rng, causal):
@@ -342,7 +578,7 @@ class TestFlashBackward:
     grads must match the dense XLA path on shapes above the pallas-backward
     threshold, across structured-mask configurations."""
 
-    SHAPE = (1, 2, 512, 32)  # 512×512 scores ≥ PALLAS_BWD_MIN_SCORES
+    SHAPE = (1, 2, 512, 32)  # 512×512 scores ≥ FLASH_MIN_SCORES
 
     def _grads(self, fn, *args):
         return jax.grad(

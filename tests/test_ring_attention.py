@@ -158,6 +158,27 @@ class TestSequenceParallelDispatch:
             ring = dot_product_attention(q, k, v, causal=True, kv_valid=kv_valid)
         np.testing.assert_allclose(np.asarray(ring), np.asarray(dense), atol=1e-5)
 
+    def test_context_wins_over_the_shape_gate(self, dp_sp_mesh, monkeypatch):
+        """On a TPU a site this short would go to the dense path by its
+        shape; an active sequence-parallel context is asked first."""
+        from machine_learning_apache_spark_tpu import telemetry
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            dot_product_attention,
+            sequence_parallel,
+        )
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        q, k, v = qkv(b=4, s=16)
+        with sequence_parallel(dp_sp_mesh):
+            ring = dot_product_attention(q, k, v, causal=True)
+        noted = [
+            e.attrs for e in telemetry.get_log().snapshot()
+            if e.name == "ops.attention_dispatch"
+        ][-1]
+        assert (noted["site"], noted["impl"]) == ("dot_product", "ring")
+        dense = scaled_dot_product_attention(q, k, v, make_causal_mask(16))
+        np.testing.assert_allclose(np.asarray(ring), np.asarray(dense), atol=1e-5)
+
     def test_ragged_batch_falls_through(self, dp_sp_mesh):
         """A batch that doesn't fill the mesh's data axis (evaluate's ragged
         tail) must fall through to the dense path, not crash shard_map."""
