@@ -74,6 +74,7 @@ class Size:
     flash_fwd_shape: tuple  # (B, H, S, d)
     flash_bwd_shape: tuple  # S*S >= ops.attention.FLASH_MIN_SCORES
     paged_heads: int
+    scan_shape: tuple  # (B, T, key heads, value heads, dk, dv, chunk)
 
 
 FULL = Size(
@@ -84,7 +85,7 @@ FULL = Size(
     zero_pairs_per_chip=256,
     interpret=False, kernel_dtype="bfloat16", tolerance=0.05,
     flash_fwd_shape=(2, 8, 200, 64), flash_bwd_shape=(1, 2, 512, 64),
-    paged_heads=8,
+    paged_heads=8, scan_shape=(2, 200, 2, 4, 128, 128, 64),
 )
 TOY = Size(
     name="rehearsal",
@@ -97,7 +98,7 @@ TOY = Size(
     zero_pairs_per_chip=32,
     interpret=True, kernel_dtype="float32", tolerance=1e-4,
     flash_fwd_shape=(2, 2, 40, 16), flash_bwd_shape=(1, 1, 512, 16),
-    paged_heads=2,
+    paged_heads=2, scan_shape=(1, 40, 1, 2, 16, 8, 16),
 )
 
 PHASES: dict = {}  # name -> {"ok", "seconds", observations...}
@@ -520,6 +521,10 @@ def run_kernels(size: Size) -> None:
         kernel_mesh,
         ragged_paged_attention,
     )
+    from machine_learning_apache_spark_tpu.ops.gated_delta import (
+        gated_delta_recurrent,
+        gated_delta_rule,
+    )
     from machine_learning_apache_spark_tpu.ops.pallas_attention import (
         _use_pallas_bwd,
         flash_attention,
@@ -599,8 +604,42 @@ def run_kernels(size: Size) -> None:
         return err(got, dot_product_attention(q, k, v, causal=True,
                                               use_pallas=False))
 
+    def scan_case() -> dict:
+        """The chunked gated delta rule, forward and backward, against its
+        per-token recurrence, in float32 at ``Precision.HIGHEST`` (a length
+        that is no multiple of the chunk): not a Pallas kernel, but the op
+        a new jax must still compile and transpose on the chip."""
+        b, t, hk, hv, dk, dv, chunk = size.scan_shape
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+        q = unit(rnd((b, t, hk, dk), 0, f32)) * dk ** -0.5
+        k = unit(rnd((b, t, hk, dk), 1, f32))
+        v, w = rnd((b, t, hv, dv), 2, f32), rnd((b, t, hv, dv), 3, f32)
+        g = -0.1 * jax.nn.sigmoid(rnd((b, t, hv), 4, f32))
+        beta = jax.nn.sigmoid(rnd((b, t, hv), 5, f32))
+
+        def both(rule):
+            def loss(*a):
+                return jnp.sum(rule(*a)[0] * w)
+            return jax.jit(lambda *a: (
+                rule(*a)[0], jax.grad(loss, argnums=range(5))(*a)
+            ))(q, k, v, g, beta)
+
+        out, grads = both(lambda *a: gated_delta_rule(*a, chunk=chunk))
+        want, want_grads = both(gated_delta_recurrent)
+        found = {"gated_delta_fwd[float32]": err(out, want)}
+        for name, a, r in zip(("dq", "dk", "dv", "dg", "dbeta"), grads, want_grads):
+            found[f"gated_delta_bwd_{name}[float32]"] = err(a, r)
+        return found
+
     with phase("kernels") as info:
         results: dict = {}
+        scan = scan_case()
+        info["gated_delta_max_abs_err_vs_recurrence"] = {
+            k: round(v, 7) for k, v in scan.items()
+        }
+        bad = {k: v for k, v in scan.items() if not v <= 1e-3}
+        require(not bad, f"chunked gated delta rule agrees with the "
+                f"recurrence within 1e-3 in float32: {bad}")
         for causal in (False, True):
             for masked in (False, True):
                 results.update(flash_case(causal, masked, dtype))

@@ -1,4 +1,5 @@
-"""Model zoo — MLP, CNN, LSTM, encoder-decoder Transformer.
+"""Model zoo — MLP, CNN, LSTM, encoder-decoder Transformer, hybrid
+linear/softmax-attention language model with sparse experts.
 
 One library replacing the reference's copy-pasted per-script model classes
 (C2/C5/C8 duplicated across sequential/distributed scripts, SURVEY.md §2.1)
@@ -18,8 +19,17 @@ from machine_learning_apache_spark_tpu.models.transformer import (
     Decoder,
     TransformerConfig,
 )
+from machine_learning_apache_spark_tpu.models.hybrid_lm import (
+    HybridLM,
+    HybridLMConfig,
+)
+from machine_learning_apache_spark_tpu.models.moe import DroplessMoE, MoEFeedForward
 
 __all__ = [
+    "HybridLM",
+    "HybridLMConfig",
+    "DroplessMoE",
+    "MoEFeedForward",
     "MLP",
     "TinyVGG",
     "FashionMNISTModel",

@@ -36,6 +36,7 @@ balancing).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import flax.linen as nn
@@ -166,3 +167,223 @@ class MoEFeedForward(nn.Module):
         self.sow("losses", "moe_aux", aux)
 
         return out
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k routing over the experts held here
+# ---------------------------------------------------------------------------
+
+
+def route_top_k(logits: jnp.ndarray, k: int, *, renormalize: bool = True):
+    """Softmax over all experts in float32, the ``k`` largest a token and
+    their weights (renormalised to sum to one where ``renormalize``).
+    ``logits [N, E]`` -> ``(probs [N, E], experts [N, k], weights [N, k])``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return probs, experts, weights
+
+
+# Rows a segment of ``DroplessMoE`` holds, over the N k held / E assignments
+# its share expects. At 1.5 the full-size cell (163,840 assignments a layer,
+# 32 of 512 experts held: 10,240 expected, 15,360 rows a segment) ran one
+# segment in every step read on the chip, at 10,300-10,500 local rows
+# (PERF.md section 6, PR 26); a batch with more runs further segments.
+_LOCAL_ROWS_SLACK = 1.5
+
+
+def _grouped_swiglu(xs, sizes, w_gate, w_up, w_down):
+    """Rows of ``xs [M, d]`` sorted by expert, ``sizes [held]`` rows an
+    expert (rows past their sum belong to no expert: what comes out for
+    them is the backend's, zeros on the CPU and garbage on the TPU, and the
+    caller masks them):
+    ``(silu(x Wg) * (x Wu)) Wd`` with each row's own expert's matrices."""
+    dot = functools.partial(
+        jax.lax.ragged_dot, group_sizes=sizes,
+        preferred_element_type=jnp.float32,
+    )
+    h = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+    return dot(h.astype(xs.dtype), w_down)
+
+
+class DroplessMoE(nn.Module):
+    """Top-k mixture of SwiGLU experts as deployed today: softmax router
+    over all ``num_experts``, ``top_k`` experts a token, no capacity and no
+    dropped token, one always-on shared expert behind a sigmoid gate.
+
+    **The share.** The layer holds ``experts_held = (first, count)`` of the
+    ``num_experts`` (expert parallelism's cut: the chip's experts). It routes
+    over all of them, keeps the assignments that fall on its own experts,
+    and returns their part of the result plus the shared expert's; what
+    absent experts would add is left out, as their chips would add it.
+    ``(0, num_experts)`` is the whole layer. Nothing here stands in for the
+    exchange between chips.
+
+    **How.** Assignments ``(token, choice)`` are sorted by local expert
+    (those of absent experts sort last); ``M`` sorted tokens at a time are
+    gathered, three grouped products (``jax.lax.ragged_dot``) apply each
+    row's expert, and a weighted scatter-add returns rows to their tokens.
+    ``M`` is static: ``_LOCAL_ROWS_SLACK`` times the ``N * top_k * count /
+    num_experts`` assignments a share expects. All ``N * top_k`` can be
+    local, so the layer loops over as many segments of ``M`` as that takes
+    and **skips the empty ones** (``lax.cond`` on the count): the expected
+    batch costs one segment, a lopsided one costs more, and none drops a
+    token. Two counters say so from either end:
+    ``stats["assignments_local"]`` counts the router's choices that name a
+    held expert, ``stats["assignments_computed"]`` adds up, inside the
+    segments that ran, the group sizes the grouped products were given. A
+    segment skipped or cut short shows as a difference between them.
+
+    Input ``[B, S, d]``; returns ``(out [B, S, d], stats)`` with float32
+    scalars ``aux`` (``E * sum_e f_e p_e`` over all experts, ``f_e`` the share
+    of assignments and ``p_e`` the mean router probability),
+    ``tokens_held_mean`` / ``tokens_held_max`` (assignments an expert held
+    here), ``assignments_local`` and ``assignments_computed``.
+    """
+
+    d_model: int
+    expert_hidden: int
+    shared_hidden: int
+    num_experts: int
+    top_k: int
+    experts_held: tuple[int, int] | None = None
+    renormalize: bool = True
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        b, s, d = x.shape
+        e, k = self.num_experts, self.top_k
+        first, held = self.experts_held or (0, e)
+        if not (0 <= first and held > 0 and first + held <= e):
+            raise ValueError(f"experts_held {(first, held)} outside 0..{e}")
+        n = b * s
+        init = nn.initializers.lecun_normal
+        router = self.param("router", init(), (d, e))
+        stacked = init(batch_axis=(0,))  # the leading axis counts experts
+        w_gate = self.param("w_gate", stacked, (held, d, self.expert_hidden))
+        w_up = self.param("w_up", stacked, (held, d, self.expert_hidden))
+        w_down = self.param("w_down", stacked, (held, self.expert_hidden, d))
+        shared_gate = self.param("shared_gate", init(), (d, self.shared_hidden))
+        shared_up = self.param("shared_up", init(), (d, self.shared_hidden))
+        shared_down = self.param("shared_down", init(), (self.shared_hidden, d))
+        shared_router = self.param("shared_router", init(), (d, 1))
+        w_gate, w_up, w_down = (
+            w.astype(self.dtype) for w in (w_gate, w_up, w_down)
+        )
+        tokens = x.reshape(n, d)
+        tokens_c = tokens.astype(self.dtype)
+
+        with jax.named_scope("lm.moe.route"):
+            logits = jnp.dot(
+                tokens.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            probs, experts, weights = route_top_k(
+                logits, k, renormalize=self.renormalize
+            )
+            flat_expert = experts.reshape(-1) - first           # [N k]
+            local = (flat_expert >= 0) & (flat_expert < held)
+            sort_key = jnp.where(local, flat_expert, held)
+            order = jnp.argsort(sort_key, stable=True)
+            counts = jnp.bincount(experts.reshape(-1), length=e)
+            sizes = counts[first:first + held].astype(jnp.int32)
+            n_local = jnp.sum(sizes)
+            token_of = order // k
+            weight_of = jnp.where(local, weights.reshape(-1), 0.0)[order]
+            aux = e * jnp.sum(
+                counts.astype(jnp.float32) / (n * k) * jnp.mean(probs, axis=0)
+            )
+
+        # Sorted assignments are taken ``rows`` at a time. A layer holding
+        # a share expects N k held / E local assignments and sizes a segment
+        # at ``_LOCAL_ROWS_SLACK`` times that, so the first segment is the
+        # step's whole work; a batch with more runs further segments (each
+        # skipped by ``lax.cond`` while empty), up to all N k. A segment is
+        # rematerialised, so the loop keeps indices, not activations.
+        full_rows = n * k
+        rows = min(
+            full_rows,
+            -(-int(_LOCAL_ROWS_SLACK * full_rows * held / e) // 256) * 256,
+        )
+        segments = -(-full_rows // rows)
+        pad = segments * rows - full_rows
+        token_of = jnp.pad(token_of, (0, pad))
+        weight_of = jnp.pad(weight_of, (0, pad))
+        ends = jnp.cumsum(sizes)
+        starts = ends - sizes
+
+        # The ``cond`` sits inside the rematerialised function: around it,
+        # the taken branch's residuals (the tokens and the experts'
+        # matrices) would become loop-variant outputs and the scan would
+        # stack them, once a segment.
+        @jax.checkpoint
+        def segment(j, tokens_c, w_gate, w_up, w_down):
+            lo = j * rows
+
+            def run():
+                with jax.named_scope("lm.moe.route"):
+                    idx = jax.lax.dynamic_slice(token_of, (lo,), (rows,))
+                    weight = jax.lax.dynamic_slice(weight_of, (lo,), (rows,))
+                    here = jnp.clip(
+                        jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo), 0
+                    ).astype(jnp.int32)
+                    # Rows past the last group belong to no expert. The
+                    # TPU's grouped product leaves them as it found them,
+                    # forward and backward (read on the chip, PR 26: garbage
+                    # of any size, where the CPU's writes zeros), so they
+                    # are cut off on both sides of it: the ``where`` in
+                    # front zeroes their cotangent, the one behind their
+                    # value.
+                    valid = (lo + jnp.arange(rows) < n_local)[:, None]
+                    xs = jnp.where(valid, tokens_c[idx], 0)
+                with jax.named_scope("lm.moe.experts"):
+                    ys = _grouped_swiglu(xs, here, w_gate, w_up, w_down)
+                ys = jnp.where(valid, ys, 0.0) * weight[:, None]
+                return idx, ys, jnp.sum(here)
+
+            def skip():
+                return (
+                    jnp.zeros((rows,), token_of.dtype),
+                    jnp.zeros((rows, d), jnp.float32),
+                    jnp.int32(0),
+                )
+
+            return jax.lax.cond(lo < n_local, run, skip)
+
+        def add_segment(carry, j):
+            out, computed = carry
+            idx, ys, grouped = segment(j, tokens_c, w_gate, w_up, w_down)
+            with jax.named_scope("lm.moe.route"):
+                out = jax.lax.cond(
+                    j * rows < n_local, lambda o: o.at[idx].add(ys),
+                    lambda o: o, out,
+                )
+            return (out, computed + grouped), None
+
+        (out, n_computed), _ = jax.lax.scan(
+            add_segment, (jnp.zeros((n, d), jnp.float32), jnp.int32(0)),
+            jnp.arange(segments),
+        )
+
+        with jax.named_scope("lm.moe.shared"):
+            h = jax.nn.silu(tokens_c @ shared_gate.astype(self.dtype)) * (
+                tokens_c @ shared_up.astype(self.dtype)
+            )
+            shared = (h @ shared_down.astype(self.dtype)).astype(jnp.float32)
+            gate = jax.nn.sigmoid(jnp.dot(
+                tokens.astype(jnp.float32), shared_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            ))
+            out = out + gate * shared
+
+        held_counts = sizes.astype(jnp.float32)
+        stats = {
+            "aux": aux,
+            "tokens_held_mean": jnp.mean(held_counts),
+            "tokens_held_max": jnp.max(held_counts),
+            "assignments_local": jnp.sum(local).astype(jnp.float32),
+            "assignments_computed": n_computed.astype(jnp.float32),
+        }
+        return out.reshape(b, s, d).astype(x.dtype), stats

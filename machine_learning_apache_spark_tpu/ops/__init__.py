@@ -19,7 +19,14 @@ from machine_learning_apache_spark_tpu.ops.masks import (
     make_segment_mask,
     combine_masks,
 )
-from machine_learning_apache_spark_tpu.ops.positional import sinusoidal_encoding
+from machine_learning_apache_spark_tpu.ops.positional import (
+    rotary_embedding,
+    sinusoidal_encoding,
+)
+from machine_learning_apache_spark_tpu.ops.gated_delta import (
+    gated_delta_recurrent,
+    gated_delta_rule,
+)
 from machine_learning_apache_spark_tpu.ops.attention import (
     attention_impl,
     kernel_mesh,
@@ -41,6 +48,9 @@ __all__ = [
     "make_segment_mask",
     "combine_masks",
     "sinusoidal_encoding",
+    "rotary_embedding",
+    "gated_delta_rule",
+    "gated_delta_recurrent",
     "scaled_dot_product_attention",
     "multi_head_attention_weights",
     "sequence_parallel",

@@ -13,6 +13,11 @@ from machine_learning_apache_spark_tpu.recipes.translation import (
     TranslationRecipe,
     train_translator,
 )
+from machine_learning_apache_spark_tpu.recipes.language_model import (
+    LMRecipe,
+    make_lm_loss,
+    train_lm,
+)
 
 __all__ = [
     "MLPRecipe",
@@ -23,4 +28,7 @@ __all__ = [
     "train_lstm",
     "TranslationRecipe",
     "train_translator",
+    "LMRecipe",
+    "make_lm_loss",
+    "train_lm",
 ]
