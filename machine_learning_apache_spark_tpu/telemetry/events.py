@@ -246,7 +246,7 @@ def enabled() -> bool:
     The env read is cached — instrumented hot paths pay one global load."""
     global _ENABLED
     if _ENABLED is None:
-        _ENABLED = os.environ.get(ENV_TELEMETRY, "1").strip().lower() not in (  # mlspark-lint: ok env-direct-read -- stdlib-only module, see _env_rank
+        _ENABLED = os.environ.get(ENV_TELEMETRY, "1").strip().lower() not in (  # mlspark-lint: ok env-direct-read recompile-env -- stdlib-only module, see _env_rank; read once a process and only gates logging, so a trace-time breadcrumb (ops.attention.record_dispatch under the jitted flash launchers) bakes nothing of it into a program
             "0", "false", "off", "no",
         )
     return _ENABLED
@@ -269,7 +269,7 @@ def get_log():
             if _LOG is None:
                 try:
                     max_events = int(
-                        os.environ.get(ENV_MAX_EVENTS, _DEFAULT_MAX_EVENTS)  # mlspark-lint: ok env-direct-read -- stdlib-only module, see _env_rank
+                        os.environ.get(ENV_MAX_EVENTS, _DEFAULT_MAX_EVENTS)  # mlspark-lint: ok env-direct-read recompile-env -- stdlib-only module, see _env_rank; read once a process and only gates logging, so a trace-time breadcrumb (ops.attention.record_dispatch under the jitted flash launchers) bakes nothing of it into a program
                     )
                 except ValueError:
                     max_events = _DEFAULT_MAX_EVENTS

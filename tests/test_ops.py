@@ -740,6 +740,243 @@ class TestFlashPerShardLaunch:
         np.testing.assert_allclose(got, body(q), rtol=1e-5, atol=1e-6)
 
 
+def _flash_tile_records(q_shape):
+    """The ``flash_tiles`` dispatch records whose padded, flattened query
+    operand is ``q_shape``."""
+    from machine_learning_apache_spark_tpu import telemetry
+
+    return [
+        e.attrs for e in telemetry.get_log().snapshot()
+        if e.kind == "annotation" and e.name == "ops.attention_dispatch"
+        and e.attrs["site"] == "flash_tiles"
+        and tuple(e.attrs["q"]) == tuple(q_shape)
+    ]
+
+
+class TestFlashTiling:
+    """The tiles of the three flash kernels come from the launch's shapes
+    (``_choose_tiling``): what the chooser picks, that any tiling computes
+    the same attention, and that a launch says which one it ran."""
+
+    @pytest.mark.parametrize("length", [200, 256])
+    @pytest.mark.parametrize("d_pad,itemsize", [(128, 2), (128, 4), (256, 2)])
+    def test_short_site_is_one_tile_a_side(self, length, d_pad, itemsize):
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            _choose_tiling,
+        )
+
+        t = _choose_tiling(length, length, d_pad, itemsize)
+        assert (t.q_pad, t.k_pad) == (256, 256)
+        assert t.fwd == t.dq == t.dkv == (256, 256)
+
+    @pytest.mark.parametrize("length", [4096, 8192, 1100, 2304])
+    @pytest.mark.parametrize(
+        "d_pad,itemsize", [(128, 2), (128, 4), (256, 2), (256, 4)]
+    )
+    def test_tiles_fit_the_budget_and_divide_the_padded_sides(
+        self, length, d_pad, itemsize
+    ):
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            VMEM_BUDGET,
+            _choose_tiling,
+            _vmem_bytes,
+        )
+
+        t = _choose_tiling(length, length, d_pad, itemsize)
+        base = -(-length // 128) * 128
+        for pad in (t.q_pad, t.k_pad):
+            # a side grows by at most an eighth over its padding to 128
+            assert base <= pad <= base + base // 8
+        for kernel in ("fwd", "dq", "dkv"):
+            bq, bk = getattr(t, kernel)
+            assert bq % 128 == 0 and bk % 128 == 0
+            # one q_pad for all three kernels: the backward reads the
+            # forward's lse at that length
+            assert t.q_pad % bq == 0 and t.k_pad % bk == 0
+            assert _vmem_bytes(kernel, bq, bk, d_pad, itemsize) <= VMEM_BUDGET
+        if length >= 4096:
+            # long sites leave the 128 x 128 tile behind in every kernel
+            assert min(min(getattr(t, k)) for k in ("fwd", "dq", "dkv")) >= 256
+
+    @pytest.mark.parametrize(
+        "q_len,kv_len,block_q,block_k,want_pads,want_tile",
+        [
+            (300, 300, 128, 128, (384, 384), (128, 128)),
+            (4096, 4096, 256, 512, (4096, 4096), (256, 512)),
+            (20, 150, 128, 128, (24, 256), (24, 128)),  # clamped as it was
+            (1100, 1100, 512, 128, (1536, 1152), (512, 128)),
+        ],
+    )
+    def test_explicit_tiles_are_honoured(
+        self, q_len, kv_len, block_q, block_k, want_pads, want_tile
+    ):
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            _choose_tiling,
+        )
+
+        t = _choose_tiling(q_len, kv_len, 128, 4, block_q, block_k)
+        assert (t.q_pad, t.k_pad) == want_pads
+        assert t.fwd == t.dq == t.dkv == want_tile
+
+    def test_one_side_given_the_other_chosen(self):
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            _choose_tiling,
+        )
+
+        t = _choose_tiling(4096, 4096, 128, 2, block_q=128)
+        assert {t.fwd[0], t.dq[0], t.dkv[0]} == {128}
+        assert min(t.fwd[1], t.dq[1], t.dkv[1]) >= 512
+
+    def test_short_query_side_keeps_its_multiple_of_eight(self):
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            _choose_tiling,
+        )
+
+        t = _choose_tiling(67, 67, 128, 4)
+        assert (t.q_pad, t.k_pad) == (72, 128)
+        assert t.fwd == t.dq == t.dkv == (72, 128)
+        t = _choose_tiling(1, 4096, 128, 2)  # a decode step over a history
+        assert t.q_pad == 8 and t.fwd[0] == 8 and t.fwd[1] >= 512
+
+    # (q_len, kv_len, heads, d, causal, kv_valid, tiles, against the dense
+    # path too). Lengths of 1,024 and more give several tiles a side under
+    # the chooser or under the explicit rectangles.
+    PARITY = {
+        "chosen-causal-1024x128": (1024, 1024, 2, 128, True, False, None, True),
+        "chosen-full-1024x128": (1024, 1024, 2, 128, False, False, None, True),
+        "chosen-causal-1024x256": (1024, 1024, 1, 256, True, False, None, True),
+        "chosen-causal-2048": (2048, 2048, 1, 32, True, False, None, True),
+        "chosen-causal-valid": (1024, 1024, 2, 32, True, True, None, True),
+        "chosen-full-valid-1100": (1100, 1100, 2, 32, False, True, None, True),
+        "chosen-causal-1100": (1100, 1100, 2, 32, True, False, None, True),
+        "chosen-causal-q640-kv1024": (640, 1024, 2, 32, True, False, None, True),
+        # query rows before the diagonal's start see no key: the kernel
+        # emits zeros there and the dense path an average, so kernel only
+        "chosen-causal-q1024-kv640": (1024, 640, 2, 32, True, False, None, False),
+        "256x512-causal": (1024, 1024, 2, 32, True, False, (256, 512), True),
+        "512x256-causal-q768": (768, 1024, 2, 32, True, False, (512, 256), True),
+        "256x256-causal-1100": (1100, 1100, 1, 32, True, False, (256, 256), True),
+        "512x256-full": (1024, 1024, 1, 32, False, False, (512, 256), True),
+    }
+
+    @pytest.mark.parametrize("case", list(PARITY))
+    def test_any_tiling_is_the_same_attention(self, rng, case):
+        """Forward and ``jax.grad`` of the chosen (or a rectangular) tiling
+        against explicit 128 x 128 tiles and against the dense path."""
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            dot_product_attention,
+        )
+
+        q_len, kv_len, h, d, causal, use_valid, tiles, dense = self.PARITY[case]
+        q = jnp.asarray(rng.standard_normal((1, h, q_len, d)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((1, h, kv_len, d)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((1, h, kv_len, d)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal((1, h, q_len, d)), jnp.float32)
+        kv_valid = (
+            jnp.asarray(rng.random((1, kv_len)) < 0.8).at[:, 0].set(True)
+            if use_valid else None
+        )
+        block_q, block_k = tiles or (None, None)
+
+        def both(attend):
+            def loss(q, k, v):
+                out = attend(q, k, v)
+                return jnp.sum(out * w), out
+
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True
+            )(q, k, v)
+            return out, grads
+
+        def flash(bq, bk):
+            return both(lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, kv_valid=kv_valid, block_q=bq,
+                block_k=bk, interpret=True,
+            ))
+
+        got = flash(block_q, block_k)
+        refs = [flash(128, 128)]
+        if dense:
+            refs.append(both(lambda q, k, v: dot_product_attention(
+                q, k, v, causal=causal, kv_valid=kv_valid, use_pallas=False
+            )))
+        for want in refs:
+            np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+            for name, a, e in zip("qkv", got[1], want[1]):
+                scale = float(jnp.max(jnp.abs(e))) + 1e-9
+                err = float(jnp.max(jnp.abs(a - e))) / scale
+                assert err < 1e-4, f"d{name} relative error {err}"
+
+    def test_launch_records_tiles_grid_and_vmem(self, rng):
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            _choose_tiling,
+            _vmem_bytes,
+        )
+
+        # a shape no other test launches: the record is left while tracing
+        b, h, s, d = 1, 3, 520, 24
+        q = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
+        jax.grad(lambda q: jnp.sum(
+            flash_attention(q, q, q, causal=True, interpret=True)
+        ))(q)
+        t = _choose_tiling(s, s, 128, 4)
+        records = _flash_tile_records((b * h, t.q_pad, 128))
+        forward = [r for r in records if r["impl"] == "forward"][-1]
+        backward = [r for r in records if r["impl"] == "backward"][-1]
+        bq, bk = t.fwd
+        grid = (b * h, t.q_pad // bq, t.k_pad // bk)
+        assert forward["kernels"]["flash_fwd"] == dict(
+            block_q=bq, block_k=bk, grid=grid,
+            vmem_bytes=_vmem_bytes("fwd", bq, bk, 128, 4),
+        )
+        assert forward["reason"].startswith(
+            f"flash_fwd {bq}x{bk} grid {'x'.join(map(str, grid))} vmem "
+        )
+        assert forward["reason"].endswith(
+            f"of [{b * h},{t.q_pad},128] x [{b * h},{t.k_pad},128] "
+            "float32 causal"
+        )
+        assert set(backward["kernels"]) == {"flash_bwd_dq", "flash_bwd_dkv"}
+        bq, bk = t.dkv
+        assert backward["kernels"]["flash_bwd_dkv"]["grid"] == (
+            b * h, t.k_pad // bk, t.q_pad // bq
+        )
+        assert "flash_bwd_dq " in backward["reason"]
+        assert "; flash_bwd_dkv " in backward["reason"]
+
+    def test_per_shard_launch_chooses_on_the_shard(self):
+        """Under ``kernel_mesh`` the launcher runs inside the ``shard_map``:
+        the record's grid is the shard's B/n x H/m heads, its tiles those of
+        the shard's lengths."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from machine_learning_apache_spark_tpu.ops.attention import (
+            kernel_mesh,
+        )
+        from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+            _choose_tiling,
+        )
+
+        mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
+        b, h, s, d = 8, 2, 528, 40  # a shape of this test's own
+        q = jax.device_put(
+            jax.random.normal(jax.random.key(0), (b, h, s, d), jnp.float32),
+            NamedSharding(mesh, P("data", "model")),
+        )
+        with kernel_mesh(mesh):
+            jax.jit(jax.grad(lambda q: jnp.sum(
+                flash_attention(q, q, q, causal=True, interpret=True)
+            )))(q)
+        t = _choose_tiling(s, s, 128, 4)
+        shard_rows = (b // 4) * (h // 2)
+        records = _flash_tile_records((shard_rows, t.q_pad, 128))
+        assert {r["impl"] for r in records} == {"forward", "backward"}
+        for r in records:
+            for kernel in r["kernels"].values():
+                assert kernel["grid"][0] == shard_rows
+        assert not _flash_tile_records((b * h, t.q_pad, 128))
+
+
 class TestRaggedPagedAttention:
     """Decode-step attention over a paged KV store: the XLA gather
     fallback (CPU tier-1 route), the Pallas kernel in interpret mode, and

@@ -1,0 +1,64 @@
+"""The flash kernels compiled for a described TPU v5e, no chip attached: what
+interpret mode cannot see (a tile over the kernel's VMEM, a block Mosaic
+refuses), at the widths the benchmark's cell runs and at the shapes that
+share the launchers. Nothing runs; a compile that passes is not a chip run.
+
+The topology is described inside a fixture, after collection, and every such
+test lives in this one file: only the worker that is given the file loads
+the TPU compiler.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from machine_learning_apache_spark_tpu.ops.pallas_attention import (
+    flash_attention,
+)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (batch, heads, q_len, kv_len, head_dim, dtype, causal, kv_valid)
+SITES = {
+    "q3next-4096x256-bf16-causal": (4, 16, 4096, 4096, 256, "bfloat16", True, False),
+    "long-8192x64-bf16-causal": (1, 16, 8192, 8192, 64, "bfloat16", True, False),
+    "1100x128-f32-valid": (2, 4, 1100, 1100, 128, "float32", False, True),
+    "q640-kv1024-bf16-causal-valid": (2, 4, 640, 1024, 128, "bfloat16", True, True),
+}
+
+
+@pytest.mark.parametrize("site", list(SITES))
+def test_chosen_tiles_compile_forward_and_backward(one_chip, site):
+    b, h, q_len, kv_len, d, dtype, causal, use_valid = SITES[site]
+    q = jax.ShapeDtypeStruct((b, h, q_len, d), dtype, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, h, kv_len, d), dtype, sharding=one_chip)
+    valid = (
+        (jax.ShapeDtypeStruct((b, kv_len), jnp.bool_, sharding=one_chip),)
+        if use_valid else ()
+    )
+
+    def loss(q, k, v, *valid):
+        out = flash_attention(
+            q, k, v, causal=causal, kv_valid=valid[0] if valid else None
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k, *valid
+    ).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f"%{name}" in text, f"{name} is not in the compiled program"
