@@ -425,8 +425,7 @@ def run_serve(size: Size, translator, data_root: str) -> None:
             boundaries=size.boundaries, max_new_tokens=size.max_new_tokens,
         ) as eng:
             info["warmup_seconds"] = round(time.perf_counter() - t0, 2)
-            info["kv_mode"], info["page_size"] = eng.kv_mode, eng.runtime.page_size
-            require(eng.kv_mode == "paged", "default kv_mode is paged")
+            info["page_size"] = eng.runtime.page_size
             results: list = [None] * len(texts)
 
             def client(k: int) -> None:

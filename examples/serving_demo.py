@@ -42,7 +42,7 @@ rejected: list[int] = []
 lock = threading.Lock()
 
 engine = translator.serve(
-    boundaries=(8, 12), max_batch=8, max_wait_s=0.005,
+    boundaries=(8, 12), max_batch=8,
     max_queue_depth=max(N_CLIENTS, 64), max_new_tokens=10,
 )
 

@@ -161,9 +161,9 @@ class CallGraph:
         }
         for mod in modules:
             _ModuleIndex(mod, self).visit(mod.tree)
-        # ``fn = lambda ...`` bindings: jit applications often wrap the
-        # bound name (engine._make_decoder idiom), so map names to their
-        # lambda defs per module.
+        # ``fn = lambda ...`` bindings: a jit application may wrap the
+        # bound name (``fn = lambda p, s: ...; jax.jit(fn)``), so map
+        # names to their lambda defs per module.
         self.lambda_binds: dict[str, dict[str, list[FuncInfo]]] = {}
         for mod in modules:
             binds = self.lambda_binds.setdefault(mod.name, {})

@@ -562,10 +562,10 @@ def serve_replica(
     reference — the replica-gang launch mode runs exactly this.
 
     Engine knobs resolve arg > env > default inside ``translator.serve``
-    — so a fleet driver can set a replica's KV discipline either
-    explicitly (``engine_knobs={"kv_mode": ..., "kv_dtype": ...}``) or
-    through the Distributor env contract (``MLSPARK_SERVE_KV_MODE`` /
-    ``MLSPARK_SERVE_KV_DTYPE`` exported to every rank)."""
+    — so a fleet driver can set a replica's KV store dtype either
+    explicitly (``engine_knobs={"kv_dtype": ...}``) or through the
+    Distributor env contract (``MLSPARK_SERVE_KV_DTYPE`` exported to
+    every rank)."""
     d = directory or fleet_dir() or "."
     if port is None:
         port = envcfg.get_int("MLSPARK_FLEET_PORT")

@@ -317,20 +317,17 @@ class Translator:
         """Continuous-batching server over this translator — the
         request-level layer ``__call__`` lacks: concurrent callers share
         an admission queue, and every hot step lands on a program
-        precompiled at warmup. By default (``kv_mode="paged"``) requests
-        decode out of a shared paged KV store — one ragged launch
-        program for any occupancy/length mix, chunk-padded prefill, and
-        an LRU prefix cache so repeated prompts skip their prefill;
-        ``kv_mode="padded"`` (or env ``MLSPARK_SERVE_KV_MODE``) selects
-        the legacy shape-bucketed rectangle path, which ``method="beam"``
-        still requires. Both modes produce outputs identical to
-        ``__call__`` (docs/SERVING.md). ``kv_dtype="int8"`` (or env
-        ``MLSPARK_SERVE_KV_DTYPE``) quantizes the paged KV pages to int8
-        with per-page scales — ~4x the concurrency ceiling per HBM byte
-        at >= 0.99 greedy token agreement; padded/beam engines reject it
-        at construction (their flax cache has no scale plane).
+        precompiled at warmup. Requests decode out of a shared paged KV
+        store — one ragged launch program for any occupancy/length mix,
+        chunk-padded prefill, and an LRU prefix cache so repeated prompts
+        skip their prefill. Decoding is greedy, with outputs identical to
+        ``__call__`` (docs/SERVING.md); beam search is not served: it is
+        ``__call__(method="beam")``, offline. ``kv_dtype="int8"`` (or env
+        ``MLSPARK_SERVE_KV_DTYPE``) quantizes the KV pages to int8 with
+        per-page scales — ~4x the concurrency ceiling per HBM byte at
+        >= 0.99 greedy token agreement.
 
-        >>> with t.serve(max_batch=8, boundaries=(16, 32)) as eng:
+        >>> with t.serve(max_active=8, boundaries=(16, 32)) as eng:
         ...     futs = [eng.submit(s) for s in sentences]
         ...     outs = [f.result(timeout=30) for f in futs]
 

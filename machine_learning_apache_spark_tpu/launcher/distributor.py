@@ -223,7 +223,6 @@ class Distributor:
         env: dict[str, str] | None = None,
         dp_mode: str | None = None,
         dp_overlap: bool | None = None,
-        serve_kv_mode: str | None = None,
         serve_kv_dtype: str | None = None,
         telemetry_http: int | None = None,
         ingest: dict | None = None,
@@ -263,25 +262,12 @@ class Distributor:
                 f"dp_overlap must be a bool or None, got {dp_overlap!r}"
             )
         self.dp_overlap = dp_overlap
-        # Serving KV-cache mode, same env contract shape: the knob becomes
-        # MLSPARK_SERVE_KV_MODE in every worker, which ServingEngine
-        # resolves when kv_mode isn't passed explicitly ("paged" is the
-        # engine default; "padded" selects the legacy rectangle path as
-        # an equivalence oracle). Validated here so a typo fails in the
+        # Serving KV-store dtype, same env contract shape: the knob
+        # becomes MLSPARK_SERVE_KV_DTYPE in every worker, which
+        # ServingEngine resolves when kv_dtype isn't passed explicitly
+        # ("float32" is the engine default; "int8" quantizes the KV pages
+        # with per-page scales). Validated here so a typo fails in the
         # driver, not inside every rank after rendezvous.
-        if serve_kv_mode is not None and serve_kv_mode not in (
-            "padded", "paged"
-        ):
-            raise ValueError(
-                f"unknown serve_kv_mode {serve_kv_mode!r} (expected "
-                "'padded' or 'paged')"
-            )
-        self.serve_kv_mode = serve_kv_mode
-        # Serving KV-store dtype, same contract: the knob becomes
-        # MLSPARK_SERVE_KV_DTYPE in every worker ("float32" is the engine
-        # default; "int8" quantizes paged KV pages with per-page scales).
-        # ServingEngine revalidates against the resolved kv_mode — int8
-        # with a padded/beam engine fails there with the full context.
         if serve_kv_dtype is not None and serve_kv_dtype not in (
             "float32", "int8"
         ):
@@ -619,10 +605,8 @@ class Distributor:
                     env, "MLSPARK_ZERO1_OVERLAP",
                     "1" if self.dp_overlap else "0",
                 )
-            # Serving KV mode rides the same contract (constructor >
+            # Serving KV dtype rides the same contract (constructor >
             # inherited env; explicit env= still wins below).
-            if self.serve_kv_mode is not None:
-                envcfg.put_into(env, "MLSPARK_SERVE_KV_MODE", self.serve_kv_mode)
             if self.serve_kv_dtype is not None:
                 envcfg.put_into(env, "MLSPARK_SERVE_KV_DTYPE", self.serve_kv_dtype)
             # Observability-plane port knob, same contract shape.

@@ -4,24 +4,19 @@ The repo's inference story stops at ``inference.Translator`` — a one-shot,
 caller-owns-the-batch API. This package adds the layer the ROADMAP's
 "millions of users" north star needs: concurrent callers share a bounded
 admission queue (``queue``), and a background engine (``engine``) drives
-one of two KV disciplines while ``metrics`` keeps the latency/throughput
+the paged decode loop while ``metrics`` keeps the latency/throughput
 ledger (padding-waste accounting included). Entry point:
 ``Translator.serve()``.
 
-- **paged** (default): a refcounted page pool + prefix cache
-  (``kv_pages``) backs one device page store; a token-budget admission
-  picker (``batcher.TokenBudgetBatcher``) paces chunked prefill; one
-  compiled ragged decode program serves any occupancy/length mix
-  (``paged_runtime``).
-- **padded** (oracle/legacy): a continuous batcher groups requests into
-  padded shape buckets so every batch hits an already-compiled XLA
-  program (``batcher.Batcher``), and a fixed KV slot pool bounds
-  in-flight decode state (``kv_slots``).
+A refcounted page pool + prefix cache (``kv_pages``) backs one device
+page store; a token-budget admission picker
+(``batcher.TokenBudgetBatcher``) paces chunked prefill; one compiled
+ragged decode program serves any occupancy/length mix
+(``paged_runtime``); a fixed row pool bounds the requests decoding at
+once (``kv_slots``).
 """
 
 from machine_learning_apache_spark_tpu.serving.batcher import (
-    Batch,
-    Batcher,
     TokenBudgetBatcher,
 )
 from machine_learning_apache_spark_tpu.serving.engine import (
@@ -52,8 +47,6 @@ from machine_learning_apache_spark_tpu.serving.queue import (
 
 __all__ = [
     "Backpressure",
-    "Batch",
-    "Batcher",
     "DeadlineExceeded",
     "EngineStopped",
     "Histogram",

@@ -1,31 +1,33 @@
-"""Serving engine — the decode loop behind ``Translator.serve()``.
+"""Serving engine — the paged decode loop behind ``Translator.serve()``.
 
-Wiring: caller threads tokenize and ``submit()`` into the admission
-queue; one background worker pulls shape-bucketed batches from the
-``Batcher``, takes KV slots for every member, pads the batch to the
-bucket's static ``[max_batch, boundary]`` shape, and runs the compiled
-cached decoder for that bucket. The eager path stays thin — tokenize,
-pad, dispatch — and everything hot is an already-compiled XLA program
-(the veScale split: request plumbing in Python, math in SPMD programs).
+Caller threads tokenize and ``submit()`` into a bounded admission queue;
+one background worker turns the queue into device work, cycle by cycle:
 
-Shape discipline is the whole game: one jitted callable per bucket
-boundary, batch always padded to ``max_batch`` (filler rows replicate
-row 0 — valid tokens, so no all-masked softmax — and are discarded), so
-``warmup()`` precompiles the complete program set and steady state runs
-with zero recompiles. ``recompiles_after_warmup`` watches the jit caches
-(via ``utils.compilation_cache``-style discipline, counted per callable)
-and is the demo/bench acceptance gate.
+    queue -> token-budget admission -> chunked prefill
+          -> one ragged launch program -> retire
+
+- **admit**: the oldest pending requests take free cache rows, FIFO, as
+  far as the prefill token budget reaches (``TokenBudgetBatcher``); a
+  prompt whose prefix KV is cached attaches its pages and costs nothing;
+- **prefill**: each admitted prompt is encoded at its chunk-padded
+  width into pages of the device's KV store (``PagedDecodeRuntime``);
+- **launch**: ONE compiled program runs ``steps_per_launch`` decode
+  steps over every row, whatever the occupancy or length mix;
+- **retire**: finished rows hand their translation to the caller's
+  future and give back row and pages.
+
+The eager path stays thin — tokenize, place, dispatch — and everything
+hot is an already-compiled XLA program. ``warmup()`` compiles the whole
+set (one prefill per chunk count, one launch), so steady state runs with
+zero recompiles; ``recompiles_after_warmup`` watches the runtime's jit
+caches and is the demo/bench acceptance gate.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Sequence
-
-import jax
-import numpy as np
 
 from machine_learning_apache_spark_tpu import telemetry
 from machine_learning_apache_spark_tpu.telemetry import (
@@ -34,12 +36,13 @@ from machine_learning_apache_spark_tpu.telemetry import (
 from machine_learning_apache_spark_tpu.data.text import EOS_ID, SOS_ID
 from machine_learning_apache_spark_tpu.utils import env as envcfg
 from machine_learning_apache_spark_tpu.serving.batcher import (
-    Batch,
-    Batcher,
     TokenBudgetBatcher,
 )
 from machine_learning_apache_spark_tpu.serving.kv_slots import KVSlotPool
 from machine_learning_apache_spark_tpu.serving.metrics import ServingMetrics
+from machine_learning_apache_spark_tpu.serving.paged_runtime import (
+    PagedDecodeRuntime,
+)
 from machine_learning_apache_spark_tpu.serving.queue import (
     DeadlineExceeded,
     RequestQueue,
@@ -59,9 +62,10 @@ class EngineStopped(RuntimeError):
 class InternalError(RuntimeError):
     """The engine failed this request internally (its decode batch raised).
 
-    The failure is *contained*: only the quarantined batch's requests see
-    this, the decode loop keeps serving, and — because the per-bucket
-    programs were compiled at warmup — recovery triggers zero recompiles.
+    The failure is *contained*: only the requests on the active rows of
+    the quarantined launch see this, the decode loop keeps serving, and —
+    because the page store resets to the same shapes — recovery triggers
+    zero recompiles.
     The original exception rides along as ``__cause__``.
     """
 
@@ -105,33 +109,30 @@ class _HealthWindow:
 
 class ServingEngine:
     """Continuous-batching server over a ``Translator``-shaped bundle
-    (``model``, ``params``, ``src_pipe``, ``trg_pipe``).
+    (``model``, ``params``, ``src_pipe``, ``trg_pipe``): a page-table KV
+    store, chunk-padded prefill, refcounted prefix sharing, immediate
+    FIFO admission and ONE compiled ragged decode program for any batch
+    occupancy/length mix. Decoding is greedy and token-identical to
+    ``Translator.__call__``; beam search is offline only
+    (``Translator.__call__(method="beam")``).
 
-    >>> with translator.serve(max_batch=8, boundaries=(16, 32)) as eng:
+    >>> with translator.serve(max_active=8, boundaries=(16, 32)) as eng:
     ...     futs = [eng.submit(s) for s in texts]
     ...     outs = [f.result(timeout=30) for f in futs]
 
-    Two KV disciplines share this front door (``kv_mode``, default
-    ``"paged"``, env ``MLSPARK_SERVE_KV_MODE``):
-
-    - **paged** — a page-table KV store and ONE compiled ragged decode
-      program for any batch occupancy/length mix, chunk-padded prefill,
-      refcounted prefix sharing, immediate FIFO admission;
-    - **padded** — the legacy per-bucket rectangle programs, kept as the
-      equivalence oracle (greedy outputs are token-identical) and the
-      beam-search path.
-
-    Tuning knobs (see docs/SERVING.md): ``boundaries`` bound prompt
-    length (and pick the padded compile set), ``max_batch`` the padded
-    batch shape, ``max_wait_s`` the padded co-batching patience,
-    ``max_queue_depth`` the backpressure point; paged mode adds
-    ``max_active`` (concurrent rows), ``page_size``/``num_pages`` (KV
-    granularity/budget), ``prefill_chunk``+``prefill_budget`` (chunked-
-    prefill pacing), ``steps_per_launch`` (decode steps per dispatch),
-    ``prefix_cache_size`` (shared-prefix entries), and ``kv_dtype``
+    Knobs (see docs/SERVING.md) and what each bounds: ``boundaries`` the
+    prompt length (the largest is the page store's prompt width, the
+    smallest the default prefill chunk); ``max_active`` the rows decoding
+    at once (``max_batch`` is its default and an older name for it);
+    ``max_new_tokens`` the tokens a request may emit; ``max_queue_depth``
+    the backpressure point; ``default_deadline_s`` a request's life;
+    ``page_size``/``num_pages`` the KV granularity/budget;
+    ``prefill_chunk``+``prefill_budget`` the prefill work between two
+    launches; ``steps_per_launch`` the decode steps per dispatch;
+    ``prefix_cache_size`` the shared-prefix entries; ``kv_dtype``
     (``"float32"`` default / ``"int8"`` quantized pages with per-page
-    scales, env ``MLSPARK_SERVE_KV_DTYPE``; paged+greedy only —
-    padded/beam engines reject int8 loudly).
+    scales, env ``MLSPARK_SERVE_KV_DTYPE``) and ``quantize_self`` the
+    store's precision.
     """
 
     def __init__(
@@ -140,15 +141,9 @@ class ServingEngine:
         *,
         boundaries: Sequence[int] = (16, 32, 64),
         max_batch: int = 8,
-        max_wait_s: float = 0.02,
         max_queue_depth: int = 64,
-        num_slots: int | None = None,
         max_new_tokens: int | None = None,
         default_deadline_s: float | None = None,
-        method: str = "greedy",
-        beam_size: int = 4,
-        length_penalty: float = 0.6,
-        kv_mode: str | None = None,
         kv_dtype: str | None = None,
         quantize_self: bool = False,
         page_size: int = 8,
@@ -168,41 +163,13 @@ class ServingEngine:
                 f"max_len {cfg.max_len}; positions past max_len have no "
                 "encoding"
             )
-        if method not in ("greedy", "beam"):
-            raise ValueError(
-                f"method must be 'greedy' or 'beam', got {method!r}"
-            )
-        if kv_mode is None:
-            kv_mode = envcfg.get_str("MLSPARK_SERVE_KV_MODE")
-        if kv_mode not in ("padded", "paged"):
-            raise ValueError(
-                f"kv_mode must be 'padded' or 'paged', got {kv_mode!r} "
-                "(check MLSPARK_SERVE_KV_MODE)"
-            )
-        if method == "beam" and kv_mode == "paged":
-            # Beam search rides the dense flax-cache decoder (hypothesis
-            # rows share and reorder KV); the paged store has no story
-            # for that yet, so beam engines run the padded path.
-            log.info("beam method: routing kv_mode paged -> padded")
-            kv_mode = "padded"
-        # Quantized KV store: arg > env > default, validated here like
-        # kv_mode. int8 exists only for the paged store (the padded/beam
-        # flax cache has no scale plane), so those combinations fail
-        # loudly instead of silently serving fp32.
+        # Quantized KV store: arg > env > default.
         if kv_dtype is None:
             kv_dtype = envcfg.get_str("MLSPARK_SERVE_KV_DTYPE")
         if kv_dtype not in ("float32", "int8"):
             raise ValueError(
                 f"kv_dtype must be 'float32' or 'int8', got {kv_dtype!r} "
                 "(check MLSPARK_SERVE_KV_DTYPE)"
-            )
-        if kv_dtype == "int8" and kv_mode != "paged":
-            raise ValueError(
-                "kv_dtype='int8' requires the paged KV store; this engine "
-                f"resolved kv_mode={kv_mode!r}"
-                + (" via method='beam'" if method == "beam" else "")
-                + " — use kv_mode='paged' with greedy decoding, or drop "
-                "the int8 request (check MLSPARK_SERVE_KV_DTYPE)"
             )
         self.kv_dtype = kv_dtype
         self.quantize_self = bool(quantize_self)
@@ -212,8 +179,6 @@ class ServingEngine:
         self.max_new_tokens = (
             cfg.max_len - 1 if max_new_tokens is None else max_new_tokens
         )
-        self.method = method
-        self.kv_mode = kv_mode
         self.clock = clock
         self.metrics = ServingMetrics(clock=clock)
         self.queue = RequestQueue(
@@ -221,65 +186,43 @@ class ServingEngine:
             clock=clock, on_expire=self.metrics.on_expire,
             on_slo=self.metrics.on_slo,
         )
-        self.batcher = Batcher(
-            self.queue,
-            boundaries=boundaries,
-            max_batch=max_batch,
-            max_wait_s=max_wait_s,
+        self.max_active = max_active or max_batch
+        if prefill_chunk is None:
+            prefill_chunk = max(
+                page_size, boundaries[0] // page_size * page_size
+            )
+        self.prefill_chunk = prefill_chunk
+        # Chunked-prefill pacing: at most this many chunk-padded
+        # prompt tokens prefill between consecutive decode launches,
+        # so admission bursts can't stall in-flight rows' next token.
+        self.prefill_budget = (
+            prefill_budget
+            if prefill_budget is not None
+            else 2 * -(-boundaries[-1] // prefill_chunk) * prefill_chunk
         )
-        if kv_mode == "paged":
-            from machine_learning_apache_spark_tpu.serving.paged_runtime import (
-                PagedDecodeRuntime,
-            )
-
-            self.max_active = max_active or max_batch
-            if prefill_chunk is None:
-                prefill_chunk = max(
-                    page_size, boundaries[0] // page_size * page_size
-                )
-            self.prefill_chunk = prefill_chunk
-            # Chunked-prefill pacing: at most this many chunk-padded
-            # prompt tokens prefill between consecutive decode launches,
-            # so admission bursts can't stall in-flight rows' next token.
-            self.prefill_budget = (
-                prefill_budget
-                if prefill_budget is not None
-                else 2 * -(-boundaries[-1] // prefill_chunk) * prefill_chunk
-            )
-            self.runtime = PagedDecodeRuntime(
-                translator.model, translator.params,
-                max_active=self.max_active,
-                max_src=boundaries[-1],
-                max_new_tokens=self.max_new_tokens,
-                page_size=page_size,
-                prefill_chunk=prefill_chunk,
-                steps_per_launch=steps_per_launch,
-                num_pages=num_pages,
-                prefix_cache_size=prefix_cache_size,
-                kv_dtype=kv_dtype,
-                quantize_self=quantize_self,
-                sos_id=SOS_ID, eos_id=EOS_ID, pad_id=cfg.pad_id,
-            )
-            # The row pool: one slot = one cache row in the launch
-            # program (``num_slots`` is a padded-path knob; the paged
-            # in-flight ceiling is ``max_active``).
-            self.pool = KVSlotPool(self.max_active)
-            self.paged_batcher = TokenBudgetBatcher(
-                self.queue, chunk=prefill_chunk
-            )
-        else:
-            self.max_active = max_batch
-            self.runtime = None
-            # 2× max_batch by default: one batch decoding plus one forming.
-            self.pool = KVSlotPool(num_slots or 2 * max_batch)
-        self._decoders = {
-            b: self._make_decoder(beam_size, length_penalty)
-            for b in boundaries
-        }
+        self.runtime = PagedDecodeRuntime(
+            translator.model, translator.params,
+            max_active=self.max_active,
+            max_src=boundaries[-1],
+            max_new_tokens=self.max_new_tokens,
+            page_size=page_size,
+            prefill_chunk=prefill_chunk,
+            steps_per_launch=steps_per_launch,
+            num_pages=num_pages,
+            prefix_cache_size=prefix_cache_size,
+            kv_dtype=kv_dtype,
+            quantize_self=quantize_self,
+            sos_id=SOS_ID, eos_id=EOS_ID, pad_id=cfg.pad_id,
+        )
+        # The row pool: one slot = one cache row in the launch program.
+        self.pool = KVSlotPool(self.max_active)
+        self.paged_batcher = TokenBudgetBatcher(
+            self.queue, chunk=prefill_chunk
+        )
         self._compiles_at_warmup: int | None = None
         self._stop = threading.Event()
         self._worker: threading.Thread | None = None
-        # Monotonic sequence over dispatched batches/launches — the
+        # Monotonic sequence over dispatched launches — the
         # ``decode_batch`` fault-injection coordinate (worker thread
         # only; no lock needed).
         self._batch_seq = 0
@@ -288,27 +231,6 @@ class ServingEngine:
         # batch — i.e. it has contained a fault and not yet proven it can
         # decode again. Worker-thread writes, scrape-thread reads.
         self._health = _HealthWindow()
-
-    def _make_decoder(self, beam_size: int, length_penalty: float):
-        """One jitted decode callable (its own jit cache → per-bucket
-        compile counting stays exact)."""
-        from machine_learning_apache_spark_tpu.models import (
-            beam_translate,
-            greedy_translate_cached,
-        )
-
-        model, mnt = self.translator.model, self.max_new_tokens
-        if self.method == "beam":
-            fn = lambda p, s: beam_translate(  # noqa: E731
-                model, p, s, beam_size=beam_size,
-                length_penalty=length_penalty, max_new_tokens=mnt,
-                sos_id=SOS_ID, eos_id=EOS_ID,
-            )
-        else:
-            fn = lambda p, s: greedy_translate_cached(  # noqa: E731
-                model, p, s, max_new_tokens=mnt, sos_id=SOS_ID, eos_id=EOS_ID,
-            )
-        return jax.jit(fn)
 
     # -- lifecycle -----------------------------------------------------------
     def start(self, *, warmup: bool = True) -> "ServingEngine":
@@ -327,30 +249,28 @@ class ServingEngine:
         # is set and telemetry is on.
         telemetry.register_status_provider("serving", self._status_snapshot)
         telemetry.register_health_provider("serving", self._health_snapshot)
-        if self.runtime is not None:
-            # First-class residency section for the fleet router's
-            # affinity table: bounded MRU digests of the prompts whose
-            # prefix KV this replica already holds (fleet/affinity.py
-            # scrapes sections.prefix_cache off /statusz).
-            telemetry.register_status_provider(
-                "prefix_cache", self.runtime.prefix_cache.stats
-            )
+        # First-class residency section for the fleet router's
+        # affinity table: bounded MRU digests of the prompts whose
+        # prefix KV this replica already holds (fleet/affinity.py
+        # scrapes sections.prefix_cache off /statusz).
+        telemetry.register_status_provider(
+            "prefix_cache", self.runtime.prefix_cache.stats
+        )
         telemetry.register_live_gauge(
             "serving", "queue_depth_live", lambda: self.queue.depth
         )
-        if self.runtime is not None:
-            telemetry.register_live_gauge(
-                "serving", "kv_page_occupancy",
-                lambda: self.runtime.mem_pool.occupancy,
-            )
-            telemetry.register_live_gauge(
-                "serving", "kv_mem_bytes_in_use",
-                lambda: self.runtime.mem_pool.bytes_in_use,
-            )
-            telemetry.register_live_gauge(
-                "serving", "active_rows",
-                lambda: self.runtime.active_count(),
-            )
+        telemetry.register_live_gauge(
+            "serving", "kv_page_occupancy",
+            lambda: self.runtime.mem_pool.occupancy,
+        )
+        telemetry.register_live_gauge(
+            "serving", "kv_mem_bytes_in_use",
+            lambda: self.runtime.mem_pool.bytes_in_use,
+        )
+        telemetry.register_live_gauge(
+            "serving", "active_rows",
+            lambda: self.runtime.active_count(),
+        )
         telemetry.start_http_server()
         telemetry.beacon_update(phase="serving")
         return self
@@ -382,46 +302,27 @@ class ServingEngine:
 
     # -- warmup / compile accounting ----------------------------------------
     def warmup(self) -> int:
-        """Precompile every program a live request could need — padded:
-        one decoder per bucket; paged: one prefill per chunk count plus
-        the single ragged launch — so no request ever pays a compile.
-        Returns the program count."""
-        if self.kv_mode == "paged":
-            with annotate("serve_warmup_paged"):
-                n = self.runtime.warmup()
-            self._compiles_at_warmup = self.compile_count()
-            log.info(
-                "warmup compiled %d paged programs (%d prefill widths + 1 "
-                "launch; max_active=%d, page_size=%d)",
-                n, n - 1, self.max_active, self.runtime.page_size,
-            )
-            return n
-        params = self.translator.params
-        row = [SOS_ID, EOS_ID]
-        for b in self.boundaries:
-            src = np.full((self.max_batch, b), self._pad_id, np.int32)
-            src[:, : len(row)] = row
-            with annotate(f"serve_warmup_b{b}"):
-                np.asarray(jax.block_until_ready(self._decoders[b](params, src)))
+        """Precompile every program a live request could need — one
+        prefill per chunk count plus the single ragged launch — so no
+        request ever pays a compile. Returns the program count."""
+        with annotate("serve_warmup_paged"):
+            n = self.runtime.warmup()
         self._compiles_at_warmup = self.compile_count()
         log.info(
-            "warmup compiled %d bucket programs (max_batch=%d, buckets=%s)",
-            len(self.boundaries), self.max_batch, list(self.boundaries),
+            "warmup compiled %d paged programs (%d prefill widths + 1 "
+            "launch; max_active=%d, page_size=%d)",
+            n, n - 1, self.max_active, self.runtime.page_size,
         )
-        return len(self.boundaries)
+        return n
 
     def compile_count(self) -> int:
         """Total compiled programs across every jitted callable the
-        engine owns — bucket decoders plus, in paged mode, the runtime's
-        prefill/launch programs."""
+        engine owns: the runtime's prefill and launch programs."""
         from machine_learning_apache_spark_tpu.utils.compilation_cache import (
             jit_cache_size,
         )
 
-        fns = list(self._decoders.values())
-        if self.runtime is not None:
-            fns += self.runtime.jit_fns()
-        return sum(jit_cache_size(f) for f in fns)
+        return sum(jit_cache_size(f) for f in self.runtime.jit_fns())
 
     @property
     def recompiles_after_warmup(self) -> int | None:
@@ -442,7 +343,6 @@ class ServingEngine:
             "healthy": worker_alive and recovered,
             "worker_alive": worker_alive,
             "quarantine_recovered": recovered,
-            "kv_mode": self.kv_mode,
             "kv_dtype": self.kv_dtype,
             "queue_depth": self.queue.depth,
             "loop_restarts": self.metrics.loop_restarts,
@@ -453,10 +353,8 @@ class ServingEngine:
         """/statusz section: the engine's full live state — config,
         conservation ledger, latency summary, page-pool stats, slowest-
         request exemplars."""
-        out = {
-            "kv_mode": self.kv_mode,
+        return {
             "kv_dtype": self.kv_dtype,
-            "method": self.method,
             "boundaries": list(self.boundaries),
             "max_batch": self.max_batch,
             "max_active": self.max_active,
@@ -466,16 +364,10 @@ class ServingEngine:
             "ledger": self.metrics.ledger(),
             "metrics": self.metrics.summary(),
             "slowest_requests": self.metrics.request_exemplars(),
+            "page_pool": self.runtime.stats(),
         }
-        if self.runtime is not None:
-            out["page_pool"] = self.runtime.stats()
-        return out
 
     # -- request path --------------------------------------------------------
-    @property
-    def _pad_id(self) -> int:
-        return self.translator.model.cfg.pad_id
-
     def submit(
         self,
         text: str,
@@ -485,8 +377,8 @@ class ServingEngine:
     ) -> ServeRequest:
         """Tokenize and admit one request; returns its ``ServeRequest``
         (``.result(timeout)`` blocks for the translation). Raises
-        ``Backpressure`` at capacity and ``ValueError`` for inputs no
-        bucket can hold — both *before* the request costs decode work.
+        ``Backpressure`` at capacity and ``ValueError`` for inputs past
+        the largest boundary — both *before* the request costs decode work.
 
         Distributed tracing: a context already active on the calling
         thread (a replica handling a routed request) is adopted; a bare
@@ -499,7 +391,7 @@ class ServingEngine:
         if len(ids) > self.boundaries[-1]:
             raise ValueError(
                 f"input tokenizes to {len(ids)} ids, beyond the largest "
-                f"bucket boundary {self.boundaries[-1]}; raise boundaries "
+                f"prompt boundary {self.boundaries[-1]}; raise boundaries "
                 "or shorten the input"
             )
         # Count the attempt BEFORE the queue decides: the conservation law
@@ -521,43 +413,31 @@ class ServingEngine:
     def _serve_loop(self) -> None:
         """Supervisor: keep a decode loop alive until ``stop()``.
 
-        Two containment rings (docs/FAULT_TOLERANCE.md). Inner: a batch
-        that raises is quarantined — its own requests fail with
-        ``InternalError``, everything else keeps flowing. Outer: if the
-        loop itself dies (batcher bug, quarantine path raising), it is
+        Two containment rings (docs/FAULT_TOLERANCE.md). Inner: a launch
+        or admission that raises is quarantined — the active rows'
+        requests fail with ``InternalError``, everything queued keeps
+        flowing. Outer: if the loop itself dies (batcher bug, quarantine
+        path raising), it is
         restarted here rather than leaving a silently dead engine whose
         submitters all block until their deadlines; ``loop_restarts``
         counts how often that safety net caught something.
         """
         while not self._stop.is_set():
             try:
-                self._decode_loop()
+                self._paged_loop()
             except Exception:  # noqa: BLE001 — a dead loop, not a dead engine
                 if self._stop.is_set():
                     break
                 log.exception("decode loop died; restarting")
                 self.metrics.on_loop_restart()
 
-    def _decode_loop(self) -> None:
-        if self.kv_mode == "paged":
-            self._paged_loop()
-            return
-        while not self._stop.is_set():
-            batch = self.batcher.next_batch(timeout=0.05)
-            if batch is None:
-                continue
-            try:
-                self._run_batch(batch)
-            except Exception as e:  # noqa: BLE001 — a batch must never kill the loop
-                self._quarantine(batch, e)
-
     # -- the paged decode loop ----------------------------------------------
     def _paged_loop(self) -> None:
         """Continuous paged serving: admit FIFO requests into free cache
         rows (chunk-budgeted prefill), launch ``steps_per_launch`` ragged
         decode steps over every occupied row, retire rows as they finish.
-        A raised launch or admission quarantines the active set only —
-        same inner containment ring as the padded loop.
+        A raised launch or admission quarantines the active set only
+        (the inner containment ring).
 
         An engine with no active row blocks in ``serving.idle_wait`` until
         work arrives; every other iteration is one ``serving.cycle``."""
@@ -768,9 +648,9 @@ class ServingEngine:
                 self.metrics.on_slo(
                     req.tier, req.deadline is not None and now > req.deadline
                 )
-            # Token ledger parity with the padded path (len(content)+1 per
-            # request): real emits count EOS when emitted; a
-            # budget-exhausted row gets its implicit stop token here.
+            # Token ledger: len(content)+1 per request. Real emits count
+            # EOS when emitted; a budget-exhausted row gets its implicit
+            # stop token here.
             new_tokens = result.real_tokens + sum(
                 1 for *_ , saw_eos in result.completed if not saw_eos
             )
@@ -851,168 +731,3 @@ class ServingEngine:
         if n:
             self.metrics.on_failure(n)
             log.info("engine stop failed %d in-flight paged rows", n)
-
-    def _quarantine(self, batch: Batch, exc: Exception) -> None:
-        """Contain one failed batch: free its KV slots, fail its (and only
-        its) requests with ``InternalError``, and count it."""
-        self._health.note_quarantine(self.clock())
-        log.info("quarantining batch of %d: %r", len(batch.requests), exc)
-        telemetry.annotate(
-            "serving.quarantine",
-            boundary=batch.boundary, requests=len(batch.requests),
-            error=type(exc).__name__,
-        )
-        n = 0
-        traces = []
-        for r in batch.requests:
-            self.pool.release_owner(r.id)
-            if not r.future.done():
-                r.trace.mark(
-                    "failed", self.clock(), reason="quarantine",
-                    error=type(exc).__name__,
-                )
-                err = InternalError(
-                    f"decode batch failed internally ({type(exc).__name__}); "
-                    "only this batch's requests are affected"
-                )
-                err.__cause__ = exc
-                r.future.set_exception(err)
-                n += 1
-                traces.append(r.trace.to_dict())
-                self.metrics.on_trace(r)
-        self.metrics.on_quarantine(n)
-        self.metrics.on_failure(n)
-        # Flight recorder: the quarantined batch's decode span (errored),
-        # the annotation above, and every victim's trace timeline.
-        telemetry.dump_flight(
-            f"serving.quarantine:{type(exc).__name__}",
-            extra={
-                "boundary": batch.boundary, "requests_failed": n,
-                "request_traces": traces,
-            },
-        )
-
-    def _take_slots(self, batch: Batch) -> list[ServeRequest]:
-        """All-or-nothing slot acquisition for the batch's live members,
-        shedding any member whose deadline passes while waiting."""
-        members = list(batch.requests)
-        while members and not self._stop.is_set():
-            now = self.clock()
-            live = [r for r in members if not r.expired(now)]
-            for r in members:
-                if r not in live:
-                    self.metrics.on_expire()
-                    self.metrics.on_slo(r.tier, True)
-                    r.trace.mark("expire", now, where="slot_wait")
-                    r.future.set_exception(
-                        DeadlineExceeded(
-                            f"request {r.id} expired awaiting a KV slot"
-                        )
-                    )
-            members = live
-            if not members:
-                break
-            if self.pool.acquire_many([r.id for r in members], timeout=0.05):
-                return members
-        n_failed = 0
-        for r in members:  # engine stopping
-            if not r.future.done():
-                r.trace.mark("failed", self.clock(), reason="engine_stop")
-                r.future.set_exception(EngineStopped("engine stopping"))
-                n_failed += 1
-        if n_failed:
-            self.metrics.on_failure(n_failed)  # terminal — conservation
-        return []
-
-    def _run_batch(self, batch: Batch) -> None:
-        with telemetry.span(
-            "serving.batch", mode="padded", boundary=batch.boundary,
-            size=len(batch.requests),
-        ) as span:
-            self._run_batch_inner(batch, span)
-
-    def _run_batch_inner(self, batch: Batch, span) -> None:
-        members = self._take_slots(batch)
-        if not members:
-            return
-        # After slot acquisition, before decode: an injected failure here
-        # exercises the full quarantine path, slot release included.
-        seq = self._batch_seq
-        self._batch_seq += 1
-        span.set(seq=seq)
-        maybe_fault("decode_batch", batch=seq)
-        batch_start = self.clock()
-        for r in members:
-            r.trace.mark(
-                "admit", batch_start,
-                kind="padded", prefill_tokens=batch.boundary,
-            )
-        src = np.full((self.max_batch, batch.boundary), self._pad_id, np.int32)
-        for i, r in enumerate(members):
-            row = r.ids[: batch.boundary]
-            src[i, : len(row)] = row
-        # Filler rows replicate row 0: real tokens keep every attention row
-        # well-formed, and rows past len(members) are simply discarded.
-        for i in range(len(members), self.max_batch):
-            src[i] = src[0]
-        with annotate(f"serve_decode_b{batch.boundary}"):
-            out = np.asarray(
-                jax.block_until_ready(
-                    self._decoders[batch.boundary](self.translator.params, src)
-                )
-            )
-        decode_done = self.clock()
-
-        from machine_learning_apache_spark_tpu.train.metrics import (
-            strip_special_ids,
-        )
-
-        rows = strip_special_ids(
-            out[: len(members)],
-            pad_id=self._pad_id, sos_id=SOS_ID, eos_id=EOS_ID,
-        )
-        vocab = self.translator.trg_pipe.vocab
-        new_tokens = 0
-        real_decode = 0
-        for r, row in zip(members, rows):
-            r.decode_done_time = decode_done
-            r.trace.note_launch(seq)
-            r.trace.mark("first_token", decode_done)
-            new_tokens += len(row) + 1  # emitted ids + the eos/stop token
-            real_decode += min(len(row) + 1, self.max_new_tokens)
-            text = " ".join(vocab.lookup_tokens(row))
-            # Slot frees at EOS — the row is done generating either way
-            # (eos emitted, or the max_new_tokens budget is exhausted).
-            self.pool.release_owner(r.id)
-            r.trace.mark("complete", decode_done, tokens=len(row))
-            r.future.set_result(text)
-            done = self.clock()
-            self.metrics.on_complete(
-                queue_wait=batch_start - r.submit_time,
-                ttft=decode_done - r.submit_time,
-                total=done - r.submit_time,
-            )
-            self.metrics.on_trace(r)
-            self.metrics.on_slo(
-                r.tier, r.deadline is not None and done > r.deadline
-            )
-        # Padding-waste ledger: the rectangle this batch computed (every
-        # row, filler included, at full boundary/budget width) versus the
-        # tokens that were real.
-        self.metrics.on_token_slots(
-            real=sum(min(len(r.ids), batch.boundary) for r in members)
-            + real_decode,
-            padded=self.max_batch * (batch.boundary + self.max_new_tokens),
-        )
-        decode_s = decode_done - batch_start
-        self.queue.note_serviced(len(members), decode_s)
-        self.metrics.on_batch(
-            n_requests=len(members),
-            max_batch=self.max_batch,
-            decode_s=decode_s,
-            new_tokens=new_tokens,
-            queue_depth=self.queue.depth,
-            slot_occupancy=self.pool.occupancy,
-        )
-        # Batch retired cleanly: end of any post-quarantine degraded window.
-        self._health.note_ok_batch(decode_done)

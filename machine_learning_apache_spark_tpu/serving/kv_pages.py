@@ -1,7 +1,7 @@
 """Paged KV pool — block-table memory management for ragged serving.
 
-The padded engine's ``KVSlotPool`` hands out whole cache *rows*; this
-module manages the same capacity at **page** granularity (the Ragged
+``KVSlotPool`` hands out whole cache *rows* of the launch program; this
+module manages the KV those rows hold at **page** granularity (the Ragged
 Paged Attention discipline, arxiv 2604.15464): the device holds one big
 page store ``[layers, 2, num_pages, page_size, d_model]`` and every
 in-flight request owns a *list* of page ids — its block table — that

@@ -1,13 +1,12 @@
 """Fixed-pool KV slot manager — bounded in-flight decode state.
 
-Each in-flight request owns one slot of decode-cache capacity for the
-duration of its batch (Ragged Paged Attention's slot discipline, at batch
-granularity: the engine's KV caches are per-batch scan state, so a slot
-here is the *right to occupy a cache row*, and the pool bound is the hard
-ceiling on concurrently-decoding requests). Slots free on EOS — every
-completed row — and on deadline expiry of a request that died holding
-one; an exhausted pool makes the batcher's next batch wait instead of
-oversubscribing device memory.
+Each in-flight request owns one slot for as long as it decodes: a slot is
+the *right to occupy a cache row* of the engine's launch program, and the
+pool bound (``max_active``) is the hard ceiling on concurrently-decoding
+requests. Slots free when a row retires — EOS, the token budget, deadline
+expiry mid-decode, quarantine; the engine admits no more requests than
+the pool has free, so device memory is never oversubscribed. What a row
+holds in KV is the page pool's business (``kv_pages``).
 
 The pool is a condition-backed free list with owner tracking, so a crash
 path can free by request id without knowing which slot it held, plus the

@@ -222,10 +222,9 @@ class ServingMetrics:
         self.tokens_out = 0
         # padding-waste accounting: of every token slot the compiled
         # programs computed (prefill + decode), how many carried a real
-        # token? The padded path pays rectangle slots (max_batch x
-        # boundary, max_batch x max_new_tokens); the paged path pays
-        # chunk-padded prefill and max_active x steps launches. The gap
-        # is the waste the paged KV layer exists to shrink.
+        # token? ``padded_tokens`` counts the computed slots: a prompt's
+        # chunk-padded width per prefill, max_active x steps per launch.
+        # The gap to ``real_tokens`` is the padding waste.
         self.real_tokens = 0
         self.padded_tokens = 0
         # latency histograms (seconds)

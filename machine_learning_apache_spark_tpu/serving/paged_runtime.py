@@ -1,9 +1,9 @@
 """Paged decode runtime — the device half of the ragged serving engine.
 
-Where the padded engine compiles one decoder program per bucket and pays
-``max_batch x boundary`` prefill plus ``max_batch x max_new_tokens``
-decode slots for every batch, this runtime keeps **two page stores** on
-device, each ``[layers, 2, num_pages, page_size, d_model]``:
+Where a rectangle decoder (``greedy_translate_cached``) compiles one
+program per padded shape and pays ``batch x width`` prefill plus
+``batch x max_new_tokens`` decode slots for every batch, this runtime
+keeps **two page stores** on device, each ``[layers, 2, num_pages, page_size, d_model]``:
 
 - the **self store** holds generated-token K/V. It is small (worst case
   ``max_active x ceil(max_new_tokens/page_size)`` pages) because it is
@@ -51,14 +51,15 @@ the cursor crosses page boundaries, free everything on EOS/expiry via
 the request id, and share refcounted prefix pages (mem pool) through
 the cache.
 
-Decode discipline (kept bit-consistent with the padded scan): each step
+Decode discipline (kept bit-consistent with the one-shot decoder's scan,
+``greedy_translate_cached``): each step
 scatters the new K/V at the row's *old* cursor, emits
 ``argmax`` (pad forced for finished rows), then advances the cursor for
 unfinished rows only. A row finishes on emitting EOS, on exhausting the
-``max_new_tokens`` budget, or on emitting pad (the padded path can decode
-*through* an emitted pad because its dense mask hides interior holes;
-length-addressed block tables cannot represent a hole, so the paged path
-treats an emitted pad as terminal — in practice an untrained-corner
+``max_new_tokens`` budget, or on emitting pad (the one-shot decoder can
+decode *through* an emitted pad because its dense mask hides interior
+holes; length-addressed block tables cannot represent a hole, so the
+paged path treats an emitted pad as terminal — in practice an untrained-corner
 behaviour that greedy decoding does not produce).
 """
 

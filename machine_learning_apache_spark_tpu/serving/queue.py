@@ -171,8 +171,8 @@ class DeadlineExceeded(RuntimeError):
 class ServeRequest:
     """One in-flight translation request.
 
-    ``ids`` is the ragged (unpadded) token-id row — the bucketing key and
-    the payload the batcher pads. ``deadline`` is an absolute monotonic
+    ``ids`` is the ragged (unpadded) token-id row — what admission is
+    priced by and what prefill pads to the chunk grid. ``deadline`` is an absolute monotonic
     time or None. The ``future`` resolves to the detokenized string (or
     an exception); timestamps feed the metrics ledger.
     """
@@ -184,11 +184,9 @@ class ServeRequest:
     id: int = dataclasses.field(default_factory=lambda: next(_REQUEST_IDS))
     future: Future = dataclasses.field(default_factory=Future)
     # Stamped by the engine: when this request's first token became
-    # available (padded path: batch decode emits all tokens at once, so
-    # TTFT and decode-done coincide; paged path: end of the launch that
-    # produced the first emit).
+    # available (the end of the launch that produced the first emit).
     decode_done_time: float | None = None
-    # Stamped by the paged engine when the request leaves the queue for a
+    # Stamped by the engine when the request leaves the queue for a
     # cache row (queue-wait measurement point).
     admit_time: float | None = None
     slot: int | None = None

@@ -383,8 +383,9 @@ def serving_report(events: list[dict], table: dict | None = None) -> dict:
 
     - ``phases``: the ``serving.*`` rows of the phase table (submit and
       batch/launch span durations);
-    - ``batches_by_mode``: span counts and mean duration split by the
-      ``mode`` attr ("padded" vs "paged") — a mixed-mode gang shows both;
+    - ``batches_by_mode``: ``serving.batch`` span counts and mean
+      duration by the ``mode`` attr the engine stamps ("paged"; a span
+      of a foreign log that carries none is listed as "unknown");
     - ``counters``: per-rank totals of ``serving.*`` counter events
       (today: ``tokens_real``/``tokens_padded``, the padding-waste pair
       ``ServingMetrics.on_token_slots`` mirrors into the event stream);
@@ -411,7 +412,7 @@ def serving_report(events: list[dict], table: dict | None = None) -> dict:
             entry = per_rank.setdefault(ev.get("rank"), {"total": 0.0})
             entry["total"] += float(ev.get("value") or 0.0)
         elif kind == "span_end" and name == "serving.batch":
-            mode = str(attrs.get("mode") or "padded")
+            mode = str(attrs.get("mode") or "unknown")
             entry = by_mode.setdefault(mode, {"count": 0, "total_s": 0.0})
             entry["count"] += 1
             entry["total_s"] += float(ev.get("value") or 0.0)
@@ -786,7 +787,7 @@ def render_markdown(report: dict) -> str:
                 lines.append(f"- {key}: {serving[key]}")
         if serving.get("batches_by_mode"):
             lines.append("")
-            lines.append("| kv mode | dispatches | mean (ms) | total (s) |")
+            lines.append("| mode | dispatches | mean (ms) | total (s) |")
             lines.append("|---|---|---|---|")
             for mode, entry in serving["batches_by_mode"].items():
                 lines.append(

@@ -243,16 +243,9 @@ register(
 
 # serving
 register(
-    "MLSPARK_SERVE_KV_MODE", type="str", default="paged", subsystem="serving",
-    description="KV-cache discipline for ServingEngine when kv_mode= is "
-    "not passed: `paged` (ragged paged attention, the default) or "
-    "`padded` (per-bucket rectangle oracle / beam path).",
-    choices=("padded", "paged"),
-)
-register(
     "MLSPARK_SERVE_KV_DTYPE", type="str", default="float32", subsystem="serving",
-    description="Paged KV store dtype: `float32`, or `int8` with "
-    "per-page scales (paged+greedy only; padded/beam engines reject it).",
+    description="Paged KV store dtype for ServingEngine when kv_dtype= "
+    "is not passed: `float32`, or `int8` with per-page scales.",
     choices=("float32", "int8"),
 )
 

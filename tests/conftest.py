@@ -53,6 +53,44 @@ def rng():
     return np.random.default_rng(1234)
 
 
+@pytest.fixture(scope="session")
+def make_tiny_translator():
+    """``make(n_pairs) -> (Translator, source texts)``: an untrained tiny
+    MT bundle over ``n_pairs`` synthetic sentence pairs — serving
+    semantics don't need a trained model, and init is ~instant where
+    training is not."""
+    from machine_learning_apache_spark_tpu.data.datasets import (
+        synthetic_translation_pairs,
+    )
+    from machine_learning_apache_spark_tpu.data.text import TextPipeline
+    from machine_learning_apache_spark_tpu.inference import Translator
+    from machine_learning_apache_spark_tpu.models import (
+        Transformer,
+        TransformerConfig,
+    )
+
+    def make(n_pairs: int):
+        pairs = synthetic_translation_pairs(
+            n_pairs, min_len=3, max_len=8, seed=0
+        )
+        src_pipe = TextPipeline.fit([s for s, _ in pairs], max_seq_len=14)
+        trg_pipe = TextPipeline.fit([t for _, t in pairs], max_seq_len=14)
+        cfg = TransformerConfig(
+            src_vocab_size=len(src_pipe.vocab.itos),
+            trg_vocab_size=len(trg_pipe.vocab.itos),
+            d_model=32, ffn_hidden=64, num_heads=2, num_layers=1,
+            max_len=16, dropout=0.0,
+        )
+        model = Transformer(cfg)
+        dummy = np.ones((2, 8), np.int32)
+        params = model.init(jax.random.key(0), dummy, dummy)["params"]
+        return Translator(model, params, src_pipe, trg_pipe), [
+            s for s, _ in pairs
+        ]
+
+    return make
+
+
 def pytest_sessionfinish(session, exitstatus):
     """Sweep stray gang process groups at session end: a launcher test
     that timed out or crashed mid-gang must not leave orphaned ranks

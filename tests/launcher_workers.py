@@ -230,6 +230,14 @@ def echo_dp_mode():
     }
 
 
+def echo_serve_env():
+    """The serving env contract as a worker sees it: every
+    ``MLSPARK_SERVE_*`` variable in the rank's environment."""
+    return {
+        k: v for k, v in os.environ.items() if k.startswith("MLSPARK_SERVE_")
+    }
+
+
 def echo_ingest_env():
     """The ingest env contract as a worker sees it (Distributor(ingest=...)
     must plumb MLSPARK_INGEST_* into every rank's environment), resolved

@@ -207,34 +207,9 @@ class TestGangFaultDrill:
 
 
 @pytest.fixture(scope="module")
-def tiny_translator():
-    """Untrained tiny MT bundle (mirrors tests/test_serving.py — serving
-    semantics don't need a trained model)."""
-    import jax
-
-    from machine_learning_apache_spark_tpu.data.datasets import (
-        synthetic_translation_pairs,
-    )
-    from machine_learning_apache_spark_tpu.data.text import TextPipeline
-    from machine_learning_apache_spark_tpu.inference import Translator
-    from machine_learning_apache_spark_tpu.models import (
-        Transformer,
-        TransformerConfig,
-    )
-
-    pairs = synthetic_translation_pairs(32, min_len=3, max_len=8, seed=0)
-    src_pipe = TextPipeline.fit([s for s, _ in pairs], max_seq_len=14)
-    trg_pipe = TextPipeline.fit([t for _, t in pairs], max_seq_len=14)
-    cfg = TransformerConfig(
-        src_vocab_size=len(src_pipe.vocab.itos),
-        trg_vocab_size=len(trg_pipe.vocab.itos),
-        d_model=32, ffn_hidden=64, num_heads=2, num_layers=1,
-        max_len=16, dropout=0.0,
-    )
-    model = Transformer(cfg)
-    dummy = np.ones((2, 8), np.int32)
-    params = model.init(jax.random.key(0), dummy, dummy)["params"]
-    return Translator(model, params, src_pipe, trg_pipe), [s for s, _ in pairs]
+def tiny_translator(make_tiny_translator):
+    """Untrained tiny MT bundle over 32 sentence pairs."""
+    return make_tiny_translator(32)
 
 
 class TestServingPoisonedBatch:
@@ -250,8 +225,7 @@ class TestServingPoisonedBatch:
         texts = texts[:12]
         faults.install(FaultPlan.from_spec("raise@decode_batch:batch=0"))
         with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8,
+            boundaries=(8, 16), max_batch=4, max_new_tokens=8,
         ) as eng:
             futs = [eng.submit(s) for s in texts]
             served, failures = [], []
@@ -280,7 +254,7 @@ class TestServingPoisonedBatch:
         eng = t.serve(
             boundaries=(8, 16), max_batch=4, max_new_tokens=8, start=False
         )
-        real = eng._decode_loop
+        real = eng._paged_loop
         died = {"n": 0}
 
         def dying_then_real():
@@ -289,7 +263,7 @@ class TestServingPoisonedBatch:
                 raise RuntimeError("decode loop death (injected)")
             real()
 
-        eng._decode_loop = dying_then_real
+        eng._paged_loop = dying_then_real
         eng.start()
         try:
             out = eng.submit(texts[0]).result(timeout=120)

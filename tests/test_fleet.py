@@ -854,45 +854,17 @@ class TestRouterHedging:
 
 
 @pytest.fixture(scope="module")
-def mt_bundle():
-    """Untrained tiny MT bundle (the test_serving idiom): serving
-    semantics need no trained weights, and init is ~instant."""
-    import jax
-    import numpy as np
-
-    from machine_learning_apache_spark_tpu.data.datasets import (
-        synthetic_translation_pairs,
-    )
-    from machine_learning_apache_spark_tpu.data.text import TextPipeline
-    from machine_learning_apache_spark_tpu.inference import Translator
-    from machine_learning_apache_spark_tpu.models import (
-        Transformer,
-        TransformerConfig,
-    )
-
-    pairs = synthetic_translation_pairs(32, min_len=3, max_len=8, seed=0)
-    src_pipe = TextPipeline.fit([s for s, _ in pairs], max_seq_len=14)
-    trg_pipe = TextPipeline.fit([t for _, t in pairs], max_seq_len=14)
-    cfg = TransformerConfig(
-        src_vocab_size=len(src_pipe.vocab.itos),
-        trg_vocab_size=len(trg_pipe.vocab.itos),
-        d_model=32, ffn_hidden=64, num_heads=2, num_layers=1,
-        max_len=16, dropout=0.0,
-    )
-    model = Transformer(cfg)
-    dummy = np.ones((2, 8), np.int32)
-    params = model.init(jax.random.key(0), dummy, dummy)["params"]
-    return Translator(model, params, src_pipe, trg_pipe), [
-        s for s, _ in pairs
-    ]
+def mt_bundle(make_tiny_translator):
+    """Untrained tiny MT bundle over 32 sentence pairs."""
+    return make_tiny_translator(32)
 
 
 class TestFleetTraceE2E:
     """One trace id from router mint through the replica HTTP hop into
-    the real engine — the distributed-tracing acceptance path, with one
-    replica per KV discipline so both modes ride the same fleet."""
+    the real engine — the distributed-tracing acceptance path, over two
+    engine replicas so each request's id crosses its own process hop."""
 
-    def test_one_trace_id_across_both_kv_modes(
+    def test_one_trace_id_per_request_across_two_replicas(
         self, mt_bundle, fresh_trace, tmp_path
     ):
         from machine_learning_apache_spark_tpu.telemetry import (
@@ -903,10 +875,9 @@ class TestFleetTraceE2E:
         t, texts = mt_bundle
         engines, servers = [], []
         try:
-            for rank, kv_mode in enumerate(("paged", "padded")):
+            for rank in range(2):
                 eng = t.serve(
-                    boundaries=(8, 16), max_batch=2, max_wait_s=0.01,
-                    max_new_tokens=8, kv_mode=kv_mode,
+                    boundaries=(8, 16), max_batch=2, max_new_tokens=8,
                 )
                 engines.append(eng)
                 srv = ReplicaServer(eng, rank=rank, port=0)
@@ -923,7 +894,7 @@ class TestFleetTraceE2E:
             for eng in engines:
                 eng.stop()
 
-        assert {p["rank"] for p in payloads} == {0, 1}  # both kv modes
+        assert {p["rank"] for p in payloads} == {0, 1}  # both replicas
         evs = events.get_log().snapshot()
         hexdigits = set("0123456789abcdef")
         assert len({p["trace_id"] for p in payloads}) == 2

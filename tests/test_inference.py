@@ -59,6 +59,32 @@ class TestTranslator:
         with pytest.raises(ValueError, match="rng"):
             t(srcs, method="sample")  # silent fixed default would repeat
 
+    def test_translator_beam_matches_beam_translate(self, trained):
+        """``__call__(method="beam")`` is the home of beam search (the
+        serving engine decodes greedily only): its strings are those of
+        ``beam_translate`` called directly on the same ids, and one beam
+        is the greedy decoder."""
+        from machine_learning_apache_spark_tpu.data.text import EOS_ID, SOS_ID
+        from machine_learning_apache_spark_tpu.models import beam_translate
+        from machine_learning_apache_spark_tpu.train.metrics import (
+            strip_special_ids,
+        )
+
+        t, _ = trained
+        texts = ["one two three", "alpha beta gamma delta", "epsilon"]
+        ys = beam_translate(
+            t.model, t.params, np.asarray(t.src_pipe(texts)),
+            beam_size=2, max_new_tokens=4, sos_id=SOS_ID, eos_id=EOS_ID,
+        )
+        rows = strip_special_ids(
+            ys, pad_id=t.model.cfg.pad_id, sos_id=SOS_ID, eos_id=EOS_ID
+        )
+        direct = [" ".join(t.trg_pipe.vocab.lookup_tokens(r)) for r in rows]
+        assert t(texts, method="beam", beam_size=2, max_new_tokens=4) == direct
+        assert t(
+            texts, method="beam", beam_size=1, max_new_tokens=4
+        ) == t(texts, method="greedy", max_new_tokens=4)
+
     def test_unregistered_tokenizer_fails_at_save(self, trained, tmp_path):
         """A pipeline built around a bare callable cannot be rebuilt by
         load(); save() must refuse up front, not persist an unloadable

@@ -72,6 +72,19 @@ class TestDistributorLocal:
         with pytest.raises(ValueError, match="dp_mode"):
             Distributor(num_processes=2, dp_mode="zero2")
 
+    def test_distributor_has_no_serve_kv_mode(self, monkeypatch):
+        # Serving has one KV discipline: the keyword that chose between
+        # two is gone, and the serving contract a worker sees holds the
+        # store's dtype only.
+        with pytest.raises(TypeError, match="serve_kv_mode"):
+            Distributor(num_processes=1, serve_kv_mode="paged")
+        monkeypatch.delenv("MLSPARK_SERVE_KV_DTYPE", raising=False)
+        out = Distributor(
+            num_processes=1, platform="cpu", timeout=120,
+            serve_kv_dtype="int8",
+        ).run("launcher_workers:echo_serve_env")
+        assert out == {"MLSPARK_SERVE_KV_DTYPE": "int8"}
+
     def test_gang_failure_raises(self):
         with pytest.raises(RuntimeError, match="worker exploded"):
             Distributor(num_processes=2, platform="cpu", timeout=120).run(

@@ -1,8 +1,8 @@
-"""serving/: admission queue, continuous batcher, KV slot pool, metrics,
+"""serving/: admission queue, admission picker, KV slot pool, metrics,
 and the end-to-end engine — the request-level layer over the compiled
 decode core (docs/SERVING.md).
 
-Unit tests drive queue/batcher/slots with a fake clock (no sleeps where
+Unit tests drive queue/picker/slots with a fake clock (no sleeps where
 avoidable); the e2e class serves real concurrent requests through a tiny
 untrained Transformer on CPU and pins the two serving invariants: results
 identical to the one-shot ``Translator`` path, and zero recompiles after
@@ -17,7 +17,6 @@ import pytest
 
 from machine_learning_apache_spark_tpu.serving import (
     Backpressure,
-    Batcher,
     DeadlineExceeded,
     Histogram,
     KVSlotPool,
@@ -107,67 +106,6 @@ class TestRequestQueue:
             with pytest.raises(RuntimeError, match="down"):
                 r.result(timeout=0)
         assert q.depth == 0
-
-
-class TestBatcher:
-    def _mk(self, clock, **kw):
-        q = RequestQueue(max_depth=64, clock=clock)
-        kw.setdefault("boundaries", (4, 8))
-        kw.setdefault("max_batch", 2)
-        kw.setdefault("max_wait_s", 1.0)
-        return q, Batcher(q, **kw)
-
-    def test_full_bucket_ships_immediately(self):
-        clock = FakeClock()
-        q, b = self._mk(clock)
-        q.submit("a", [1, 2])        # bucket 0 (len 2 ≤ 4)
-        q.submit("b", [1, 2, 3, 4, 5])  # bucket 1
-        q.submit("c", [3])           # bucket 0 → full
-        batch = b.next_batch(timeout=0)
-        assert batch is not None and batch.boundary == 4
-        assert [r.text for r in batch.requests] == ["a", "c"]
-        assert q.depth == 1  # the bucket-1 request stays queued
-
-    def test_partial_batch_waits_for_max_wait(self):
-        clock = FakeClock()
-        q, b = self._mk(clock)
-        q.submit("a", [1, 2])
-        assert b.next_batch(timeout=0) is None  # not full, not overdue
-        clock.advance(1.5)  # past max_wait_s
-        batch = b.next_batch(timeout=0)
-        assert batch is not None and len(batch) == 1
-        assert batch.requests[0].text == "a"
-
-    def test_overdue_prefers_fullest_bucket(self):
-        clock = FakeClock()
-        q, b = self._mk(clock, max_batch=3)
-        q.submit("a", [1, 2, 3, 4, 5])  # bucket 1, head of line
-        q.submit("b", [1])              # bucket 0
-        q.submit("c", [2])              # bucket 0
-        clock.advance(2.0)              # everyone overdue
-        batch = b.next_batch(timeout=0)
-        assert batch.boundary == 4 and len(batch) == 2  # fullest bucket wins
-        assert b.next_batch(timeout=0).boundary == 8  # then the head's own
-
-    def test_real_clock_max_wait_bounds_latency(self):
-        """Wall-clock: a lone request ships within ~max_wait, not never."""
-        q = RequestQueue(max_depth=8)
-        b = Batcher(q, boundaries=(4,), max_batch=8, max_wait_s=0.05)
-        t0 = time.monotonic()
-        q.submit("a", [1, 2])
-        batch = b.next_batch(timeout=2.0)
-        waited = time.monotonic() - t0
-        assert batch is not None and len(batch) == 1
-        assert waited < 1.0, f"max-wait did not bound formation ({waited:.3f}s)"
-
-    def test_expired_request_never_enters_a_batch(self):
-        clock = FakeClock()
-        q, b = self._mk(clock)
-        r = q.submit("a", [1], deadline_s=0.5)
-        clock.advance(2.0)
-        assert b.next_batch(timeout=0) is None
-        with pytest.raises(DeadlineExceeded):
-            r.result(timeout=0)
 
 
 class TestKVSlotPool:
@@ -292,48 +230,21 @@ def test_jit_cache_size_counts_programs():
 
 
 @pytest.fixture(scope="module")
-def tiny_translator():
-    """Untrained tiny MT bundle — serving semantics don't need a trained
-    model, and init is ~instant where training is not."""
-    import jax
-
-    from machine_learning_apache_spark_tpu.data.datasets import (
-        synthetic_translation_pairs,
-    )
-    from machine_learning_apache_spark_tpu.data.text import TextPipeline
-    from machine_learning_apache_spark_tpu.inference import Translator
-    from machine_learning_apache_spark_tpu.models import (
-        Transformer,
-        TransformerConfig,
-    )
-
-    pairs = synthetic_translation_pairs(64, min_len=3, max_len=8, seed=0)
-    src_pipe = TextPipeline.fit([s for s, _ in pairs], max_seq_len=14)
-    trg_pipe = TextPipeline.fit([t for _, t in pairs], max_seq_len=14)
-    cfg = TransformerConfig(
-        src_vocab_size=len(src_pipe.vocab.itos),
-        trg_vocab_size=len(trg_pipe.vocab.itos),
-        d_model=32, ffn_hidden=64, num_heads=2, num_layers=1,
-        max_len=16, dropout=0.0,
-    )
-    model = Transformer(cfg)
-    dummy = np.ones((2, 8), np.int32)
-    params = model.init(jax.random.key(0), dummy, dummy)["params"]
-    return Translator(model, params, src_pipe, trg_pipe), [
-        s for s, _ in pairs
-    ]
+def tiny_translator(make_tiny_translator):
+    """Untrained tiny MT bundle over 64 sentence pairs."""
+    return make_tiny_translator(64)
 
 
 class TestEngineE2E:
     def test_concurrent_round_trip_matches_oneshot(self, tiny_translator):
-        """32 concurrent clients through the batcher produce exactly the
-        one-shot ``Translator.__call__`` outputs (bucket padding must be
-        semantics-free), with zero recompiles after warmup."""
+        """32 concurrent clients through the engine produce exactly the
+        one-shot ``Translator.__call__`` outputs (chunk padding and row
+        sharing must be semantics-free), with zero recompiles after
+        warmup."""
         t, texts = tiny_translator
         texts = texts[:32]
         with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8,
+            boundaries=(8, 16), max_batch=4, max_new_tokens=8,
         ) as eng:
             futs = [eng.submit(s) for s in texts]
             outs = [f.result(timeout=120) for f in futs]
@@ -391,7 +302,7 @@ class TestEngineE2E:
     def test_oversized_input_rejected_at_submit(self, tiny_translator):
         t, _ = tiny_translator
         with t.serve(boundaries=(8,), max_batch=2, max_new_tokens=4) as eng:
-            with pytest.raises(ValueError, match="largest bucket boundary"):
+            with pytest.raises(ValueError, match="largest prompt boundary"):
                 eng.submit("w " * 30)
 
     def test_stop_fails_queued_requests(self, tiny_translator):
@@ -402,32 +313,19 @@ class TestEngineE2E:
         t, texts = tiny_translator
         short = [s for s in texts if len(s.split()) <= 5][:3]
         eng = t.serve(
-            boundaries=(8,), max_batch=8, max_wait_s=30.0, max_new_tokens=4,
-            start=False,
+            boundaries=(8,), max_batch=8, max_new_tokens=4, start=False,
         )
         eng.start(warmup=False)
         reqs = [eng.submit(s) for s in short]
         eng.stop()
-        # 3 < max_batch and max_wait is 30s, so nothing shipped: every
-        # queued request must fail loudly, never hang
+        # a cold engine is still compiling its first program: whether a
+        # request was still queued or already on a row, it must fail
+        # loudly, never hang
         for r in reqs:
             with pytest.raises(EngineStopped):
                 r.result(timeout=5)
         ledger = eng.metrics.check_conservation(in_flight=0)
         assert ledger["submitted"] == 3 and ledger["failed"] == 3
-
-    def test_beam_method_serves(self, tiny_translator):
-        t, texts = tiny_translator
-        short = [s for s in texts if len(s.split()) <= 5][:4]
-        with t.serve(
-            boundaries=(8,), max_batch=2, max_new_tokens=4,
-            method="beam", beam_size=2,
-        ) as eng:
-            outs = [
-                f.result(timeout=120)
-                for f in [eng.submit(s) for s in short]
-            ]
-        assert outs == t(short, method="beam", beam_size=2, max_new_tokens=4)
 
 
 class TestKVPagePool:
@@ -749,24 +647,6 @@ class TestKVSlotPoolFairness:
 
 
 class TestPagedEngine:
-    def test_kv_mode_validation_and_env_override(self, tiny_translator):
-        t, _ = tiny_translator
-        with pytest.raises(ValueError, match="kv_mode"):
-            t.serve(boundaries=(8,), max_batch=2, kv_mode="ragged",
-                    start=False)
-        import os
-
-        os.environ["MLSPARK_SERVE_KV_MODE"] = "padded"
-        try:
-            eng = t.serve(boundaries=(8,), max_batch=2, start=False)
-            assert eng.kv_mode == "padded" and eng.runtime is None
-        finally:
-            del os.environ["MLSPARK_SERVE_KV_MODE"]
-        # explicit argument beats the env contract
-        eng = t.serve(boundaries=(8,), max_batch=2, kv_mode="paged",
-                      start=False)
-        assert eng.kv_mode == "paged" and eng.runtime is not None
-
     def test_mesh_trained_params_serve_from_one_device(self, tiny_translator):
         """Params trained under a mesh arrive replicated over all of its
         devices. The paged runtime lives on one: left on the mesh, the
@@ -797,20 +677,6 @@ class TestPagedEngine:
             }
         assert outs == t(texts[:6], max_new_tokens=8)
 
-    def test_padded_mode_still_matches_oneshot(self, tiny_translator):
-        """The legacy rectangle path stays selectable and correct — it is
-        the parity oracle the paged path is measured against."""
-        t, texts = tiny_translator
-        texts = texts[:8]
-        with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8, kv_mode="padded",
-        ) as eng:
-            outs = [f.result(timeout=120) for f in
-                    [eng.submit(s) for s in texts]]
-            assert eng.recompiles_after_warmup == 0
-        assert outs == t(texts, max_new_tokens=8)
-
     def test_zero_recompiles_across_ragged_occupancies(self, tiny_translator):
         """The paged tentpole invariant: after warmup, every wave shape —
         occupancy 1..max_active, short and long prompts interleaved,
@@ -820,8 +686,7 @@ class TestPagedEngine:
         short = [s for s in texts if len(s.split()) <= 5]
         long_ = [s for s in texts if len(s.split()) >= 7]
         with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8, kv_mode="paged",
+            boundaries=(8, 16), max_batch=4, max_new_tokens=8,
         ) as eng:
             waves = [
                 short[:1],                  # single row
@@ -845,6 +710,61 @@ class TestPagedEngine:
         for wave, outs in expect:
             assert outs == t(wave, max_new_tokens=8)
 
+    @pytest.mark.parametrize(
+        "max_active,steps_per_launch",
+        [(1, 1), (1, 4), (2, 2), (4, 1), (4, 3), (8, 4)],
+    )
+    def test_paged_matches_oneshot_across_launch_shapes(
+        self, tiny_translator, max_active, steps_per_launch
+    ):
+        """Whatever the launch program's shape — one row or eight, one
+        decode step a dispatch or four, a generation that ends inside a
+        launch or on its edge — the engine's answers are the one-shot
+        greedy decoder's, token for token."""
+        t, texts = tiny_translator
+        short = [s for s in texts if len(s.split()) <= 5][:6]
+        long_ = [s for s in texts if len(s.split()) >= 7][:6]
+        mixed = [s for pair in zip(short, long_) for s in pair]
+        assert len(mixed) == 12
+        with t.serve(
+            boundaries=(8, 16), max_active=max_active,
+            steps_per_launch=steps_per_launch, max_new_tokens=8,
+        ) as eng:
+            outs = [f.result(timeout=120) for f in
+                    [eng.submit(s) for s in mixed]]
+            assert eng.recompiles_after_warmup == 0
+            eng.metrics.check_conservation(in_flight=0)
+        assert outs == t(mixed, method="greedy", max_new_tokens=8)
+
+    def test_engine_owns_only_the_runtimes_programs(self, tiny_translator):
+        """One loop, one program set: what ``warmup()`` compiled is all
+        the engine counts, and nothing of a second decoder is built."""
+        t, _ = tiny_translator
+        eng = t.serve(boundaries=(8, 16), max_batch=2, max_new_tokens=4,
+                      start=False)
+        n = eng.warmup()
+        assert n == len(eng.runtime.jit_fns()) == eng.compile_count()
+        assert eng.recompiles_after_warmup == 0
+        assert not hasattr(eng, "_decoders")
+        assert not hasattr(eng, "batcher")
+
+    @pytest.mark.parametrize(
+        "knob,value",
+        [
+            ("kv_mode", "paged"), ("method", "greedy"), ("max_wait_s", 0.01),
+            ("num_slots", 8), ("beam_size", 2), ("length_penalty", 0.6),
+        ],
+    )
+    def test_removed_serving_knobs_are_rejected(
+        self, tiny_translator, knob, value
+    ):
+        """The padded engine's knobs went with it: passing one fails as
+        any unknown keyword does, instead of being accepted and ignored."""
+        t, _ = tiny_translator
+        with pytest.raises(TypeError, match=knob):
+            t.serve(boundaries=(8,), max_batch=2, start=False,
+                    **{knob: value})
+
     def test_kv_dtype_validation_and_env_override(self, tiny_translator):
         t, _ = tiny_translator
         import os
@@ -852,14 +772,6 @@ class TestPagedEngine:
         with pytest.raises(ValueError, match="kv_dtype"):
             t.serve(boundaries=(8,), max_batch=2, kv_dtype="int4",
                     start=False)
-        # int8 needs the paged store: padded mode and beam (which forces
-        # padded) both reject at construction, naming the resolution.
-        with pytest.raises(ValueError, match="requires the paged"):
-            t.serve(boundaries=(8,), max_batch=2, kv_mode="padded",
-                    kv_dtype="int8", start=False)
-        with pytest.raises(ValueError, match="method='beam'"):
-            t.serve(boundaries=(8,), max_batch=2, method="beam",
-                    beam_size=2, kv_dtype="int8", start=False)
         os.environ["MLSPARK_SERVE_KV_DTYPE"] = "int8"
         try:
             eng = t.serve(boundaries=(8,), max_batch=2, start=False)
@@ -890,8 +802,8 @@ class TestPagedEngine:
             short[:1],                  # repeat: prefix-cache hit
         ]
         with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8, kv_mode="paged", kv_dtype="int8",
+            boundaries=(8, 16), max_batch=4,
+            max_new_tokens=8, kv_dtype="int8",
             quantize_self=True,
         ) as eng:
             got = []
@@ -934,7 +846,7 @@ class TestPagedEngine:
         t, texts = tiny_translator
         with t.serve(
             boundaries=(8, 16), max_batch=4, max_new_tokens=8,
-            kv_mode="paged", prefix_cache_size=0,
+            prefix_cache_size=0,
         ) as eng:
             [f.result(timeout=120) for f in
              [eng.submit(s) for s in texts[:8]]]
@@ -946,7 +858,7 @@ class TestPagedEngine:
 
 def test_serve_bench_smoke_subprocess(tmp_path):
     """tools/serve_bench.py --smoke is the tier-1 CI entry: fresh
-    process, padded-vs-paged parity gate, the int8 accuracy (token
+    process, engine-vs-one-shot parity gate, the int8 accuracy (token
     match) and capacity (equal-byte ceiling) gates, and short paged +
     paged-int8 sweeps with the zero-recompile and conservation gates."""
     import json
@@ -1038,8 +950,7 @@ class TestObservabilityPlane:
         yield
         telemetry.reset()
 
-    @pytest.mark.parametrize("kv_mode", ["padded", "paged"])
-    def test_request_trace_timeline_end_to_end(self, tiny_translator, kv_mode):
+    def test_request_trace_timeline_end_to_end(self, tiny_translator):
         """Every request carries a trace from submit to completion: the
         mark vocabulary is present in order, the derived breakdown is
         sane, each request's annotation names the batches that served it,
@@ -1048,8 +959,7 @@ class TestObservabilityPlane:
 
         t, texts = tiny_translator
         with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8, kv_mode=kv_mode,
+            boundaries=(8, 16), max_batch=4, max_new_tokens=8,
         ) as eng:
             futs = [eng.submit(s) for s in texts[:8]]
             [f.result(timeout=120) for f in futs]
@@ -1060,7 +970,7 @@ class TestObservabilityPlane:
                 assert names[0] == "submit"
                 for required in ("batched", "admit", "first_token",
                                  "complete"):
-                    assert required in names, (kv_mode, names)
+                    assert required in names, names
                 bd = f.trace.breakdown()
                 assert bd["queue_wait_s"] >= 0.0
                 assert bd["ttft_s"] > 0.0
@@ -1122,8 +1032,7 @@ class TestObservabilityPlane:
         faults.install(FaultPlan.from_spec("raise@decode_batch:batch=0"))
         try:
             with t.serve(
-                boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-                max_new_tokens=8,
+                boundaries=(8, 16), max_batch=4, max_new_tokens=8,
             ) as eng:
                 srv = telemetry.get_http_server()
                 assert srv is not None
@@ -1184,7 +1093,7 @@ class TestObservabilityPlane:
         eng = t.serve(
             boundaries=(8, 16), max_batch=4, max_new_tokens=8, start=False
         )
-        real = eng._decode_loop
+        real = eng._paged_loop
         died = {"n": 0}
 
         def dying_then_real():
@@ -1193,7 +1102,7 @@ class TestObservabilityPlane:
                 raise RuntimeError("decode loop death (injected)")
             real()
 
-        eng._decode_loop = dying_then_real
+        eng._paged_loop = dying_then_real
         eng.start()
         try:
             srv = telemetry.get_http_server()
@@ -1215,8 +1124,7 @@ class TestObservabilityPlane:
 
         t, texts = tiny_translator
         with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8,
+            boundaries=(8, 16), max_batch=4, max_new_tokens=8,
         ) as eng:
             srv = telemetry.get_http_server()
             assert srv is not None
@@ -1286,8 +1194,8 @@ class TestPagedCancellation:
         t, texts = tiny_translator
         wave = texts[:4]
         with t.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8, kv_mode="paged", kv_dtype=kv_dtype,
+            boundaries=(8, 16), max_batch=4,
+            max_new_tokens=8, kv_dtype=kv_dtype,
             steps_per_launch=1,
         ) as eng:
             # Warm wave: completes normally and seeds the prefix cache,

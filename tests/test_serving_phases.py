@@ -21,35 +21,9 @@ LAUNCH_PARTS = (
 
 
 @pytest.fixture(scope="module")
-def tiny_translator():
-    """Untrained tiny MT bundle (mirrors tests/test_serving.py)."""
-    import jax
-
-    from machine_learning_apache_spark_tpu.data.datasets import (
-        synthetic_translation_pairs,
-    )
-    from machine_learning_apache_spark_tpu.data.text import TextPipeline
-    from machine_learning_apache_spark_tpu.inference import Translator
-    from machine_learning_apache_spark_tpu.models import (
-        Transformer,
-        TransformerConfig,
-    )
-
-    pairs = synthetic_translation_pairs(48, min_len=3, max_len=8, seed=0)
-    src_pipe = TextPipeline.fit([s for s, _ in pairs], max_seq_len=14)
-    trg_pipe = TextPipeline.fit([t for _, t in pairs], max_seq_len=14)
-    cfg = TransformerConfig(
-        src_vocab_size=len(src_pipe.vocab.itos),
-        trg_vocab_size=len(trg_pipe.vocab.itos),
-        d_model=32, ffn_hidden=64, num_heads=2, num_layers=1,
-        max_len=16, dropout=0.0,
-    )
-    model = Transformer(cfg)
-    dummy = np.ones((2, 8), np.int32)
-    params = model.init(jax.random.key(0), dummy, dummy)["params"]
-    return Translator(model, params, src_pipe, trg_pipe), [
-        s for s, _ in pairs
-    ]
+def tiny_translator(make_tiny_translator):
+    """Untrained tiny MT bundle over 48 sentence pairs."""
+    return make_tiny_translator(48)
 
 
 @pytest.fixture(autouse=True)
@@ -67,7 +41,7 @@ def _serve(translator, texts, **kw):
     metrics' ledger, metrics)."""
     t = translator
     with t.serve(
-        boundaries=(8, 16), max_batch=4, max_new_tokens=8, kv_mode="paged",
+        boundaries=(8, 16), max_batch=4, max_new_tokens=8,
         **kw,
     ) as eng:
         futs = [eng.submit(s) for s in texts]
@@ -231,7 +205,7 @@ def test_an_idle_engine_writes_one_open_span_and_nothing_more(
     the flight recorder's tail."""
     t, texts = tiny_translator
     with t.serve(
-        boundaries=(8, 16), max_batch=4, max_new_tokens=8, kv_mode="paged",
+        boundaries=(8, 16), max_batch=4, max_new_tokens=8,
     ) as eng:
         eng.submit(texts[0]).result(timeout=120)
         def idling():

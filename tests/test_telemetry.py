@@ -736,6 +736,46 @@ class TestRequestReport:
         assert len(m.request_exemplars()) == 3
 
 
+def test_serving_rollup_of_a_paged_gang_has_no_padded_mode(
+    make_tiny_translator
+):
+    """engine -> event log -> serving_report: the engine stamps every
+    ``serving.batch`` span with its one mode, the rollup lists that mode
+    alone, and a span that carries none (a foreign log) is not taken for
+    a padded engine's."""
+    translator, texts = make_tiny_translator(16)
+    with translator.serve(
+        boundaries=(8, 16), max_active=4, max_new_tokens=4,
+    ) as eng:
+        for req in [eng.submit(s) for s in texts[:6]]:
+            req.result(timeout=120)
+    evs = [e.to_dict() for e in events.get_log().snapshot()]
+    launches = sum(
+        e["kind"] == "span_end" and e["name"] == "serving.batch" for e in evs
+    )
+    rep = aggregate.serving_report(evs)
+    assert launches >= 1
+    assert list(rep["batches_by_mode"]) == ["paged"]
+    assert rep["batches_by_mode"]["paged"]["count"] == launches
+    assert set(rep["counters"]) >= {
+        "serving.tokens_real", "serving.tokens_padded"
+    }
+    assert 0.0 <= rep["padding_waste"] < 1.0
+    md = aggregate.render_markdown({
+        "ranks": [0], "event_count": len(evs), "phases": {}, "skew": {},
+        "serving": rep,
+    })
+    assert "## Serving" in md and "| paged |" in md
+    assert "| padded |" not in md
+    evs.append({
+        "kind": "span_end", "name": "serving.batch", "value": 0.5,
+        "rank": 0, "attrs": {},
+    })
+    assert list(aggregate.serving_report(evs)["batches_by_mode"]) == [
+        "paged", "unknown",
+    ]
+
+
 class TestStatusMarkdown:
     def test_render_rows_and_step_skew(self):
         rows = [

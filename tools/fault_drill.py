@@ -267,8 +267,7 @@ def scenario_serving_poison(workdir: str) -> dict:
     texts = [s for s, _ in pairs][:12]
     try:
         with translator.serve(
-            boundaries=(8, 16), max_batch=4, max_wait_s=0.01,
-            max_new_tokens=8,
+            boundaries=(8, 16), max_batch=4, max_new_tokens=8,
         ) as eng:
             futs = [eng.submit(s) for s in texts]
             served = failed = 0

@@ -78,9 +78,9 @@ def bench_knobs(tiny: bool) -> dict:
     """Per-replica engine knobs — the serve_bench paged profile, so the
     fleet columns are comparable to the single-engine bench's."""
     return dict(
-        boundaries=(8, 16), max_batch=8, max_wait_s=0.005,
+        boundaries=(8, 16), max_batch=8,
         max_queue_depth=128, max_new_tokens=10, prefix_cache_size=256,
-        steps_per_launch=10, max_active=16, kv_mode="paged",
+        steps_per_launch=10, max_active=16,
     )
 
 

@@ -49,7 +49,7 @@ from machine_learning_apache_spark_tpu.utils.sysinfo import host_load  # noqa: E
 #: bench re-pins was measured under these; a different engine config
 #: would compare two different machines' worth of work.
 SERVE_KNOBS = dict(
-    boundaries=(8, 16), max_batch=8, max_wait_s=0.005,
+    boundaries=(8, 16), max_batch=8,
     max_queue_depth=128, max_new_tokens=10, prefix_cache_size=256,
     steps_per_launch=10, max_active=16,
 )
@@ -123,8 +123,8 @@ def engine_trace_complete() -> dict:
 
 
 def fleet_trace_complete(translator, texts, n_requests: int) -> dict:
-    """2-replica fleet section: one paged and one padded replica behind
-    real HTTP data planes, a round-robin router minting one context per
+    """2-replica fleet section: two engine replicas behind real HTTP
+    data planes, a round-robin router minting one context per
     request, and the traceview verdict over exactly the minted trace
     ids — every one must root at ``fleet.submit`` and resolve its
     cross-process ``remote_parent`` edge."""
@@ -143,10 +143,9 @@ def fleet_trace_complete(translator, texts, n_requests: int) -> dict:
     engines, servers, payloads = [], [], []
     with tempfile.TemporaryDirectory(prefix="trace_bench_fleet_") as tmp:
         try:
-            for rank, kv_mode in enumerate(("paged", "padded")):
+            for rank in range(2):
                 eng = translator.serve(
-                    boundaries=(8, 16), max_batch=4, max_wait_s=0.005,
-                    max_new_tokens=8, kv_mode=kv_mode,
+                    boundaries=(8, 16), max_batch=4, max_new_tokens=8,
                 )
                 engines.append(eng)
                 srv = ReplicaServer(eng, rank=rank, port=0)
