@@ -603,42 +603,82 @@ def run_kernels(size: Size) -> None:
         return err(got, dot_product_attention(q, k, v, causal=True,
                                               use_pallas=False))
 
-    def scan_case() -> dict:
-        """The chunked gated delta rule, forward and backward, against its
-        per-token recurrence, in float32 at ``Precision.HIGHEST`` (a length
-        that is no multiple of the chunk): not a Pallas kernel, but the op
-        a new jax must still compile and transpose on the chip."""
+    def scan_case(dt) -> tuple[dict, dict, str]:
+        """The chunked gated delta rule, forward and the five gradients,
+        against its per-token recurrence (a length that is no multiple of
+        the chunk): ``(largest error, the recurrence's largest value, the
+        path the site took)``. float32 runs every product at
+        ``Precision.HIGHEST``; bfloat16 is what the benchmark's cell runs.
+        On the chip both take the Pallas chunk kernels (``pallas_chunk``),
+        in a rehearsal the ``lax.scan`` path: the dispatch observes the
+        backend, the smoke does not steer it."""
+        from machine_learning_apache_spark_tpu import telemetry
+
         b, t, hk, hv, dk, dv, chunk = size.scan_shape
         unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-        q = unit(rnd((b, t, hk, dk), 0, f32)) * dk ** -0.5
-        k = unit(rnd((b, t, hk, dk), 1, f32))
-        v, w = rnd((b, t, hv, dv), 2, f32), rnd((b, t, hv, dv), 3, f32)
+        q = (unit(rnd((b, t, hk, dk), 0, f32)) * dk ** -0.5).astype(dt)
+        k = unit(rnd((b, t, hk, dk), 1, f32)).astype(dt)
+        v, w = rnd((b, t, hv, dv), 2, dt), rnd((b, t, hv, dv), 3, f32)
         g = -0.1 * jax.nn.sigmoid(rnd((b, t, hv), 4, f32))
         beta = jax.nn.sigmoid(rnd((b, t, hv), 5, f32))
 
         def both(rule):
             def loss(*a):
-                return jnp.sum(rule(*a)[0] * w)
+                return jnp.sum(rule(*a)[0].astype(f32) * w)
             return jax.jit(lambda *a: (
                 rule(*a)[0], jax.grad(loss, argnums=range(5))(*a)
             ))(q, k, v, g, beta)
 
-        out, grads = both(lambda *a: gated_delta_rule(*a, chunk=chunk))
+        telemetry.get_log().clear()
+        out, grads = both(lambda *a: gated_delta_rule(
+            *a, chunk=chunk, site="chip_smoke"))
+        took = sorted({
+            f"{e.attrs['impl']} ({e.attrs['reason']})"
+            for e in telemetry.get_log().snapshot()
+            if e.name == "ops.gated_delta_dispatch"
+        })
         want, want_grads = both(gated_delta_recurrent)
-        found = {"gated_delta_fwd[float32]": err(out, want)}
+        tag = jnp.dtype(dt).name
+        found = {f"gated_delta_fwd[{tag}]": (out, want)}
         for name, a, r in zip(("dq", "dk", "dv", "dg", "dbeta"), grads, want_grads):
-            found[f"gated_delta_bwd_{name}[float32]"] = err(a, r)
-        return found
+            found[f"gated_delta_bwd_{name}[{tag}]"] = (a, r)
+        return (
+            {k: err(a, r) for k, (a, r) in found.items()},
+            {k: float(jnp.max(jnp.abs(r.astype(f32)))) for k, (_, r) in found.items()},
+            "; ".join(took),
+        )
 
     with phase("kernels") as info:
         results: dict = {}
-        scan = scan_case()
+        scan, _, took = scan_case(f32)
         info["gated_delta_max_abs_err_vs_recurrence"] = {
             k: round(v, 7) for k, v in scan.items()
         }
+        info["gated_delta_path"] = {"float32": took}
         bad = {k: v for k, v in scan.items() if not v <= 1e-3}
         require(not bad, f"chunked gated delta rule agrees with the "
                 f"recurrence within 1e-3 in float32: {bad}")
+        # The path the benchmark's cell takes: bfloat16 operands. A rounding
+        # is 2^-8 of a value and a chunk holds a few of them, so the bar is
+        # 3 % of the recurrence's own largest value (and of 1).
+        scan, scale, took = scan_case(jnp.bfloat16)
+        info["gated_delta_max_abs_err_vs_recurrence"].update(
+            {k: round(v, 5) for k, v in scan.items()}
+        )
+        info["gated_delta_recurrence_max_abs"] = {
+            k: round(v, 4) for k, v in scale.items()
+        }
+        info["gated_delta_path"]["bfloat16"] = took
+        bad = {k: (v, scale[k]) for k, v in scan.items()
+               if not v <= 0.03 * (1.0 + scale[k])}
+        require(not bad, f"chunked gated delta rule agrees with the "
+                f"recurrence within 3 % in bfloat16: {bad}")
+        on_chip = not size.interpret
+        for tag, path in info["gated_delta_path"].items():
+            require(path.startswith("pallas_chunk") == on_chip,
+                    f"gated delta site ({tag}) took "
+                    f"{'the chunk kernels' if on_chip else 'the lax.scan path'}"
+                    f": {path}")
         for causal in (False, True):
             for masked in (False, True):
                 results.update(flash_case(causal, masked, dtype))

@@ -24,11 +24,31 @@ factors). With ``T = (I + A)^-1`` applied by forward substitution, in float32,
     o_i   = exp(G_i) q_i S0 + tril(q k^T exp(G_i - G_j)) u
     S_end = exp(G_C) S0 + (exp(G_C - G_i) k_i)^T u
 
-Everything but the state hand-off is batched over all chunks; one
-``lax.scan`` over the chunks carries the float32 state, and a second batched
-pass forms the outputs from each chunk's starting state. The backward pass
-is the transpose of the same chunked program (JAX autodiff through the scan
-and the triangular solve): no per-token loop in either direction.
+The in-chunk preparation (``A``, the solve giving ``value`` and ``w``, the
+decays, ``tril(q k^T decay)``) is batched over all chunks, and JAX
+differentiates it. What is sequential, the state's hand-off from chunk to
+chunk and the two output products that read the state, runs in one of two
+places, with the same products and the same two rounding points (the state
+and ``u`` rounded to the operands' dtype before they enter a product):
+
+* **``pallas_chunk``** — one Pallas (Mosaic) kernel a direction
+  (``ops.pallas_gated_delta``: ``gdn_chunk_fwd``, ``gdn_chunk_bwd``), grid ``(B*H / head_block, chunks)``
+  with the chunk axis sequential and the float32 state of ``head_block``
+  heads in VMEM scratch across it; blocks are indexed ``(head, chunk)``
+  straight out of the ``[B*H, n, C, d]`` arrays. The primal writes the
+  outputs and the final state only; under differentiation the forward also
+  writes each chunk's starting state (rounded) and ``u``, and the backward
+  kernel walks the chunks in reverse with the state's cotangent in VMEM.
+  Taken where the site can be seen to allow it (``_kernel_refusal``): a TPU
+  backend, ``dk`` and ``dv`` multiples of 128, ``chunk`` a multiple of the
+  dtype's sublane tile, no mesh of more than one device active.
+* **``chunked_scan``** — anywhere else (every CPU test, odd head widths):
+  one ``lax.scan`` over the chunks carries the float32 state, a second
+  batched pass forms the outputs from each chunk's starting state, and the
+  backward is JAX autodiff through the scan.
+
+Which one a site took, and why, is its ``ops.gated_delta_dispatch`` record.
+No per-token loop in either direction, either way.
 
 Products take operands in the inputs' dtype and accumulate in float32;
 decays, the solve and the state are float32 throughout. With float32 inputs every
@@ -112,6 +132,94 @@ def _solve_unit_lower(a: jnp.ndarray, rhs: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+# -- where the sequential part runs -----------------------------------------
+
+_LANES = 128
+# Heads a grid step. A step's products are small (a [64, 128] tile against a
+# [128, 128] state) and a head's chain is sequential, so several heads a step
+# give the scheduler independent chains and amortise the step's fixed cost:
+# at the benchmark's site 16 heads a step read 2.7 times faster than 1 and
+# 6 % faster than 8, and 32 do not fit Mosaic's default scoped VMEM
+# (tools/gdn_chunk_sweep.py; PERF.md section 6, PR 29).
+MAX_HEAD_BLOCK = 16
+# What a launch may take of VMEM as ``_vmem_bytes`` reckons it (the compiled
+# kernels allocate 0.8 to 0.9 of the reckoning): under Mosaic's default
+# scoped limit of 16 MiB, so no launch has to ask for more.
+VMEM_BUDGET = 12 * 2**20
+
+
+def _backend() -> str:
+    """The backend a launch is traced for: it decides both whether a site
+    takes the kernel and, where a test sends one there on a CPU, that the
+    kernel is interpreted."""
+    return jax.default_backend()
+
+
+def _sublanes(dtype) -> int:
+    """Rows of the dtype's VMEM tile: 8 for float32, 16 for bfloat16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _kernel_refusal(dtype, chunk: int, dk: int, dv: int) -> str | None:
+    """Why this site keeps the ``lax.scan`` hand-off; ``None`` where it can
+    be seen to take the kernel."""
+    from machine_learning_apache_spark_tpu.ops.attention import (
+        active_kernel_mesh,
+    )
+
+    if _backend() != "tpu":
+        return f"backend {_backend()}"
+    if dk % _LANES or dv % _LANES:
+        return f"dk {dk}, dv {dv} not multiples of {_LANES}"
+    if chunk % _sublanes(dtype):
+        return (
+            f"chunk {chunk} not a multiple of {jnp.dtype(dtype).name}'s "
+            f"{_sublanes(dtype)} sublanes"
+        )
+    mesh = active_kernel_mesh()
+    if mesh is not None and mesh.size > 1:
+        return f"a mesh of {mesh.size} devices is active"
+    return None
+
+
+def _vmem_bytes(
+    kernel: str, head_block: int, chunk: int, dk: int, dv: int, itemsize: int
+) -> int:
+    """VMEM a grid step of ``kernel`` ("fwd", "fwd_save", "bwd") holds,
+    reckoned: the pipeline's two buffers of every operand and output block
+    (a block's last dimension padded to whole lanes, a ``[1, dv]`` float32
+    row to 8 sublanes; the state enters and leaves as float32 blocks) and
+    the float32 state in scratch."""
+    def tile(rows, cols, size):
+        return rows * -(-cols // _LANES) * _LANES * size
+
+    wide_k, wide_v = tile(chunk, dk, itemsize), tile(chunk, dv, itemsize)
+    square, row = tile(chunk, chunk, itemsize), tile(8, dv, 4)
+    state, rounded = tile(dk, dv, 4), tile(dk, dv, itemsize)
+    blocks = {
+        "fwd": 3 * wide_k + 2 * wide_v + square + row,
+        "fwd_save": 3 * wide_k + 3 * wide_v + square + row + rounded,
+        "bwd": 6 * wide_k + 3 * wide_v + 2 * square + 2 * row + rounded,
+    }[kernel]
+    return head_block * (2 * (blocks + 2 * state) + state)
+
+
+def _choose_head_block(
+    heads: int, chunk: int, dk: int, dv: int, itemsize: int
+) -> int:
+    """Heads a grid step, off the launch's own shapes: the largest divisor
+    of ``heads`` (``B*H``: no head is padded) up to ``MAX_HEAD_BLOCK`` whose
+    backward kernel, the fullest of the three, is reckoned under
+    ``VMEM_BUDGET``."""
+    return max(
+        hb for hb in range(1, min(heads, MAX_HEAD_BLOCK) + 1)
+        if heads % hb == 0 and (
+            hb == 1
+            or _vmem_bytes("bwd", hb, chunk, dk, dv, itemsize) <= VMEM_BUDGET
+        )
+    )
+
+
 def gated_delta_rule(
     q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK, initial_state=None,
     site: str = "gated_delta",
@@ -128,11 +236,6 @@ def gated_delta_rule(
         else jax.lax.Precision.DEFAULT
     )
     n = -(-t // chunk)
-    record_dispatch(
-        site, "chunked_scan",
-        f"lax.scan over {n} chunks of {chunk}, WY form inside a chunk",
-        batch=b, length=t, heads=heads, dk=dk, dv=dv, dtype=str(dtype),
-    )
     pad = n * chunk - t
 
     def chunks(x):
@@ -179,26 +282,70 @@ def gated_delta_rule(
         if initial_state is None else initial_state.astype(jnp.float32)
     )
 
-    def hand_off(s, xs):
-        w_i, value_i, k_tail_i, g_end_i = xs
-        u = value_i - dot("bhik,bhkv->bhiv", w_i, s.astype(dtype))
-        u = u.astype(dtype)
-        s_next = s * jnp.exp(g_end_i)[..., None, None] + dot(
-            "bhik,bhiv->bhkv", k_tail_i, u
-        )
-        return s_next, (s.astype(dtype), u)
-
-    chunk_major = lambda x: jnp.moveaxis(x, 2, 0)  # noqa: E731
-    final, (starts, u) = jax.lax.scan(
-        hand_off, state0, tuple(map(chunk_major, (w, value, k_tail, g_end)))
-    )
-    starts = jnp.moveaxis(starts, 0, 2)  # [B, H, n, dk, dv]
-    u = jnp.moveaxis(u, 0, 2)            # [B, H, n, C, dv]
-
     q_decayed = (q * jnp.exp(big_g)[..., None]).astype(dtype)
-    qk = dot("bhnid,bhnjd->bhnij", q, k) * decay          # i >= j kept
-    out = dot("bhnid,bhndv->bhniv", q_decayed, starts) + dot(
-        "bhnij,bhnjv->bhniv", qk.astype(dtype), u
-    )
+    qk = (dot("bhnid,bhnjd->bhnij", q, k) * decay).astype(dtype)  # i >= j kept
+    shape = dict(batch=b, length=t, heads=heads, dk=dk, dv=dv, dtype=str(dtype))
+
+    refusal = _kernel_refusal(dtype, chunk, dk, dv)
+    if refusal is None:
+        head_block = _choose_head_block(
+            b * heads, chunk, dk, dv, dtype.itemsize
+        )
+        vmem = {
+            kernel: _vmem_bytes(
+                kernel, head_block, chunk, dk, dv, dtype.itemsize
+            ) for kernel in ("fwd", "fwd_save", "bwd")
+        }
+        record_dispatch(
+            site, "pallas_chunk",
+            f"gdn_chunk_fwd / gdn_chunk_bwd head_block {head_block} grid "
+            f"{b * heads // head_block}x{n} vmem "
+            + " / ".join(f"{v / 2**20:.1f}" for v in vmem.values())
+            + f" MiB (primal / saving forward / backward); of [{b * heads},"
+            f"{n},{chunk},{dk}|{dv}] {dtype.name}"
+            + (" at Precision.HIGHEST" if dtype == jnp.float32 else ""),
+            head_block=head_block, grid=(b * heads // head_block, n),
+            vmem_bytes=vmem, **shape,
+        )
+        flat = lambda x: x.reshape(b * heads, *x.shape[2:])  # noqa: E731
+        # imported here: Pallas costs a second at import, and only a site
+        # that takes the kernels needs it
+        from machine_learning_apache_spark_tpu.ops.pallas_gated_delta import (
+            chunk_scan,
+        )
+
+        out, final = chunk_scan(
+            (head_block, _backend() != "tpu"), *map(flat, (
+                w, value, k_tail, q_decayed, qk, jnp.exp(g_end), state0
+            ))
+        )
+        out = out.reshape(b, heads, n, chunk, dv)
+        final = final.reshape(b, heads, dk, dv)
+    else:
+        record_dispatch(
+            site, "chunked_scan",
+            f"lax.scan over {n} chunks of {chunk}, WY form inside a chunk: "
+            + refusal, **shape,
+        )
+
+        def hand_off(s, xs):
+            w_i, value_i, k_tail_i, g_end_i = xs
+            u = value_i - dot("bhik,bhkv->bhiv", w_i, s.astype(dtype))
+            u = u.astype(dtype)
+            s_next = s * jnp.exp(g_end_i)[..., None, None] + dot(
+                "bhik,bhiv->bhkv", k_tail_i, u
+            )
+            return s_next, (s.astype(dtype), u)
+
+        chunk_major = lambda x: jnp.moveaxis(x, 2, 0)  # noqa: E731
+        final, (starts, u) = jax.lax.scan(
+            hand_off, state0,
+            tuple(map(chunk_major, (w, value, k_tail, g_end))),
+        )
+        starts = jnp.moveaxis(starts, 0, 2)  # [B, H, n, dk, dv]
+        u = jnp.moveaxis(u, 0, 2)            # [B, H, n, C, dv]
+        out = dot("bhnid,bhndv->bhniv", q_decayed, starts) + dot(
+            "bhnij,bhnjv->bhniv", qk, u
+        )
     out = jnp.moveaxis(out, 1, 3).reshape(b, n * chunk, heads, dv)
     return out[:, :t].astype(dtype), final

@@ -65,6 +65,10 @@ def test_rehearsal_runs_every_phase_and_is_stamped_cpu(tmp_path):
     assert serve["first_disagreements"] == []
     assert serve["engine_devices"] == [0]  # one engine, one device
     assert report["phases"]["kernels"]["mode"] == "interpret"
+    # both dtypes of the scan check, and on a CPU the lax.scan path
+    paths = report["phases"]["kernels"]["gated_delta_path"]
+    assert set(paths) == {"float32", "bfloat16"}
+    assert all(p.startswith("chunked_scan (") for p in paths.values())
     assert report["phases"]["train"]["mesh_devices"] == 8
     sites = {a["site"] for a in serve["attention"]}
     assert sites == {"dot_product", "ragged_paged_decode"}

@@ -1,7 +1,7 @@
-"""The flash kernels compiled for a described TPU v5e, no chip attached: what
-interpret mode cannot see (a tile over the kernel's VMEM, a block Mosaic
-refuses), at the widths the benchmark's cell runs and at the shapes that
-share the launchers. Nothing runs; a compile that passes is not a chip run.
+"""The flash kernels and the Gated DeltaNet chunk kernels compiled for a
+described TPU v5e, no chip attached: what interpret mode cannot see (a tile
+over the kernel's VMEM, a block Mosaic refuses), at the widths the
+benchmark's cell runs and at the shapes that share the launchers. Nothing runs; a compile that passes is not a chip run.
 
 The topology is described inside a fixture, after collection, and every such
 test lives in this one file: only the worker that is given the file loads
@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from machine_learning_apache_spark_tpu.ops import gated_delta
 from machine_learning_apache_spark_tpu.ops.pallas_attention import (
     flash_attention,
 )
@@ -61,4 +62,35 @@ def test_chosen_tiles_compile_forward_and_backward(one_chip, site):
     ).compile()
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f"%{name}" in text, f"{name} is not in the compiled program"
+
+
+# (batch, length, key heads, value heads, dk = dv, dtype)
+SCAN_SITES = {
+    "q3next-4x4096x32x128-bf16": (4, 4096, 16, 32, 128, "bfloat16"),
+    "smoke-2x200x4x128-f32": (2, 200, 2, 4, 128, "float32"),
+    "3x130x3x256-bf16": (3, 130, 3, 3, 256, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("site", list(SCAN_SITES))
+def test_gated_delta_chunk_kernels_compile(one_chip, monkeypatch, site):
+    """The dispatch is told the backend is a TPU (here it would observe the
+    CPU); the shapes then pass its gate on their own, and the program holds
+    the primal's and the saving forward's kernel and the backward's."""
+    b, t, hk, hv, d, dtype = SCAN_SITES[site]
+    monkeypatch.setattr(gated_delta, "_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((b, t, hk, d), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, t, hv, d), dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((b, t, hv), jnp.float32, sharding=one_chip)
+
+    def loss(*a):
+        first, _ = gated_delta.gated_delta_rule(*a)  # the primal
+        out, final = jax.checkpoint(gated_delta.gated_delta_rule)(*a)
+        return jnp.sum((first + out).astype(jnp.float32)) + jnp.sum(final)
+
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        q, q, v, g, g
+    ).compile().as_text()
+    for name in ("gdn_chunk_fwd", "gdn_chunk_bwd"):
         assert f"%{name}" in text, f"{name} is not in the compiled program"
