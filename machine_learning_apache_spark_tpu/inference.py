@@ -340,6 +340,27 @@ class Translator:
         engine = ServingEngine(self, **engine_kwargs)
         return engine.start() if start else engine
 
+    # -- what ``ServingEngine`` asks of a bundle ---------------------------------
+    @property
+    def max_positions(self) -> int:
+        return self.model.cfg.max_len
+
+    def encode(self, text: str):
+        return self.src_pipe.ragged([text])[0]
+
+    def decode(self, ids) -> str:
+        return " ".join(self.trg_pipe.vocab.lookup_tokens(ids))
+
+    def make_runtime(self, **kwargs):
+        from machine_learning_apache_spark_tpu.serving.paged_runtime import (
+            PagedDecodeRuntime,
+        )
+
+        return PagedDecodeRuntime(
+            self.model, self.params, sos_id=SOS_ID, eos_id=EOS_ID,
+            pad_id=self.model.cfg.pad_id, **kwargs,
+        )
+
     # -- persistence ----------------------------------------------------------
     def save(self, directory: str) -> None:
         """One directory = one deployable model: params (orbax) + config +
@@ -399,4 +420,119 @@ class Translator:
             params,
             pipe(meta["src_vocab"], meta["src_pipe"]),
             pipe(meta["trg_vocab"], meta["trg_pipe"]),
+        )
+
+
+class LanguageModel:
+    """A decoder-only language model (``models.sala_lm``) with its
+    parameters, callable on token ids and servable through the same
+    ``ServingEngine`` as a ``Translator``. Prompts and answers are arrays of
+    token ids: the bundle holds no tokenizer.
+
+    >>> lm = LanguageModel(cfg, params)
+    >>> lm([prompt_ids], max_new_tokens=16)          # greedy, one shot
+    >>> with lm.serve(max_context=4096, max_active=8, max_new_tokens=16) as eng:
+    ...     answer = eng.submit(prompt_ids).future.result(timeout=60)
+    """
+
+    def __init__(self, cfg, params):
+        from machine_learning_apache_spark_tpu.parallel.mesh import (
+            on_one_device,
+        )
+
+        self.cfg = cfg
+        self.params, _ = on_one_device(params)
+
+    def __call__(self, prompts, *, max_new_tokens: int, **runtime_kwargs):
+        """Greedy continuations of ``prompts`` (a list of id arrays), each an
+        int32 array of at most ``max_new_tokens`` ids: every prompt takes a
+        row of a runtime of its own size, prefilled and decoded to the end
+        (the engine's programs, without its queue)."""
+        import numpy as np
+
+        from machine_learning_apache_spark_tpu.serving.lm_runtime import (
+            LMDecodeRuntime,
+        )
+        from machine_learning_apache_spark_tpu.serving.queue import ServeRequest
+
+        prompts = [np.asarray(p, np.int32) for p in prompts]
+        runtime = LMDecodeRuntime(
+            self.cfg, self.params, max_active=len(prompts),
+            max_context=max(len(p) for p in prompts) + max_new_tokens,
+            max_new_tokens=max_new_tokens, snapshot_capacity=0,
+            **runtime_kwargs,
+        )
+        requests = [
+            ServeRequest(text="", ids=p, submit_time=0.0) for p in prompts
+        ]
+        for row, req in enumerate(requests):
+            if runtime.admit(req, row) is None:
+                raise RuntimeError("the default page pool holds every row")
+        answers = {}
+        while runtime.any_active():
+            if runtime.grow():
+                raise RuntimeError("the default page pool holds every row")
+            for req, ids, row, _ in runtime.launch().completed:
+                runtime.retire(row)
+                answers[req.id] = np.asarray(ids, np.int32)
+        return [answers[r.id] for r in requests]
+
+    def serve(self, *, start: bool = True, max_context: int, **engine_kwargs):
+        """Continuous-batching server over this model: ``ServingEngine`` with
+        the decoder-only runtime (``serving.lm_runtime``). ``max_context``
+        bounds prompt plus new tokens; ``prefix_cache_size`` is the number of
+        prefix snapshots kept. ``submit`` takes a sequence of token ids and
+        the future resolves to an int32 array of the new ids.
+
+        >>> with lm.serve(max_context=66560, max_active=32,
+        ...               max_new_tokens=64, prefill_chunk=512,
+        ...               steps_per_launch=8) as eng:
+        ...     req = eng.submit(ids)
+        """
+        from machine_learning_apache_spark_tpu.serving import ServingEngine
+
+        new = engine_kwargs.get("max_new_tokens")
+        if new is None:
+            raise TypeError("serve() needs max_new_tokens")
+        engine_kwargs.setdefault("boundaries", (max_context - new,))
+        engine_kwargs.setdefault("page_size", self.cfg.sparse.block)
+        engine = ServingEngine(self, **engine_kwargs)
+        return engine.start() if start else engine
+
+    # -- what ``ServingEngine`` asks of a bundle ---------------------------------
+    @property
+    def max_positions(self) -> int:
+        return self.cfg.max_positions
+
+    def encode(self, ids):
+        import numpy as np
+
+        return np.asarray(ids, np.int32)
+
+    def decode(self, ids):
+        import numpy as np
+
+        return np.asarray(ids, np.int32)
+
+    def make_runtime(self, *, max_src, max_new_tokens, page_size,
+                     prefix_cache_size, kv_dtype, quantize_self, **kwargs):
+        from machine_learning_apache_spark_tpu.serving.lm_runtime import (
+            LMDecodeRuntime,
+        )
+
+        if page_size != self.cfg.sparse.block:
+            raise ValueError(
+                f"page_size {page_size}: a page is the selector's block "
+                f"({self.cfg.sparse.block} positions)"
+            )
+        if kv_dtype != "float32" or quantize_self:
+            raise ValueError(
+                "the decoder-only runtime keeps its pages in the model's "
+                "dtype; kv_dtype / quantize_self belong to the "
+                "encoder-decoder runtime"
+            )
+        return LMDecodeRuntime(
+            self.cfg, self.params, max_context=max_src + max_new_tokens,
+            max_new_tokens=max_new_tokens,
+            snapshot_capacity=prefix_cache_size, **kwargs,
         )

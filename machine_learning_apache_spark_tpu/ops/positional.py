@@ -60,3 +60,25 @@ def rotary_embedding(
     b = x[..., half:rotary_dim].astype(jnp.float32)
     turned = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
     return jnp.concatenate([turned.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
+def rotary_embedding_at(
+    x: jnp.ndarray, positions: jnp.ndarray, *, theta: float = 10000.0
+) -> jnp.ndarray:
+    """Rotary positions on every channel of ``x [..., d]`` at the given
+    ``positions`` (broadcastable to ``x.shape[:-1]``): what a served model
+    needs, whose chunk or decode step starts anywhere in the sequence. Same
+    rotate-half layout as ``rotary_embedding``; the angles are worked out in
+    float32 where they are used."""
+    d = x.shape[-1]
+    half = d // 2
+    inv_freq = jnp.asarray(
+        theta ** (-np.arange(0, d, 2, dtype=np.float64) / d), jnp.float32
+    )
+    angles = jnp.asarray(positions, jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    a = x[..., :half].astype(jnp.float32)
+    b = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin], axis=-1
+    ).astype(x.dtype)

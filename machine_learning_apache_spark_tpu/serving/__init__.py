@@ -8,6 +8,11 @@ the paged decode loop while ``metrics`` keeps the latency/throughput
 ledger (padding-waste accounting included). Entry point:
 ``Translator.serve()``.
 
+One engine drives two runtimes through one interface: the
+encoder-decoder ``paged_runtime`` and the decoder-only ``lm_runtime``
+(imported where ``LanguageModel.serve()`` takes it), whose prefix reuse is
+``kv_pages.SnapshotCache`` (pages and a linear-attention state together).
+
 A refcounted page pool + prefix cache (``kv_pages``) backs one device
 page store; a token-budget admission picker
 (``batcher.TokenBudgetBatcher``) paces chunked prefill; one compiled
@@ -28,6 +33,7 @@ from machine_learning_apache_spark_tpu.serving.kv_pages import (
     NULL_PAGE,
     KVPagePool,
     PrefixCache,
+    SnapshotCache,
     prefix_digest,
 )
 from machine_learning_apache_spark_tpu.serving.kv_slots import KVSlotPool
@@ -60,6 +66,7 @@ __all__ = [
     "ServeRequest",
     "ServingEngine",
     "ServingMetrics",
+    "SnapshotCache",
     "TokenBudgetBatcher",
     "prefix_digest",
 ]

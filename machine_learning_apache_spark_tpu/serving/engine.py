@@ -1,4 +1,16 @@
-"""Serving engine — the paged decode loop behind ``Translator.serve()``.
+"""Serving engine — the paged decode loop behind ``Translator.serve()`` and
+``LanguageModel.serve()``.
+
+One loop, two runtimes: the engine owns the queue, the batcher, the row pool,
+the metrics and the phase spans, and drives the device through the runtime
+its bundle builds (``bundle.make_runtime``): ``PagedDecodeRuntime`` for the
+encoder-decoder ``Translator``, ``LMDecodeRuntime`` for the decoder-only
+``LanguageModel``. A runtime offers ``warmup``, ``jit_fns``, ``prefill_cost``,
+``admit``, ``grow``, ``launch``, ``retire``, ``reset``, ``stats``, the
+``active_*`` views, and its ``mem_pool`` / ``prefix_cache`` for the live
+plane; the bundle offers ``encode`` (a request's text or ids to prompt ids),
+``decode`` (emitted ids to the caller's result), ``max_positions`` and
+``make_runtime``.
 
 Caller threads tokenize and ``submit()`` into a bounded admission queue;
 one background worker turns the queue into device work, cycle by cycle:
@@ -33,16 +45,12 @@ from machine_learning_apache_spark_tpu import telemetry
 from machine_learning_apache_spark_tpu.telemetry import (
     tracectx as _tracectx,
 )
-from machine_learning_apache_spark_tpu.data.text import EOS_ID, SOS_ID
 from machine_learning_apache_spark_tpu.utils import env as envcfg
 from machine_learning_apache_spark_tpu.serving.batcher import (
     TokenBudgetBatcher,
 )
 from machine_learning_apache_spark_tpu.serving.kv_slots import KVSlotPool
 from machine_learning_apache_spark_tpu.serving.metrics import ServingMetrics
-from machine_learning_apache_spark_tpu.serving.paged_runtime import (
-    PagedDecodeRuntime,
-)
 from machine_learning_apache_spark_tpu.serving.queue import (
     DeadlineExceeded,
     RequestQueue,
@@ -108,9 +116,9 @@ class _HealthWindow:
 
 
 class ServingEngine:
-    """Continuous-batching server over a ``Translator``-shaped bundle
-    (``model``, ``params``, ``src_pipe``, ``trg_pipe``): a page-table KV
-    store, chunk-padded prefill, refcounted prefix sharing, immediate
+    """Continuous-batching server over a ``Translator`` or a
+    ``LanguageModel`` (the bundle builds the runtime; the loop is one): a
+    page-table KV store, chunk-padded prefill, refcounted prefix sharing, immediate
     FIFO admission and ONE compiled ragged decode program for any batch
     occupancy/length mix. Decoding is greedy and token-identical to
     ``Translator.__call__``; beam search is offline only
@@ -155,12 +163,12 @@ class ServingEngine:
         prefill_budget: int | None = None,
         clock=time.monotonic,
     ):
-        cfg = translator.model.cfg
+        max_positions = translator.max_positions
         boundaries = tuple(sorted(boundaries))
-        if boundaries[-1] > cfg.max_len:
+        if boundaries[-1] > max_positions:
             raise ValueError(
                 f"largest boundary {boundaries[-1]} exceeds the model's "
-                f"max_len {cfg.max_len}; positions past max_len have no "
+                f"max_len {max_positions}; positions past max_len have no "
                 "encoding"
             )
         # Quantized KV store: arg > env > default.
@@ -177,7 +185,7 @@ class ServingEngine:
         self.boundaries = boundaries
         self.max_batch = max_batch
         self.max_new_tokens = (
-            cfg.max_len - 1 if max_new_tokens is None else max_new_tokens
+            max_positions - 1 if max_new_tokens is None else max_new_tokens
         )
         self.clock = clock
         self.metrics = ServingMetrics(clock=clock)
@@ -200,8 +208,7 @@ class ServingEngine:
             if prefill_budget is not None
             else 2 * -(-boundaries[-1] // prefill_chunk) * prefill_chunk
         )
-        self.runtime = PagedDecodeRuntime(
-            translator.model, translator.params,
+        self.runtime = translator.make_runtime(
             max_active=self.max_active,
             max_src=boundaries[-1],
             max_new_tokens=self.max_new_tokens,
@@ -212,7 +219,6 @@ class ServingEngine:
             prefix_cache_size=prefix_cache_size,
             kv_dtype=kv_dtype,
             quantize_self=quantize_self,
-            sos_id=SOS_ID, eos_id=EOS_ID, pad_id=cfg.pad_id,
         )
         # The row pool: one slot = one cache row in the launch program.
         self.pool = KVSlotPool(self.max_active)
@@ -309,9 +315,8 @@ class ServingEngine:
             n = self.runtime.warmup()
         self._compiles_at_warmup = self.compile_count()
         log.info(
-            "warmup compiled %d paged programs (%d prefill widths + 1 "
-            "launch; max_active=%d, page_size=%d)",
-            n, n - 1, self.max_active, self.runtime.page_size,
+            "warmup compiled %d paged programs (max_active=%d, page_size=%d)",
+            n, self.max_active, self.runtime.page_size,
         )
         return n
 
@@ -387,7 +392,7 @@ class ServingEngine:
         """
         if self._worker is None:
             raise RuntimeError("engine not started (use start() or `with`) ")
-        ids = self.translator.src_pipe.ragged([text])[0]
+        ids = self.translator.encode(text)
         if len(ids) > self.boundaries[-1]:
             raise ValueError(
                 f"input tokenizes to {len(ids)} ids, beyond the largest "
@@ -495,15 +500,9 @@ class ServingEngine:
             cycle.set(seq=seq, launched=int(self._paged_step(seq)))
 
     def _admission_cost(self, req) -> int:
-        """Prefill tokens admitting ``req`` will actually compute: zero
-        for a prefix-cache hit (pages attach, no program runs), the
-        chunk-padded prompt width otherwise. Racy against eviction — a
-        stale zero only means one admission cycle briefly exceeds the
-        budget, which the budget's own FIFO-prefix rule already permits
-        for the head request."""
-        if self.runtime.prefix_cache.contains(tuple(req.ids)):
-            return 0
-        return self.paged_batcher.cost(req.ids)
+        """Prefill positions admitting ``req`` will actually compute, as its
+        runtime prices them (a cached prefix costs nothing)."""
+        return self.runtime.prefill_cost(req.ids)
 
     def _paged_admit(self, phase, taken: list[ServeRequest] | None) -> None:
         """Move pending requests onto free rows, bounded by the prefill
@@ -629,13 +628,13 @@ class ServingEngine:
             for req in result.first_emits:
                 req.decode_done_time = decode_done
                 req.trace.mark("first_token", decode_done)
-            vocab = self.translator.trg_pipe.vocab
+            decode = self.translator.decode
             n_completed = 0
             for req, ids, row, saw_eos in result.completed:
                 self.runtime.retire(row)
                 self.pool.release_owner(req.id)
                 req.trace.mark("complete", decode_done, tokens=len(ids))
-                req.future.set_result(" ".join(vocab.lookup_tokens(ids)))
+                req.future.set_result(decode(ids))
                 n_completed += 1
                 now = self.clock()
                 self.metrics.on_complete(

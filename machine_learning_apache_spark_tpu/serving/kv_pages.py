@@ -347,3 +347,155 @@ class PrefixCache:
                 "resident_digests": digests,
                 "digests_truncated": max(0, len(self._entries) - len(digests)),
             }
+
+
+class SnapshotCache:
+    """Prefix reuse for a model whose layers keep two kinds of cache: pages
+    (softmax attention's K/V, shareable read-only) and a fixed-size state a
+    row (linear attention), which pages cannot share because it is a
+    function of *every* position before it. An entry is a **snapshot**: the
+    page ids that hold a prompt's first ``length`` positions (``length`` a
+    multiple of the page size), under a cache-owned reference in the same
+    ``KVPagePool`` the rows allocate from, and the number of a slot of the
+    runtime's snapshot plane holding a copy of the state as it stood after
+    position ``length - 1``. A later prompt that begins with the same ids
+    attaches the pages, has the state copied into its row, and prefills the
+    rest.
+
+    Slot 0 is reserved, as page 0 is: it holds zeros (a cold start restores
+    from it). Entries are LRU by last match; eviction drops the page
+    references and frees the slot. Single-writer (the engine's decode
+    thread), locked for introspection, as ``PrefixCache``.
+    """
+
+    def __init__(self, pool: KVPagePool, capacity: int, page_size: int):
+        if capacity < 0:
+            raise ValueError(f"capacity must be >= 0, got {capacity}")
+        self.pool, self.capacity, self.page_size = pool, capacity, page_size
+        self._lock = threading.Lock()
+        self._entries: OrderedDict[tuple, dict] = OrderedDict()
+        self._by_first_page: dict[bytes, list[tuple]] = {}
+        self._free_slots = list(range(capacity, 0, -1))  # slot 1 on top
+        self.hits = self.misses = self.evictions = 0
+
+    @property
+    def num_slots(self) -> int:
+        """Slots the runtime's snapshot plane needs (slot 0 included)."""
+        return self.capacity + 1
+
+    @staticmethod
+    def owner_for(key: tuple) -> tuple:
+        return ("snapshot", key)
+
+    def _first_page(self, ids) -> bytes:
+        return ids[: self.page_size].tobytes()
+
+    def _best_locked(self, ids, limit: int) -> dict | None:
+        best = None
+        for key in self._by_first_page.get(self._first_page(ids), ()):
+            entry = self._entries[key]
+            n = entry["length"]
+            if n <= limit and (best is None or n > best["length"]) and (
+                (ids[:n] == entry["ids"]).all()
+            ):
+                best = entry
+        return best
+
+    def match_length(self, ids, limit: int) -> int:
+        """Positions of ``ids`` (an int32 array) the longest matching
+        snapshot of at most ``limit`` positions covers; 0 for none. No
+        reference taken, no LRU bump: for admission pricing."""
+        with self._lock:
+            best = self._best_locked(ids, limit)
+            return best["length"] if best else 0
+
+    def lookup(self, ids, limit: int, owner: object) -> dict | None:
+        """The longest snapshot that ``ids`` begins with, of at most
+        ``limit`` positions, with ``owner`` attached to its pages; None for
+        a miss."""
+        with self._lock:
+            entry = self._best_locked(ids, limit)
+            if entry is None:
+                self.misses += 1
+                return None
+            self._entries.move_to_end(entry["key"])
+            self.hits += 1
+        self.pool.add_ref(entry["pages"], owner)
+        return entry
+
+    def reserve_slot(self) -> int | None:
+        """A free slot for a snapshot about to be taken, evicting the LRU
+        entry if none is free; None where the cache is disabled."""
+        if self.capacity == 0:
+            return None
+        if not self._free_slots:
+            self.evict_one()
+        return self._free_slots.pop() if self._free_slots else None
+
+    def put(self, ids, pages: list[int], slot: int) -> bool:
+        """Adopt the snapshot in ``slot`` of the prompt prefix ``ids``
+        (whose pages are ``pages``). False, and the slot is free again,
+        where that prefix is already held."""
+        key = (
+            len(ids),
+            hashlib.blake2b(ids.tobytes(), digest_size=8).hexdigest(),
+            self._first_page(ids),
+        )
+        with self._lock:
+            if key in self._entries:
+                self._free_slots.append(slot)
+                return False
+        self.pool.add_ref(pages, self.owner_for(key))
+        with self._lock:
+            self._entries[key] = {
+                "key": key, "ids": ids.copy(), "length": len(ids),
+                "pages": list(pages), "slot": slot, "digest": key[1],
+            }
+            self._by_first_page.setdefault(key[2], []).append(key)
+        return True
+
+    def evict_one(self) -> bool:
+        with self._lock:
+            if not self._entries:
+                return False
+            key, entry = self._entries.popitem(last=False)
+            keys = self._by_first_page[key[2]]
+            keys.remove(key)
+            if not keys:
+                del self._by_first_page[key[2]]
+            self._free_slots.append(entry["slot"])
+            self.evictions += 1
+        self.pool.release_owner(self.owner_for(key))
+        return True
+
+    def evict_until_free(self, n_pages: int) -> None:
+        while self.pool.free < n_pages:
+            if not self.evict_one():
+                return
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._entries)
+
+    def stats(self, *, max_digests: int = 64) -> dict:
+        with self._lock:
+            lookups = self.hits + self.misses
+            entries = list(self._entries.values())
+            digests = [e["digest"] for e in reversed(entries)][:max_digests]
+            resident = sum(len(e["pages"]) for e in entries)
+            return {
+                "entries": len(entries),
+                "capacity": self.capacity,
+                "hits": self.hits,
+                "misses": self.misses,
+                "evictions": self.evictions,
+                "hit_rate": round(self.hits / lookups, 4) if lookups else None,
+                "resident_pages": resident,
+                "resident_bytes": (
+                    None if self.pool.page_bytes is None
+                    else resident * self.pool.page_bytes
+                ),
+                "resident_positions": sum(e["length"] for e in entries),
+                "resident_digests": digests,
+                "digests_truncated": max(0, len(entries) - len(digests)),
+            }

@@ -468,6 +468,15 @@ class PagedDecodeRuntime:
             pages = self.mem_pool.try_acquire(n, owner)
         return pages
 
+    def prefill_cost(self, ids) -> int:
+        """Prefill positions admitting ``ids`` would compute: none for a
+        prefix-cache hit (pages attach, no program runs), the chunk-padded
+        prompt width otherwise. Racy against eviction: a stale zero only
+        means one admission round briefly exceeds the engine's budget."""
+        if self.prefix_cache.contains(tuple(ids)):
+            return 0
+        return _round_up(max(len(ids), 1), self.prefill_chunk)
+
     def admit(self, req, row: int):
         """Place ``req`` on ``row``: attach (cache hit) or prefill (miss)
         its memory pages, allocate its first self page, and arm the row

@@ -270,7 +270,8 @@ class RequestQueue:
                 )
             req = ServeRequest(
                 text=text,
-                ids=list(ids),
+                # an id array (a language model's prompt) is kept as it is
+                ids=ids if hasattr(ids, "dtype") else list(ids),
                 submit_time=now,
                 deadline=None if deadline_s is None else now + deadline_s,
                 tier=tier,

@@ -94,3 +94,60 @@ def test_gated_delta_chunk_kernels_compile(one_chip, monkeypatch, site):
     ).compile().as_text()
     for name in ("gdn_chunk_fwd", "gdn_chunk_bwd"):
         assert f"%{name}" in text, f"{name} is not in the compiled program"
+
+
+@pytest.mark.parametrize("program", ["launch", "prefill"])
+def test_served_language_model_programs_compile_in_place(one_chip, program):
+    """The decoder-only runtime's two hot programs at the benchmark's
+    published widths and engine sizes (``minicpm_sala_9b``): they fit the
+    chip, the page store is updated in place (no copy of a whole plane: the
+    first layout, ``[heads, pages, page, d]``, cost four such copies a layer
+    a step), and no softmax maximum became a row-wide ``reduce-window``."""
+    from benchmark import manifest, weights_sala_lm
+    from machine_learning_apache_spark_tpu.models import sala_lm
+    from machine_learning_apache_spark_tpu.serving.lm_runtime import (
+        LMDecodeRuntime,
+    )
+
+    cfg = manifest.load_config(manifest.load_manifest(), "minicpm_sala_9b")
+    model, engine = weights_sala_lm.model_config(cfg), cfg["engine"]
+    runtime = object.__new__(LMDecodeRuntime)  # the programs, no planes
+    runtime.cfg, runtime._donate = model, True
+    runtime.steps_per_launch = engine["steps_per_launch"]
+    runtime.max_new_tokens = engine["max_new_tokens"]
+    rows, chunk = engine["max_active"], engine["prefill_chunk"]
+    width = -(-engine["max_context"] // 64) + chunk // 64
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = described(jax.eval_shape(lambda: weights_sala_lm.make_params(1, cfg)))
+    cache = described(jax.eval_shape(
+        lambda: sala_lm.new_cache(model, rows=rows, num_pages=engine["num_pages"])
+    ))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=one_chip)  # noqa: E731
+    if program == "launch":
+        lowered = runtime._make_launch().lower(
+            params, cache, i32(rows), i32(rows), i32(rows), flag(rows),
+            i32(rows, width), flag(rows),
+        )
+    else:
+        lowered = runtime._make_prefill().lower(
+            params, cache, i32(chunk), i32(width), i32(), i32(), i32(), flag()
+        )
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+    assert memory.alias_size_in_bytes > 1.6e9  # the cache is donated
+    text = compiled.as_text()
+    plane = f"bf16[{2 * engine['num_pages'] * 64},128]"
+    assert f"= {plane}" in text
+    assert not [
+        line for line in text.splitlines()
+        if " copy(" in line and f"= {plane}" in line
+    ]
+    assert "reduce-window" not in text
