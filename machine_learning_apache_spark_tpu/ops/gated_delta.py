@@ -18,15 +18,39 @@ time. Inside a chunk, with ``G_i`` the running sum of ``g`` and
 ``A[i, j] = beta_i (k_i . k_j) exp(G_i - G_j)`` for ``j < i``, the writes
 ``u`` solve the unit lower-triangular system ``(I + A) u = beta (v - exp(G)
 k S0)`` (the WY representation of the product of the chunk's Householder-like
-factors). With ``T = (I + A)^-1`` applied by forward substitution, in float32,
+factors). With ``T = (I + A)^-1`` built once, in float32, and applied by a
+product,
 
     u_i   = T (beta v) - T (beta exp(G) k) S0        # ``value`` - ``w`` S0
     o_i   = exp(G_i) q_i S0 + tril(q k^T exp(G_i - G_j)) u
     S_end = exp(G_C) S0 + (exp(G_C - G_i) k_i)^T u
 
-The in-chunk preparation (``A``, the solve giving ``value`` and ``w``, the
-decays, ``tril(q k^T decay)``) is batched over all chunks, and JAX
-differentiates it. What is sequential, the state's hand-off from chunk to
+The in-chunk preparation (``A``, ``T`` and its product giving ``value`` and
+``w``, the decays, ``tril(q k^T decay)``) is batched over all chunks, and JAX
+differentiates it.
+
+``T`` is a blocked product form (``_solve_unit_lower``; until PR 32 XLA's
+triangular solve, whose expansion inverted each chunk's 64 x 64 block by a
+custom call of 10.9 ms a layer a pass at the benchmark's site): diagonal
+blocks of at most 16 x 16 by forward substitution, merged by halves,
+``T21 = -T22 (A21 T11)``, 16 -> 32 -> 64, the split read off ``chunk``; every
+product of the build, of the application ``T [beta v | beta e^G k]`` and of
+the VJP (``d rhs = T^T d solved``, ``d A = -tril(d rhs solved^T, -1)``: no
+transposed solve, no second inversion) is a float32 product at
+``Precision.HIGHEST``. It is as exact as substitution (1e-7 of the answer's
+largest entry whatever the keys: ``_solve_unit_lower`` has the table),
+because the entries of ``T`` and of its blocks stay at or under 1; the
+squaring form ``(I - A)(I + A^2)(I + A^4)...`` is not, its powers of ``A``
+reach 1e6 for keys as alike as a positive activation leaves them and cancel,
+and the blocked form loses the same digits if a product's operands are
+rounded to bfloat16. Where a site takes the kernels below the build runs in
+one more (``gdn_inverse``: the chunks' index in the lanes, substitution and
+merges as float32 multiply-adds on the VPU), anywhere else as plain JAX (the
+same form; XLA lays the 16 x 16 blocks out in padded tiles and takes 21 ms
+where the kernel takes about one); the ``inverse=`` of the dispatch record
+says which.
+
+What is sequential, the state's hand-off from chunk to
 chunk and the two output products that read the state, runs in one of two
 places, with the same products and the same two rounding points (the state
 and ``u`` rounded to the operands' dtype before they enter a product):
@@ -51,13 +75,15 @@ Which one a site took, and why, is its ``ops.gated_delta_dispatch`` record.
 No per-token loop in either direction, either way.
 
 Products take operands in the inputs' dtype and accumulate in float32;
-decays, the solve and the state are float32 throughout. With float32 inputs every
+decays, the inverse, its products and the state are float32 throughout. With float32 inputs every
 product runs at ``Precision.HIGHEST`` (a float32 product on a TPU is
 otherwise bfloat16 passes), which is what the tests and ``chip_smoke.py``
 compare against the recurrence.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -119,17 +145,151 @@ def gated_delta_recurrent(q, k, v, g, beta, *, initial_state=None):
     return jnp.moveaxis(out, 0, 1), state
 
 
-def _solve_unit_lower(a: jnp.ndarray, rhs: jnp.ndarray) -> jnp.ndarray:
-    """``(I + a)^-1 rhs`` for strictly lower-triangular ``a [..., C, C]`` by
-    forward substitution (XLA's triangular solve), in float32. Substitution
-    is backward-stable whatever the keys; the product form ``(I - a)(I +
-    a^2)(I + a^4)...`` is not: with keys as alike as a positive activation
-    leaves them (mean cosine 0.5 and up) its powers of ``a`` reach 1e5 and
-    cancel, and read 1e6 times the true gradient on the chip."""
-    return jax.lax.linalg.triangular_solve(
-        a + jnp.eye(a.shape[-1], dtype=a.dtype), rhs,
-        left_side=True, lower=True, unit_diagonal=True,
+# Side of the diagonal blocks inverted by substitution; above it the inverse
+# is merged from its halves by products.
+SUBSTITUTION_BLOCK = 16
+# The longest side ``gdn_inverse`` holds: its ``[128 C, C]`` blocks in and out
+# and ``[C, C, 128]`` float32 scratch planes are 20.5 MiB of VMEM at 64.
+INVERSE_MAX_SIDE = 64
+
+
+def _dot_f32(spec: str, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """A float32 product at ``Precision.HIGHEST``, whatever the operands:
+    the only kind the inverse, its application and its VJP may hold."""
+    return jnp.einsum(
+        spec, x, y, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
+
+
+def _inverse_unit_lower(a: jnp.ndarray) -> jnp.ndarray:
+    """``T = (I + a)^-1`` for strictly lower-triangular ``a [..., C, C]``,
+    split by the shape: a side of at most ``SUBSTITUTION_BLOCK`` by forward
+    substitution, a longer one at ``C // 2`` into
+
+        [[T11, 0], [T21, T22]],   T21 = -T22 (A21 T11)
+
+    with ``T11``, ``T22`` the halves' own inverses. No power of ``a`` is ever
+    formed: every factor is a block of ``T`` itself, whose entries stay at
+    or under 1 (each row is a product of the contractions
+    ``I - beta k k^T``)."""
+    size = a.shape[-1]
+    if size <= SUBSTITUTION_BLOCK:
+        # Row i of T is e_i - a[i, :i] T[:i, :], for all blocks at once, the
+        # blocks' index minor. (XLA's TPU compiler lays the array out its own
+        # way, the 16 x 16 pair in a tile padded to 128 lanes: 21 ms a build
+        # at the benchmark's site, which is why a kernel site does not build
+        # here.)
+        lanes = jnp.moveaxis(a.reshape(-1, size, size), 0, -1)  # [C, C, N]
+        eye = jnp.eye(size, dtype=a.dtype)[:, :, None]
+        t = jnp.zeros_like(lanes)
+        for i in range(size):
+            t = t.at[i].set(
+                eye[i] - jnp.sum(lanes[i, :i, None, :] * t[:i], axis=0)
+            )
+        return jnp.moveaxis(t, -1, 0).reshape(a.shape)
+    if size % 2:  # an odd side: one more row and column of the identity
+        grown = jnp.pad(a, [(0, 0)] * (a.ndim - 2) + [(0, 1), (0, 1)])
+        return _inverse_unit_lower(grown)[..., :size, :size]
+    half = size // 2
+    # both halves in one call: every level is one batch of equal blocks
+    t11, t22 = _inverse_unit_lower(
+        jnp.stack([a[..., :half, :half], a[..., half:, half:]])
+    )
+    t21 = -_dot_f32(
+        "...ij,...jk->...ik", t22,
+        _dot_f32("...ij,...jk->...ik", a[..., half:, :half], t11),
+    )
+    return jnp.concatenate([
+        jnp.concatenate([t11, jnp.zeros_like(t11)], -1),
+        jnp.concatenate([t21, t22], -1),
+    ], -2)
+
+
+def _kernel_side(chunk: int) -> int | None:
+    """The side ``gdn_inverse`` works at for a ``chunk``: half the
+    substitution's block, doubled until it holds the chunk (halves then stay
+    whole sublane tiles down to the block; the systems are grown by rows and
+    columns of the identity); ``None`` over ``INVERSE_MAX_SIDE``."""
+    side = SUBSTITUTION_BLOCK // 2
+    while side < chunk:
+        side *= 2
+    return side if side <= INVERSE_MAX_SIDE else None
+
+
+def _inverse_place(refusal: str | None, chunk: int) -> str:
+    """Where a site builds the inverse: ``"pallas"`` (``gdn_inverse``) where
+    it takes the chunk kernels (``refusal`` is ``None``) and the kernel holds
+    a side of ``chunk`` in VMEM, else ``"xla"``."""
+    return "xla" if refusal or _kernel_side(chunk) is None else "pallas"
+
+
+def _inverse_form(chunk: int, place: str) -> str:
+    """The dispatch record's ``inverse=``: the sides substituted and merged
+    at this ``chunk``, the products' precision and the place."""
+    sides = [_kernel_side(chunk) if place == "pallas" else chunk]
+    while sides[0] > SUBSTITUTION_BLOCK:
+        sides.insert(0, (sides[0] + 1) // 2)
+    return (
+        f"blocked {sides[0]}x{sides[0]} substitution"
+        + (", merges " + ">".join(map(str, sides)) if sides[1:] else "")
+        + (", f32 on the VPU, pallas gdn_inverse" if place == "pallas"
+           else ", f32 HIGHEST, xla")
+        + "; applied and differentiated by f32 HIGHEST products"
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _solve_unit_lower(
+    a: jnp.ndarray, rhs: jnp.ndarray, place: str = "xla"
+) -> jnp.ndarray:
+    """``(I + a)^-1 rhs`` for strictly lower-triangular ``a [..., C, C]``, in
+    float32: the inverse built once, blocked (``_inverse_unit_lower``, or
+    the same form in ``gdn_inverse`` where ``place`` is ``"pallas"``), and
+    applied by one product; the VJP is two more products of what the forward
+    kept, with no transposed solve and no second inversion.
+
+    As exact as the substitution (XLA's triangular solve) it replaced:
+    largest error of the answer over its largest entry, float32 against a
+    float64 solve, at mean key cosine 0.02 / 0.5 / 0.9: substitution
+    1.9e-7 / 1.9e-7 / 1.8e-7, this form 6e-8 / 8e-8 / 1e-7 (the chip read
+    9e-8 and 1.3e-7 at 0.52 and 0.93, cotangents 2e-7), where the squaring
+    form ``(I - a)(I + a^2)(I + a^4)...`` reads 6e-8 / 2e-3 / 38: its powers
+    of ``a`` reach 1e6 and cancel, while every factor here is a block of
+    ``T``, whose entries stay at or under 1. That holds only while every
+    product is a float32 one: with operands rounded to bfloat16 the blocked
+    form reads 2e-3 / 3e-3 too."""
+    return _solve_unit_lower_fwd(a, rhs, place)[0]
+
+
+def _solve_unit_lower_fwd(a, rhs, place):
+    if place == "pallas":
+        # imported here: Pallas costs a second at import, and only a site
+        # that takes the kernels needs it
+        from machine_learning_apache_spark_tpu.ops.pallas_gated_delta import (
+            unit_lower_inverse,
+        )
+
+        t = unit_lower_inverse(
+            a, side=_kernel_side(a.shape[-1]), block=SUBSTITUTION_BLOCK,
+            interpret=_backend() != "tpu",
+        )
+    else:
+        t = _inverse_unit_lower(a)
+    solved = _dot_f32("...ij,...jd->...id", t, rhs)
+    return solved, (t, solved)
+
+
+def _solve_unit_lower_bwd(place, kept, d_solved):
+    # solved = T rhs and dT = -T da T, so d rhs = T^T d solved and
+    # d a = -(d rhs) solved^T, kept where a has entries
+    t, solved = kept
+    d_rhs = _dot_f32("...ji,...jd->...id", t, d_solved)
+    d_a = -jnp.tril(_dot_f32("...id,...jd->...ij", d_rhs, solved), -1)
+    return d_a, d_rhs
+
+
+_solve_unit_lower.defvjp(_solve_unit_lower_fwd, _solve_unit_lower_bwd)
 
 
 # -- where the sequential part runs -----------------------------------------
@@ -272,7 +432,9 @@ def gated_delta_rule(
     rhs = jnp.concatenate(
         [f32(v), f32(k) * jnp.exp(big_g)[..., None]], axis=-1
     ) * beta[..., None]
-    solved = _solve_unit_lower(a, rhs).astype(dtype)
+    refusal = _kernel_refusal(dtype, chunk, dk, dv)
+    place = _inverse_place(refusal, chunk)
+    solved = _solve_unit_lower(a, rhs, place).astype(dtype)
     value, w = solved[..., :dv], solved[..., dv:]
     g_end = big_g[..., -1]                                          # [B,H,n]
     k_tail = (k * jnp.exp(g_end[..., None] - big_g)[..., None]).astype(dtype)
@@ -284,9 +446,11 @@ def gated_delta_rule(
 
     q_decayed = (q * jnp.exp(big_g)[..., None]).astype(dtype)
     qk = (dot("bhnid,bhnjd->bhnij", q, k) * decay).astype(dtype)  # i >= j kept
-    shape = dict(batch=b, length=t, heads=heads, dk=dk, dv=dv, dtype=str(dtype))
+    shape = dict(
+        batch=b, length=t, heads=heads, dk=dk, dv=dv, dtype=str(dtype),
+        inverse=_inverse_form(chunk, place),
+    )
 
-    refusal = _kernel_refusal(dtype, chunk, dk, dv)
     if refusal is None:
         head_block = _choose_head_block(
             b * heads, chunk, dk, dv, dtype.itemsize

@@ -26,6 +26,17 @@ state of ``head_block`` heads in VMEM scratch across it; blocks are indexed
 writes the outputs and the final state only; under differentiation the
 forward also writes each chunk's starting state (rounded) and ``u``, and the
 backward kernel walks the chunks in reverse.
+
+``unit_lower_inverse`` (``gdn_inverse``) is the in-chunk preparation's
+inverse ``(I + a)^-1`` in the same place, the blocked form of
+``ops.gated_delta._inverse_unit_lower`` with the systems' index in the
+lanes: a grid step takes 128 systems where they lie (``[128 * C, C]``: row
+``r`` of all of them is one strided read), turns each such ``[128, C]`` on the
+XLU into a row of ``[C, C, 128]``, and every row update of the 16 x 16 substitutions
+and every term of the merges ``T21 = -T22 (A21 T11)`` is one float32
+multiply and add over all 128 systems on the VPU (exact float32 products:
+no pass of the MXU, which would take six for float32 and fill a 64-wide
+tile by a quarter); the transposed inverse goes back through the XLU.
 """
 
 from __future__ import annotations
@@ -165,6 +176,127 @@ def _launch(
         interpret=interpret,
         name=kernel,
     )(*operands)
+
+
+# -- the in-chunk inverse ------------------------------------------------------
+
+_LANES = 128  # systems a grid step of ``gdn_inverse``: one to a lane
+
+
+def _invert_in_lanes(a, t, x, lo: int, side: int, block: int) -> None:
+    """``t[lo:lo+side, lo:lo+side] <- (I + a[lo:lo+side, lo:lo+side])^-1``,
+    entry ``(i, j)`` of all 128 systems at ``ref[i, j, :]``; the upper
+    triangle of ``a`` is not read. ``x [side/2, side/2, 128]`` is scratch."""
+    f32 = jnp.float32
+
+    def entry(ref, i, j):  # one entry of every system, as a [1, 128] row
+        return ref[i, pl.ds(j, 1), :]
+
+    if side <= block:
+        column = jax.lax.broadcasted_iota(jnp.int32, (side, _LANES), 0)
+        t[lo:lo + side, lo:lo + side, :] = jnp.zeros((side, side, _LANES), f32)
+
+        def row(i, _):  # T[i, :] = e_i - a[i, :i] T[:i, :]; rows >= i are 0
+            acc = (column == i).astype(f32)
+            for j in range(side):
+                acc = acc - jnp.where(
+                    j < i, entry(a, lo + i, lo + j), 0.0
+                ) * t[lo + j, lo:lo + side, :]
+            t[lo + i, lo:lo + side, :] = acc
+
+        jax.lax.fori_loop(0, side, row, None)
+        return
+
+    half = side // 2
+    _invert_in_lanes(a, t, x, lo, half, block)
+    _invert_in_lanes(a, t, x, lo + half, half, block)
+    mid = lo + half
+
+    def product(first, second):
+        """``sum_k first(k) second(k)`` over the half, in four chains so
+        that a term does not wait for the one before it."""
+        chains = [
+            sum(first(k) * second(k) for k in range(c, half, 4))
+            for c in range(4)
+        ]
+        return (chains[0] + chains[1]) + (chains[2] + chains[3])
+
+    def x_row(i, _):  # X = A21 T11
+        x[i, :half, :] = product(
+            lambda k: entry(a, mid + i, lo + k),
+            lambda k: t[lo + k, lo:mid, :],
+        )
+
+    def t21_row(i, _):  # T21 = -T22 X, and zeros over it
+        t[mid + i, lo:mid, :] = -product(
+            lambda k: entry(t, mid + i, mid + k), lambda k: x[k, :half, :]
+        )
+        t[lo + i, mid:mid + half, :] = jnp.zeros((half, _LANES), f32)
+
+    jax.lax.fori_loop(0, half, x_row, None)
+    jax.lax.fori_loop(0, half, t21_row, None)
+
+
+def _inverse_kernel(a_ref, t_ref, a_scr, t_scr, x_scr, *, side: int, block: int):
+    # a_ref, t_ref [128 * C, C]: row r of system s at s * C + r. Row r of all
+    # 128 systems is one strided read, and one turn of the XLU puts the
+    # systems in the lanes; the inverse goes back the same way.
+    for r in range(side):
+        a_scr[r] = a_ref[pl.ds(r, _LANES, stride=side), :].T
+    _invert_in_lanes(a_scr, t_scr, x_scr, 0, side, block)
+    for r in range(side):
+        t_ref[pl.ds(r, _LANES, stride=side), :] = t_scr[r].T
+
+
+def _inverse_vmem_bytes(side: int) -> int:
+    """VMEM a grid step of ``gdn_inverse`` holds: two buffers each of the
+    ``[128 * C, C]`` block in and out (``C`` padded to whole lanes) and the
+    ``[C, C, 128]`` scratch planes."""
+    block = _LANES * side * -(-side // _LANES) * _LANES * 4
+    return 4 * block + (2 * side * side + (side // 2) ** 2) * _LANES * 4
+
+
+@functools.partial(jax.jit, static_argnames=("side", "block", "interpret"))
+def unit_lower_inverse(a, *, side: int, block: int, interpret: bool):
+    """``(I + a)^-1`` for strictly lower-triangular float32 ``a [..., C, C]``,
+    worked at ``side >= C`` (``ops.gated_delta._kernel_side``: ``block`` or
+    half of it, doubled until it holds ``C``, so that halves stay whole
+    sublane tiles): diagonal blocks of ``block`` by substitution, merged by
+    halves. The kernel reads and writes the systems where they lie (a
+    ``[N * C, C]`` view; nothing is re-laid out around it where ``C`` is
+    ``side`` and ``N`` a multiple of 128)."""
+    chunk = a.shape[-1]
+    count = a.size // (chunk * chunk)
+    padded = -(-count // _LANES) * _LANES
+    # systems of the identity fill the last lane tile; rows and columns of
+    # it grow a chunk to the side
+    flat = jnp.pad(
+        a.reshape(count, chunk, chunk),
+        ((0, padded - count), (0, side - chunk), (0, side - chunk)),
+    ).reshape(padded * side, side)
+    spec = pl.BlockSpec((_LANES * side, side), lambda i: (i, 0))
+    plane = pltpu.VMEM((side, side, _LANES), jnp.float32)
+    vmem = _inverse_vmem_bytes(side)
+    inverse = pl.pallas_call(
+        functools.partial(_inverse_kernel, side=side, block=block),
+        grid=(padded // _LANES,),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(flat.shape, jnp.float32),
+        scratch_shapes=[
+            plane, plane,
+            pltpu.VMEM((side // 2, side // 2, _LANES), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # Mosaic's default scoped limit is 16 MiB
+            vmem_limit_bytes=vmem * 5 // 4 if vmem > 12 * 2**20 else None,
+        ),
+        interpret=interpret,
+        name="gdn_inverse",
+    )(flat)
+    inverse = inverse.reshape(padded, side, side)[:count, :chunk, :chunk]
+    return inverse.reshape(a.shape)
 
 
 def _lanes(decay: jnp.ndarray, dv: int) -> jnp.ndarray:

@@ -6,6 +6,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from machine_learning_apache_spark_tpu import telemetry
@@ -97,6 +98,74 @@ def test_keys_alike_as_a_positive_activation_leaves_them(take, path, shift):
     ref = jax.grad(loss(gated_delta_recurrent), argnums=range(5))(*args)
     for a, b in zip(got, ref):
         assert jnp.allclose(a, b, atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-6)
+
+
+def _chunk_systems(chunk, shift, *, n=12, dk=128, dv=24):
+    """``a`` and ``rhs`` of ``n`` chunks as ``gated_delta_rule`` forms them
+    (float64 numpy), the keys built as the test above builds them: plain
+    unit keys where ``shift`` is None, else a common component left by a
+    positive activation. Also the keys' mean cosine."""
+    rng = np.random.default_rng(chunk)
+    k = rng.standard_normal((n, chunk, dk))
+    if shift is not None:
+        k = np.asarray(jax.nn.silu(k + shift), np.float64)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    gram = np.einsum("nid,njd->nij", k, k)
+    beta = rng.uniform(0.05, 1.0, (n, chunk))
+    big_g = np.cumsum(rng.uniform(-0.1, 0.0, (n, chunk)), axis=-1)
+    decay = np.exp(np.minimum(big_g[:, :, None] - big_g[:, None, :], 0.0))
+    a = np.tril(gram * decay * beta[:, :, None], -1)
+    v = rng.standard_normal((n, chunk, dv))
+    rhs = np.concatenate([v, k * np.exp(big_g)[..., None]], -1)
+    return a, rhs * beta[..., None], float(np.mean(gram))
+
+
+# shift None / 1.0 / 4.0: mean cosine between a chunk's keys 0.02 / 0.5 / 0.9
+@pytest.mark.parametrize("shift,cosine", [(None, 0.0), (1.0, 0.45), (4.0, 0.85)])
+@pytest.mark.parametrize("chunk", [8, 16, 32, 64, 48])
+def test_blocked_inverse_is_as_exact_as_substitution(chunk, shift, cosine):
+    """``_solve_unit_lower`` alone, float32, against a float64 solve: values
+    within 1e-6 of the largest entry, both cotangents within 5e-6 of the
+    float64 ones. (The squaring form over the whole chunk read 2e-3 at
+    cosine 0.5 and 38 at 0.9; float32 substitution 2e-7.)"""
+    a, rhs, cos = _chunk_systems(chunk, shift)
+    assert cos >= cosine
+    inverse = np.linalg.inv(np.eye(chunk) + a)
+    want = inverse @ rhs
+    weights = np.random.default_rng(1).standard_normal(want.shape)
+    want_d_rhs = np.swapaxes(inverse, -1, -2) @ weights
+    want_d_a = -np.tril(want_d_rhs @ np.swapaxes(want, -1, -2), -1)
+
+    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    got = gated_delta._solve_unit_lower(f32(a), f32(rhs))
+    d_a, d_rhs = jax.grad(
+        lambda a, r: jnp.sum(gated_delta._solve_unit_lower(a, r) * f32(weights)),
+        argnums=(0, 1),
+    )(f32(a), f32(rhs))
+    assert got.dtype == d_a.dtype == d_rhs.dtype == jnp.float32
+    gap = lambda x, ref: np.max(np.abs(np.asarray(x, np.float64) - ref)) / np.max(np.abs(ref))  # noqa: E731
+    assert gap(got, want) <= 1e-6
+    assert gap(d_a, want_d_a) <= 5e-6
+    assert gap(d_rhs, want_d_rhs) <= 5e-6
+
+
+# 5 and 33: an odd side grows by a row and column of the identity; 24: two
+# halves of 12; 40: 20, then 10
+@pytest.mark.parametrize("chunk", [5, 24, 33, 40])
+def test_inverse_vjp_agrees_with_autodiff_through_a_plain_solve(chunk):
+    a, rhs, _ = _chunk_systems(chunk, 1.0, n=3, dk=16, dv=6)
+    a, rhs = jnp.asarray(a, jnp.float32), jnp.asarray(rhs, jnp.float32)
+
+    def plain(a, rhs):
+        return jnp.linalg.solve(jnp.eye(chunk) + jnp.tril(a, -1), rhs)
+
+    got, pull = jax.vjp(gated_delta._solve_unit_lower, a, rhs)
+    want, want_pull = jax.vjp(plain, a, rhs)
+    assert jnp.allclose(got, want, atol=2e-6, rtol=2e-6)
+    cotangent = jax.random.normal(jax.random.key(chunk), want.shape)
+    for name, x, ref in zip(("d_a", "d_rhs"), pull(cotangent), want_pull(cotangent)):
+        assert x.shape == ref.shape, name
+        assert jnp.allclose(x, ref, atol=1e-5, rtol=1e-5), name
 
 
 @pytest.mark.parametrize("path", PATHS)
@@ -218,6 +287,10 @@ def test_dispatch_record_names_the_path_and_why(take):
     assert seen["site"] == "here" and seen["impl"] == "chunked_scan"
     assert "lax.scan over 2 chunks of 16" in seen["reason"]
     assert seen["reason"].endswith("backend cpu")
+    assert seen["inverse"] == (
+        "blocked 16x16 substitution, f32 HIGHEST, xla; applied and "
+        "differentiated by f32 HIGHEST products"
+    )
 
     take("kernel")
     telemetry.get_log().clear()
@@ -229,6 +302,52 @@ def test_dispatch_record_names_the_path_and_why(take):
     assert "float32 at Precision.HIGHEST" in seen["reason"]
     assert seen["head_block"] == 8 and tuple(seen["grid"]) == (1, 2)
     assert set(seen["vmem_bytes"]) == {"fwd", "fwd_save", "bwd"}
+    assert "f32 on the VPU, pallas gdn_inverse" in seen["inverse"]
+
+
+@pytest.mark.parametrize("chunk,place,form", [
+    (64, "xla", "blocked 16x16 substitution, merges 16>32>64, f32 HIGHEST, xla"),
+    (48, "xla", "blocked 12x12 substitution, merges 12>24>48, f32 HIGHEST, xla"),
+    (64, "pallas", "blocked 16x16 substitution, merges 16>32>64, f32 on the VPU"),
+    (48, "pallas", "blocked 16x16 substitution, merges 16>32>64, f32 on the VPU"),
+    (8, "pallas", "blocked 8x8 substitution, f32 on the VPU"),
+])
+def test_inverse_record_names_the_split_and_the_place(chunk, place, form):
+    assert gated_delta._inverse_form(chunk, place).startswith(form)
+
+
+@pytest.mark.parametrize("chunk,refusal,place", [
+    (64, None, "pallas"), (48, None, "pallas"), (16, None, "pallas"),
+    (128, None, "xla"), (64, "backend cpu", "xla"),
+])
+def test_inverse_is_built_in_the_kernel_where_the_site_takes_the_kernels(
+    chunk, refusal, place
+):
+    """``gdn_inverse`` holds sides up to 64 in VMEM; a longer chunk at a
+    kernel site builds the same form as plain JAX."""
+    assert gated_delta._inverse_place(refusal, chunk) == place
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 32, 64, 48])
+def test_inverse_kernel_agrees_with_the_plain_form(chunk):
+    """``gdn_inverse`` (interpreted) against ``_inverse_unit_lower`` and a
+    float64 inverse, keys at mean cosine 0.5, more systems than a lane tile
+    and not a multiple of it."""
+    from machine_learning_apache_spark_tpu.ops.pallas_gated_delta import (
+        unit_lower_inverse,
+    )
+
+    a, _, _ = _chunk_systems(chunk, 1.0, n=130, dk=32)
+    want = np.linalg.inv(np.eye(chunk) + a)
+    a = jnp.asarray(a, jnp.float32).reshape(2, 65, chunk, chunk)
+    got = unit_lower_inverse(
+        a, side=gated_delta._kernel_side(chunk),
+        block=gated_delta.SUBSTITUTION_BLOCK, interpret=True,
+    )
+    plain = gated_delta._inverse_unit_lower(a)
+    assert got.shape == plain.shape == a.shape
+    assert float(jnp.max(jnp.abs(got - plain))) <= 1e-6
+    assert np.max(np.abs(np.asarray(got, np.float64).reshape(want.shape) - want)) <= 1e-6
 
 
 @pytest.mark.parametrize("dtype,chunk,dk,dv,mesh_size,why", [

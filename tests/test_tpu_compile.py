@@ -77,7 +77,11 @@ SCAN_SITES = {
 def test_gated_delta_chunk_kernels_compile(one_chip, monkeypatch, site):
     """The dispatch is told the backend is a TPU (here it would observe the
     CPU); the shapes then pass its gate on their own, and the program holds
-    the primal's and the saving forward's kernel and the backward's."""
+    the primal's and the saving forward's kernel and the backward's, and
+    the kernel that builds the in-chunk inverse. Nothing of XLA's
+    triangular-solve expansion is left (until PR 32 the program held its
+    ``InvertDiagBlocksLowerTriangular`` custom calls, 10.9 ms each at the
+    benchmark's site)."""
     b, t, hk, hv, d, dtype = SCAN_SITES[site]
     monkeypatch.setattr(gated_delta, "_backend", lambda: "tpu")
     q = jax.ShapeDtypeStruct((b, t, hk, d), dtype, sharding=one_chip)
@@ -92,8 +96,11 @@ def test_gated_delta_chunk_kernels_compile(one_chip, monkeypatch, site):
     text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
         q, q, v, g, g
     ).compile().as_text()
-    for name in ("gdn_chunk_fwd", "gdn_chunk_bwd"):
+    for name in ("gdn_chunk_fwd", "gdn_chunk_bwd", "gdn_inverse"):
         assert f"%{name}" in text, f"{name} is not in the compiled program"
+    for gone in ("InvertDiagBlocksLowerTriangular", "triangular-solve",
+                 "triangular_solve"):
+        assert gone not in text, f"{gone} is in the compiled program"
 
 
 @pytest.mark.parametrize("program", ["launch", "prefill"])

@@ -15,6 +15,19 @@ primal kernel) and value-and-gradients (the saving forward and the backward
 kernel); ``module_ms`` is the whole module, in-chunk preparation included, so
 the kernel cases' and the scan case's differ by what the kernels replace.
 One JSON line a case; the table also lands in ``chiprun_out/gdn_chunk_sweep/``.
+
+``--part prepare`` times the in-chunk inverse alone instead (PERF.md
+section 6, PR 32): ``ops.gated_delta._solve_unit_lower`` on the ``a`` and
+``rhs`` the op forms at the site, as two modules, the forward (build of
+``(I + a)^-1`` and its application, rounded to the operands' dtype) and
+value-and-gradients (the forward and the VJP's products). It prints each
+module's device ms a call, its five longest operations, where the inverse was
+built (``gdn_inverse`` at a site that takes the kernels; ``--cases xla``
+reads the plain-JAX form there instead), and how far the
+first ``--check-chunks`` chunks' values and cotangents lie from a float64
+solve on the host, over the largest entry of that solve; ``--key-shift``
+gives the keys a common component as the tests do (1.0: mean cosine 0.5,
+4.0: 0.9).
 Chip-only, like ``tools/flash_tile_sweep.py``; ``--rehearse 1`` interprets
 the kernels on the CPU at whatever (small) shape is given and reports no
 time.
@@ -43,6 +56,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chunk", type=int, default=64)
     ap.add_argument("--cases", default=DEFAULT_CASES)
     ap.add_argument("--rehearse", type=int, default=0)
+    ap.add_argument("--part", default="scan", choices=("scan", "prepare"))
+    ap.add_argument("--key-shift", type=float, default=0.0)
+    ap.add_argument("--check-chunks", type=int, default=64)
     args = ap.parse_args(argv)
 
     if args.rehearse:
@@ -66,12 +82,17 @@ def main(argv=None) -> int:
 
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     q = (unit(rnd((b, t, args.key_heads, d), 0)) * d ** -0.5).astype(dtype)
-    k = unit(rnd((b, t, args.key_heads, d), 1)).astype(dtype)
+    k = rnd((b, t, args.key_heads, d), 1)
+    if args.key_shift:  # keys as alike as a positive activation leaves them
+        k = jax.nn.silu(k + args.key_shift)
+    k = unit(k).astype(dtype)
     v, w = rnd((b, t, h, d), 2, dtype), rnd((b, t, h, d), 3, dtype)
     g = -0.1 * jax.nn.sigmoid(rnd((b, t, h), 4))
     beta = jax.nn.sigmoid(rnd((b, t, h), 5))
     operands = (q, k, v, g, beta)
     weighted = (w, *operands)  # the loss's weights ride as an argument
+    if args.part == "prepare":
+        return prepare_part(args, jax, operands, w)
 
     # The sweep steers the dispatch from outside, as a test would: the
     # program has no option for either.
@@ -179,6 +200,124 @@ def main(argv=None) -> int:
             # the dispatch records stay in the file: stdout is capped
             row.pop("dispatch", None)
             print(json.dumps(row), flush=True)
+    return 0
+
+
+def prepare_part(args, jax, operands, w) -> int:
+    """The ``--part prepare`` reading (module docstring)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import trace_reduce
+    from machine_learning_apache_spark_tpu.ops import gated_delta
+
+    q, k, v, g, beta = operands
+    b, t, h, d = v.shape
+    dtype, f32, c = v.dtype, jnp.float32, args.chunk
+    n = t // c
+
+    def form(k, v, g, beta):
+        """``a`` and ``rhs`` as ``gated_delta_rule`` forms them."""
+        chunks = lambda x: jnp.moveaxis(  # noqa: E731
+            x.reshape(b, n, c, *x.shape[2:]), 3, 1
+        )
+        k = chunks(jnp.repeat(k, h // k.shape[2], axis=2))
+        v, g, beta = chunks(v), chunks(g), chunks(beta)
+        big_g = jnp.cumsum(g, axis=-1)
+        strict = jnp.tril(jnp.ones((c, c), bool), -1)
+        diff = big_g[..., :, None] - big_g[..., None, :]
+        kk = jnp.einsum("bhnid,bhnjd->bhnij", k, k, preferred_element_type=f32)
+        a = jnp.where(
+            strict, kk * jnp.exp(jnp.where(strict, diff, 0.0))
+            * beta[..., :, None], 0.0,
+        )
+        rhs = jnp.concatenate(
+            [v.astype(f32), k.astype(f32) * jnp.exp(big_g)[..., None]], -1
+        ) * beta[..., None]
+        return a, rhs
+
+    a, rhs = jax.jit(form)(k, v, g, beta)
+    weights = jnp.concatenate([w, w], -1).reshape(b, n, c, h, 2 * d)
+    weights = jnp.moveaxis(weights, 3, 1).astype(f32)
+
+    refusal = None if args.rehearse else gated_delta._kernel_refusal(dtype, c, d, d)
+    place = "xla" if args.cases == "xla" else gated_delta._inverse_place(refusal, c)
+
+    def sweep_prepare_fwd(a, rhs):
+        return gated_delta._solve_unit_lower(a, rhs, place).astype(dtype)
+
+    def sweep_prepare_grad(a, rhs, weights):
+        return jnp.sum(sweep_prepare_fwd(a, rhs).astype(f32) * weights)
+
+    fns = {
+        "fwd": (jax.jit(sweep_prepare_fwd), (a, rhs)),
+        "grad": (
+            jax.jit(jax.value_and_grad(sweep_prepare_grad, argnums=(0, 1))),
+            (a, rhs, weights),
+        ),
+    }
+    solved = jax.block_until_ready(fns["fwd"][0](a, rhs))
+    _, (d_a, d_rhs) = jax.block_until_ready(fns["grad"][0](a, rhs, weights))
+
+    # float64 on the host, over the first chunks: the solve, and the
+    # cotangents of sum(solved * weights) with solved left unrounded
+    few = lambda x: np.asarray(  # noqa: E731
+        x.reshape(-1, *x.shape[3:])[: args.check_chunks], np.float64
+    )
+    a64, rhs64, w64 = few(a), few(rhs), few(weights)
+    inverse = np.linalg.inv(np.eye(c) + a64)
+    want = inverse @ rhs64
+    want_d_rhs = np.swapaxes(inverse, -1, -2) @ w64
+    want_d_a = -np.tril(want_d_rhs @ np.swapaxes(want, -1, -2), -1)
+    gap = lambda got, ref: float(  # noqa: E731
+        np.max(np.abs(few(got.astype(f32)) - ref)) / np.max(np.abs(ref))
+    )
+    kh = np.asarray(k[0, :c, 0], np.float64)
+    row = dict(
+        part="prepare", shape=[b, t, h, d], dtype=dtype.name, chunk=c,
+        key_shift=args.key_shift, inverse=gated_delta._inverse_form(c, place),
+        mean_key_cosine=float(np.mean(kh @ kh.T)),
+        gap_to_float64=dict(
+            solved=gap(solved, want), d_a=gap(d_a, want_d_a),
+            d_rhs=gap(d_rhs, want_d_rhs),
+        ),
+        solved_rounding=float(jnp.finfo(dtype).eps) / 2,
+    )
+    if not args.rehearse:
+        with tempfile.TemporaryDirectory() as trace_dir:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+            for fn, inputs in fns.values():
+                for _ in range(REPS):
+                    out = fn(*inputs)
+                jax.block_until_ready(out)
+            jax.profiler.stop_trace()
+            trace = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
+        for which in fns:
+            runs = [
+                e for spans in trace_reduce.module_runs(
+                    trace, rf"^jit_sweep_prepare_{which}\b"
+                ).values() for e in spans
+            ]
+            row[f"{which}_module_ms"] = statistics.median(
+                e.dur * 1e3 for e in runs
+            )
+            inside: dict[str, float] = {}
+            for events in trace.ops.values():
+                for e in events:
+                    if any(m.start <= e.start < m.end for m in runs):
+                        name = trace_reduce.op_short_name(e.name)
+                        inside[name] = inside.get(name, 0.0) + e.dur * 1e3
+            row[f"{which}_longest_ops_ms"] = [
+                [name, ms / len(runs)] for name, ms in
+                sorted(inside.items(), key=lambda kv: -kv[1])[:5]
+            ]
+    os.makedirs("chiprun_out/gdn_chunk_sweep", exist_ok=True)
+    name = f"prepare_{b}x{t}x{h}x{d}_{dtype.name}_shift{args.key_shift:g}.json"
+    with open(os.path.join("chiprun_out/gdn_chunk_sweep", name), "w") as f:
+        json.dump(row, f)
+    print(json.dumps(row), flush=True)
     return 0
 
 
