@@ -424,7 +424,8 @@ class Translator:
 
 
 class LanguageModel:
-    """A decoder-only language model (``models.sala_lm``) with its
+    """A decoder-only language model (``models.sala_lm``, ``models.dsa_lm``:
+    the runtime finds the model through its configuration's module) with its
     parameters, callable on token ids and servable through the same
     ``ServingEngine`` as a ``Translator``. Prompts and answers are arrays of
     token ids: the bundle holds no tokenizer.
@@ -495,7 +496,7 @@ class LanguageModel:
         if new is None:
             raise TypeError("serve() needs max_new_tokens")
         engine_kwargs.setdefault("boundaries", (max_context - new,))
-        engine_kwargs.setdefault("page_size", self.cfg.sparse.block)
+        engine_kwargs.setdefault("page_size", self.cfg.page_size)
         engine = ServingEngine(self, **engine_kwargs)
         return engine.start() if start else engine
 
@@ -520,10 +521,10 @@ class LanguageModel:
             LMDecodeRuntime,
         )
 
-        if page_size != self.cfg.sparse.block:
+        if page_size != self.cfg.page_size:
             raise ValueError(
-                f"page_size {page_size}: a page is the selector's block "
-                f"({self.cfg.sparse.block} positions)"
+                f"page_size {page_size}: the model's page is "
+                f"{self.cfg.page_size} positions"
             )
         if kv_dtype != "float32" or quantize_self:
             raise ValueError(

@@ -207,6 +207,170 @@ def _grouped_swiglu(xs, sizes, w_gate, w_up, w_down):
     return dot(h.astype(xs.dtype), w_down)
 
 
+def route_sigmoid_grouped(logits, bias, k: int, *, groups: int,
+                          groups_kept: int, scale: float = 1.0):
+    """DeepSeek-V3's router (``noaux_tc``): ``s = sigmoid(logits)`` over all
+    experts in float32; the choice reads ``s + bias`` (the correction bias
+    balances the load without an auxiliary loss): the experts fall into
+    ``groups`` equal groups, each scored by the sum of its two largest ``s +
+    bias``, the ``groups_kept`` best groups are kept and the ``k`` largest
+    ``s + bias`` among their experts are taken. The weights are the chosen
+    experts' ``s`` (not ``s + bias``), divided by their sum, times ``scale``.
+    ``logits [N, E]``, ``bias [E]`` -> ``(s [N, E], experts [N, k], weights
+    [N, k])``."""
+    n, e = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    biased = s + bias.astype(jnp.float32)
+    grouped = biased.reshape(n, groups, e // groups)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [N, groups]
+    _, kept = jax.lax.top_k(group_score, groups_kept)
+    in_kept = jnp.zeros((n, groups), bool).at[
+        jnp.arange(n)[:, None], kept
+    ].set(True)
+    masked = jnp.where(
+        jnp.repeat(in_kept, e // groups, axis=-1), biased, -jnp.inf
+    )
+    _, experts = jax.lax.top_k(masked, k)
+    weights = jnp.take_along_axis(s, experts, axis=-1)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True) * scale
+    return s, experts, weights
+
+
+def route(logits, k: int, *, kind: str = "softmax", renormalize: bool = True,
+          bias=None, groups: int = 1, groups_kept: int = 1,
+          scale: float = 1.0):
+    """The router named by ``kind`` (static): ``"softmax"`` is
+    ``route_top_k`` (``renormalize``), ``"sigmoid_grouped"`` is
+    ``route_sigmoid_grouped`` (``bias``, ``groups``, ``groups_kept``,
+    ``scale``). Returns ``(probabilities [N, E], experts [N, k], weights [N,
+    k])``."""
+    if kind == "softmax":
+        return route_top_k(logits, k, renormalize=renormalize)
+    if kind == "sigmoid_grouped":
+        return route_sigmoid_grouped(
+            logits, bias, k, groups=groups, groups_kept=groups_kept, scale=scale
+        )
+    raise ValueError(f"unknown router kind {kind!r}")
+
+
+def local_assignments(experts, weights, *, first: int, held: int,
+                      num_experts: int) -> dict:
+    """The router's choices ``experts [N, k]`` (weights ``[N, k]``) sorted
+    by held expert for the grouped products: the assignments of experts
+    ``first .. first + held - 1`` first, in expert order, those of absent
+    experts last. Returns ``order`` (assignment numbers in that order),
+    ``counts [num_experts]`` (assignments an expert, all experts), ``sizes
+    [held]``, ``n_local``, ``local`` (whether an assignment is held here),
+    and ``token_of`` / ``weight_of`` a sorted assignment (weight 0 where the
+    expert is absent)."""
+    k = experts.shape[-1]
+    flat_expert = experts.reshape(-1) - first           # [N k]
+    local = (flat_expert >= 0) & (flat_expert < held)
+    sort_key = jnp.where(local, flat_expert, held)
+    order = jnp.argsort(sort_key, stable=True)
+    counts = jnp.bincount(experts.reshape(-1), length=num_experts)
+    sizes = counts[first:first + held].astype(jnp.int32)
+    n_local = jnp.sum(sizes)
+    token_of = order // k
+    weight_of = jnp.where(local, weights.reshape(-1), 0.0)[order]
+    return dict(
+        order=order, counts=counts, sizes=sizes, n_local=n_local, local=local,
+        token_of=token_of, weight_of=weight_of,
+    )
+
+
+def grouped_experts(tokens_c, plan: dict, w_gate, w_up, w_down, *, k: int,
+                    num_experts: int):
+    """The held experts' part of the layer's output for ``tokens_c [N, d]``
+    (the compute dtype) and the sorted assignments ``plan``
+    (``local_assignments``). Sorted assignments are taken ``rows`` at a time.
+    A layer holding a share expects ``N k held / E`` local assignments and
+    sizes a segment at ``_LOCAL_ROWS_SLACK`` times that, so the first segment
+    is the step's whole work; a batch with more runs further segments (each
+    skipped by ``lax.cond`` while empty), up to all ``N k``. A segment is
+    rematerialised, so the loop keeps indices, not activations. Returns
+    ``(out [N, d] float32, assignments computed)``: the second adds up,
+    inside the segments that ran, the group sizes the grouped products were
+    given."""
+    n, d = tokens_c.shape
+    held = w_gate.shape[0]
+    sizes, n_local = plan["sizes"], plan["n_local"]
+    full_rows = n * k
+    rows = min(
+        full_rows,
+        -(-int(_LOCAL_ROWS_SLACK * full_rows * held / num_experts) // 256) * 256,
+    )
+    segments = -(-full_rows // rows)
+    pad = segments * rows - full_rows
+    token_of = jnp.pad(plan["token_of"], (0, pad))
+    weight_of = jnp.pad(plan["weight_of"], (0, pad))
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    # The ``cond`` sits inside the rematerialised function: around it,
+    # the taken branch's residuals (the tokens and the experts'
+    # matrices) would become loop-variant outputs and the scan would
+    # stack them, once a segment.
+    @jax.checkpoint
+    def segment(j, tokens_c, w_gate, w_up, w_down):
+        lo = j * rows
+
+        def run():
+            with jax.named_scope("lm.moe.route"):
+                idx = jax.lax.dynamic_slice(token_of, (lo,), (rows,))
+                weight = jax.lax.dynamic_slice(weight_of, (lo,), (rows,))
+                here = jnp.clip(
+                    jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo), 0
+                ).astype(jnp.int32)
+                # Rows past the last group belong to no expert. The
+                # TPU's grouped product leaves them as it found them,
+                # forward and backward (read on the chip, PR 26: garbage
+                # of any size, where the CPU's writes zeros), so they
+                # are cut off on both sides of it: the ``where`` in
+                # front zeroes their cotangent, the one behind their
+                # value.
+                valid = (lo + jnp.arange(rows) < n_local)[:, None]
+                xs = jnp.where(valid, tokens_c[idx], 0)
+            with jax.named_scope("lm.moe.experts"):
+                ys = _grouped_swiglu(xs, here, w_gate, w_up, w_down)
+            ys = jnp.where(valid, ys, 0.0) * weight[:, None]
+            return idx, ys, jnp.sum(here)
+
+        def skip():
+            return (
+                jnp.zeros((rows,), token_of.dtype),
+                jnp.zeros((rows, d), jnp.float32),
+                jnp.int32(0),
+            )
+
+        return jax.lax.cond(lo < n_local, run, skip)
+
+    def add_segment(carry, j):
+        out, computed = carry
+        idx, ys, grouped = segment(j, tokens_c, w_gate, w_up, w_down)
+        with jax.named_scope("lm.moe.route"):
+            out = jax.lax.cond(
+                j * rows < n_local, lambda o: o.at[idx].add(ys),
+                lambda o: o, out,
+            )
+        return (out, computed + grouped), None
+
+    (out, n_computed), _ = jax.lax.scan(
+        add_segment, (jnp.zeros((n, d), jnp.float32), jnp.int32(0)),
+        jnp.arange(segments),
+    )
+    return out, n_computed
+
+
+def shared_swiglu(tokens_c, w_gate, w_up, w_down, dtype):
+    """The always-on shared expert ``(silu(x Wg) * (x Wu)) Wd`` in ``dtype``,
+    float32 out; the caller gates it or not."""
+    h = jax.nn.silu(tokens_c @ w_gate.astype(dtype)) * (
+        tokens_c @ w_up.astype(dtype)
+    )
+    return (h @ w_down.astype(dtype)).astype(jnp.float32)
+
+
 class DroplessMoE(nn.Module):
     """Top-k mixture of SwiGLU experts as deployed today: softmax router
     over all ``num_experts``, ``top_k`` experts a token, no capacity and no
@@ -234,6 +398,10 @@ class DroplessMoE(nn.Module):
     held expert, ``stats["assignments_computed"]`` adds up, inside the
     segments that ran, the group sizes the grouped products were given. A
     segment skipped or cut short shows as a difference between them.
+
+    The routing, the sort and the grouped products are the module's
+    functions ``route``, ``local_assignments``, ``grouped_experts`` and
+    ``shared_swiglu``, which the served ``models.dsa_lm`` calls too.
 
     Input ``[B, S, d]``; returns ``(out [B, S, d], stats)`` with float32
     scalars ``aux`` (``E * sum_e f_e p_e`` over all experts, ``f_e`` the share
@@ -280,98 +448,25 @@ class DroplessMoE(nn.Module):
                 tokens.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
-            probs, experts, weights = route_top_k(
-                logits, k, renormalize=self.renormalize
+            probs, experts, weights = route(
+                logits, k, kind="softmax", renormalize=self.renormalize
             )
-            flat_expert = experts.reshape(-1) - first           # [N k]
-            local = (flat_expert >= 0) & (flat_expert < held)
-            sort_key = jnp.where(local, flat_expert, held)
-            order = jnp.argsort(sort_key, stable=True)
-            counts = jnp.bincount(experts.reshape(-1), length=e)
-            sizes = counts[first:first + held].astype(jnp.int32)
-            n_local = jnp.sum(sizes)
-            token_of = order // k
-            weight_of = jnp.where(local, weights.reshape(-1), 0.0)[order]
+            plan = local_assignments(
+                experts, weights, first=first, held=held, num_experts=e
+            )
+            counts, sizes, local = plan["counts"], plan["sizes"], plan["local"]
             aux = e * jnp.sum(
                 counts.astype(jnp.float32) / (n * k) * jnp.mean(probs, axis=0)
             )
 
-        # Sorted assignments are taken ``rows`` at a time. A layer holding
-        # a share expects N k held / E local assignments and sizes a segment
-        # at ``_LOCAL_ROWS_SLACK`` times that, so the first segment is the
-        # step's whole work; a batch with more runs further segments (each
-        # skipped by ``lax.cond`` while empty), up to all N k. A segment is
-        # rematerialised, so the loop keeps indices, not activations.
-        full_rows = n * k
-        rows = min(
-            full_rows,
-            -(-int(_LOCAL_ROWS_SLACK * full_rows * held / e) // 256) * 256,
-        )
-        segments = -(-full_rows // rows)
-        pad = segments * rows - full_rows
-        token_of = jnp.pad(token_of, (0, pad))
-        weight_of = jnp.pad(weight_of, (0, pad))
-        ends = jnp.cumsum(sizes)
-        starts = ends - sizes
-
-        # The ``cond`` sits inside the rematerialised function: around it,
-        # the taken branch's residuals (the tokens and the experts'
-        # matrices) would become loop-variant outputs and the scan would
-        # stack them, once a segment.
-        @jax.checkpoint
-        def segment(j, tokens_c, w_gate, w_up, w_down):
-            lo = j * rows
-
-            def run():
-                with jax.named_scope("lm.moe.route"):
-                    idx = jax.lax.dynamic_slice(token_of, (lo,), (rows,))
-                    weight = jax.lax.dynamic_slice(weight_of, (lo,), (rows,))
-                    here = jnp.clip(
-                        jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo), 0
-                    ).astype(jnp.int32)
-                    # Rows past the last group belong to no expert. The
-                    # TPU's grouped product leaves them as it found them,
-                    # forward and backward (read on the chip, PR 26: garbage
-                    # of any size, where the CPU's writes zeros), so they
-                    # are cut off on both sides of it: the ``where`` in
-                    # front zeroes their cotangent, the one behind their
-                    # value.
-                    valid = (lo + jnp.arange(rows) < n_local)[:, None]
-                    xs = jnp.where(valid, tokens_c[idx], 0)
-                with jax.named_scope("lm.moe.experts"):
-                    ys = _grouped_swiglu(xs, here, w_gate, w_up, w_down)
-                ys = jnp.where(valid, ys, 0.0) * weight[:, None]
-                return idx, ys, jnp.sum(here)
-
-            def skip():
-                return (
-                    jnp.zeros((rows,), token_of.dtype),
-                    jnp.zeros((rows, d), jnp.float32),
-                    jnp.int32(0),
-                )
-
-            return jax.lax.cond(lo < n_local, run, skip)
-
-        def add_segment(carry, j):
-            out, computed = carry
-            idx, ys, grouped = segment(j, tokens_c, w_gate, w_up, w_down)
-            with jax.named_scope("lm.moe.route"):
-                out = jax.lax.cond(
-                    j * rows < n_local, lambda o: o.at[idx].add(ys),
-                    lambda o: o, out,
-                )
-            return (out, computed + grouped), None
-
-        (out, n_computed), _ = jax.lax.scan(
-            add_segment, (jnp.zeros((n, d), jnp.float32), jnp.int32(0)),
-            jnp.arange(segments),
+        out, n_computed = grouped_experts(
+            tokens_c, plan, w_gate, w_up, w_down, k=k, num_experts=e
         )
 
         with jax.named_scope("lm.moe.shared"):
-            h = jax.nn.silu(tokens_c @ shared_gate.astype(self.dtype)) * (
-                tokens_c @ shared_up.astype(self.dtype)
+            shared = shared_swiglu(
+                tokens_c, shared_gate, shared_up, shared_down, self.dtype
             )
-            shared = (h @ shared_down.astype(self.dtype)).astype(jnp.float32)
             gate = jax.nn.sigmoid(jnp.dot(
                 tokens.astype(jnp.float32), shared_router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,

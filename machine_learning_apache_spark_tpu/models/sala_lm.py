@@ -58,6 +58,9 @@ from machine_learning_apache_spark_tpu.ops.sparse_block_attention import (
 )
 
 SPARSE, LIGHTNING = "minicpm4", "lightning-attn"
+#: A decode step's counters, and those of a launch that have to agree
+#: (``serving.lm_runtime``): none.
+COUNTS, PAIRED_COUNTS = (), ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,6 +91,11 @@ class SalaLMConfig:
             raise ValueError(f"unknown mixer types {sorted(unknown)}")
         if self.num_heads % self.num_kv_heads:
             raise ValueError("num_heads is not a multiple of num_kv_heads")
+
+    @property
+    def page_size(self) -> int:
+        """A page is the selector's block."""
+        return self.sparse.block
 
     @property
     def num_layers(self) -> int:
@@ -171,6 +179,37 @@ def new_cache(cfg: SalaLMConfig, *, rows: int, num_pages: int, device=None) -> d
             for _ in range(cfg.lightning_layers)
         ],
     )
+
+
+def page_bytes(cfg: SalaLMConfig) -> int:
+    """K, V and unit means of one page in every sparse layer."""
+    spec = cfg.sparse
+    return cfg.sparse_layers * cfg.num_kv_heads * cfg.head_dim * (
+        2 * spec.block + spec.units
+    ) * jnp.dtype(cfg.dtype).itemsize
+
+
+def state_planes(cfg: SalaLMConfig) -> list:
+    """Shapes of the fixed-size float32 state a row keeps beside the pages,
+    ``cache["states"]``: one ``[heads, d, d]`` a lightning layer."""
+    d = cfg.lightning_head_dim
+    return [(cfg.lightning_heads, d, d)] * cfg.lightning_layers
+
+
+def selected_share(cfg: SalaLMConfig, chosen, pos, dense):
+    """Key positions a step attended over the positions its row's context
+    held, the mean over sparse layers and KV heads (1 for a dense row):
+    ``chosen [sparse layers, R, kv heads, topk]`` block indices (-1 where
+    none), ``pos [R]`` -> ``[R]``."""
+    block = cfg.sparse.block
+    held = jnp.clip(
+        pos[None, :, None, None] + 1 - chosen * block, 0, block
+    )
+    held = jnp.where(chosen >= 0, held, 0)
+    attended = jnp.sum(held, axis=-1).mean(axis=(0, 2)) if (
+        chosen.shape[0]
+    ) else jnp.zeros(pos.shape, jnp.float32)
+    return jnp.where(dense, 1.0, attended / (pos + 1.0))
 
 
 def _norm(x, w, eps):
@@ -326,7 +365,8 @@ def decode_step(params, cfg: SalaLMConfig, cache: dict, token, pos, tables,
     """One position for every row: ``token [R]`` at ``pos [R]``, block tables
     ``tables [R, Pmax]``; rows not ``active`` write to the null page and
     their outputs mean nothing. Returns ``(logits [R, V] float32, new
-    cache, selected [sparse layers, R, kv heads, topk])``."""
+    cache, selected [sparse layers, R, kv heads, topk], counts)``; no
+    counters here (``counts`` is empty)."""
     spec = cfg.sparse
     r = token.shape[0]
     page = jnp.take_along_axis(tables, (pos // spec.block)[:, None], axis=1)[:, 0]
@@ -382,4 +422,4 @@ def decode_step(params, cfg: SalaLMConfig, cache: dict, token, pos, tables,
         jnp.stack(selected) if selected
         else jnp.zeros((0, r, cfg.num_kv_heads, spec.topk), jnp.int32)
     )
-    return _head(params, cfg, x), cache, chosen
+    return _head(params, cfg, x), cache, chosen, {}

@@ -1,4 +1,5 @@
-"""Sinusoidal positional encoding.
+"""Positional encodings: sinusoidal tables, rotary positions, and YaRN's
+stretched rotary frequencies.
 
 The reference recomputes the full PE table on **every forward call** and
 device-transfers it each time (``transformer.py:33-42``, ``:60`` — quirk noted
@@ -10,6 +11,7 @@ under jit.
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 import jax.numpy as jnp
@@ -62,18 +64,52 @@ def rotary_embedding(
     return jnp.concatenate([turned.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
 
 
+def yarn_inv_freq(dim: int, *, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies (Peng et al., arXiv:2309.00071, as DeepSeek-V3
+    applies them) for ``dim`` rotated channels: a pair ``i`` turns at
+    ``f_i = theta^(-2i/dim)`` where it completes more than ``beta_fast``
+    turns over the ``original`` context, at ``f_i / factor`` where it
+    completes fewer than ``beta_slow``, and in between at the mix given by a
+    linear ramp over the pair's index between the two correction dims.
+    Returns ``[dim / 2]`` float64."""
+    def correction_dim(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``m = 0.1 mscale ln(factor) + 1``
+    (1 where the context is not stretched); DeepSeek-V3 multiplies the
+    softmax scale by ``m^2``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rotary_embedding_at(
-    x: jnp.ndarray, positions: jnp.ndarray, *, theta: float = 10000.0
+    x: jnp.ndarray, positions: jnp.ndarray, *, theta: float = 10000.0,
+    inv_freq: np.ndarray | None = None,
 ) -> jnp.ndarray:
     """Rotary positions on every channel of ``x [..., d]`` at the given
     ``positions`` (broadcastable to ``x.shape[:-1]``): what a served model
     needs, whose chunk or decode step starts anywhere in the sequence. Same
     rotate-half layout as ``rotary_embedding``; the angles are worked out in
-    float32 where they are used."""
+    float32 where they are used. ``inv_freq [d / 2]`` (``yarn_inv_freq``)
+    replaces the plain ``theta^(-2i/d)``."""
     d = x.shape[-1]
     half = d // 2
     inv_freq = jnp.asarray(
-        theta ** (-np.arange(0, d, 2, dtype=np.float64) / d), jnp.float32
+        theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+        if inv_freq is None else inv_freq,
+        jnp.float32,
     )
     angles = jnp.asarray(positions, jnp.float32)[..., None] * inv_freq
     cos, sin = jnp.cos(angles), jnp.sin(angles)

@@ -1,35 +1,47 @@
 """Decoder-only paged runtime — the device half of the serving engine for a
-language model (``models.sala_lm``): the second implementation of the
-interface ``ServingEngine`` drives (``admit``, ``grow``, ``launch``,
-``retire``, ``reset``, ``warmup``, ``jit_fns``, ``stats``, ``prefill_cost``,
-the pools), beside the encoder-decoder ``PagedDecodeRuntime``.
+language model: the second implementation of the interface ``ServingEngine``
+drives (``admit``, ``grow``, ``launch``, ``retire``, ``reset``, ``warmup``,
+``jit_fns``, ``stats``, ``prefill_cost``, the pools), beside the
+encoder-decoder ``PagedDecodeRuntime``.
 
 Where that runtime keeps two page stores (a decoder's own K/V and the
 encoder's memory), a decoder-only model has **one**: the causal chunked
-prefill writes the very pages decode reads. And where every layer of that
-model is softmax attention, this one's layers keep two kinds of cache, held
-side by side under one manager:
+prefill writes the very pages decode reads.
 
-- **pages** of ``page_size`` positions for the sparse-attention layers (K, V
-  and the selector's unit means, ``models.sala_lm.new_cache``), addressed by
-  a block table a row, allocated from one refcounted ``KVPagePool``;
-- a **state** a row for the linear-attention layers, a fixed float32
-  ``[heads, d, d]`` a layer whatever the context's length: slot ``row`` of
-  the cache's ``states``.
+**The model seam.** The runtime holds no model of its own: the module that
+defines the configuration's class (``models.sala_lm``, ``models.dsa_lm``)
+gives ``new_cache``, ``prefill_chunk``, ``decode_step`` (logits, the new
+cache, the step's selections and a dict of the counters ``COUNTS`` names),
+``page_bytes``, ``state_planes``, ``selected_share`` and ``PAIRED_COUNTS``,
+and the config
+gives ``page_size`` and ``is_dense``. A model's cache may hold two kinds of
+state side by side under one manager:
+
+- **pages** of ``page_size`` positions (``models.sala_lm``: K, V and the
+  selector's unit means; ``models.dsa_lm``: latent rows and index keys),
+  addressed by a block table a row, allocated from one refcounted
+  ``KVPagePool``;
+- a **state** a row, where ``state_planes`` names any (``models.sala_lm``'s
+  linear-attention layers: a fixed float32 ``[heads, d, d]`` a layer
+  whatever the context's length), slot ``row`` of the cache's ``states``.
 
 Prefix reuse needs both: a ``SnapshotCache`` entry is the pages of a
-prompt's first positions (shared read-only by reference) *and* a copy of the
-state after them, kept in a snapshot plane ``[slots, heads, d, d]`` a layer.
-The runtime takes a snapshot at the last page-aligned position of a long
-prefill; a later prompt that begins with the same ids attaches the pages,
-restores the state into its row (``serving.state_restore``) and prefills the
-rest.
+prompt's first positions (shared read-only by reference) and, for a model
+with a state, a copy of the state after them, kept in a snapshot plane
+``[slots, heads, d, d]`` a layer. The runtime takes a snapshot at the last
+page-aligned position of a long prefill; a later prompt that begins with the
+same ids attaches the pages, restores any state into its row
+(``serving.state_restore``) and prefills the rest. A model without a state
+resumes from the pages alone.
 
 Compiled programs, all of fixed shapes (so zero recompiles whatever the
 traffic): ``paged_prefill`` (one chunk of one request, run chunk after chunk
 however long the prompt), ``paged_launch`` (``steps_per_launch`` greedy steps
-over every row), ``state_save`` and ``state_restore`` (a row's states to and
-from a snapshot slot). ``launch(logits_of=rows)`` is the one exception: the
+over every row) and, for a model with a state, ``state_save`` and
+``state_restore`` (a row's states to and from a snapshot slot). A launch sums
+the model's counters over its steps into ``counters`` and onto its fold span;
+a pair named in ``PAIRED_COUNTS`` that differs in a launch is counted in
+``launches_unequal``. ``launch(logits_of=rows)`` is the one exception: the
 launch that also hands back those rows' logits and selections is a program of
 its own, compiled when first asked for (a check's tool; the serving loop
 never asks).
@@ -41,11 +53,12 @@ Single-threaded by contract, as ``PagedDecodeRuntime``.
 
 from __future__ import annotations
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from machine_learning_apache_spark_tpu.models import sala_lm
 from machine_learning_apache_spark_tpu.parallel.mesh import on_one_device
 from machine_learning_apache_spark_tpu.serving.kv_pages import (
     NULL_PAGE,
@@ -62,7 +75,7 @@ NO_TOKEN = -1
 class LMDecodeRuntime:
     def __init__(
         self,
-        cfg: sala_lm.SalaLMConfig,
+        cfg,
         params,
         *,
         max_active: int,
@@ -73,11 +86,11 @@ class LMDecodeRuntime:
         num_pages: int | None = None,
         snapshot_capacity: int = 16,
     ):
-        page = cfg.sparse.block
+        page = cfg.page_size
         if prefill_chunk % page:
             raise ValueError(
                 f"prefill_chunk ({prefill_chunk}) must be a multiple of the "
-                f"page size ({page}, the selector's block)"
+                f"page size ({page})"
             )
         if max_new_tokens >= max_context:
             raise ValueError(
@@ -103,21 +116,29 @@ class LMDecodeRuntime:
             )
         self.num_pages = num_pages
         self.snapshot_capacity = snapshot_capacity
-        itemsize = np.dtype(cfg.dtype).itemsize
-        self.page_bytes = cfg.sparse_layers * cfg.num_kv_heads * cfg.head_dim * (
-            2 * page + cfg.sparse.units
-        ) * itemsize
-        self.state_bytes_per_row = (
-            cfg.lightning_layers * cfg.lightning_heads
-            * cfg.lightning_head_dim ** 2 * 4
+        self.page_bytes = self.model.page_bytes(cfg)
+        self.state_bytes_per_row = sum(
+            int(np.prod(shape)) * 4 for shape in self._states
         )
         self._donate = jax.default_backend() != "cpu"
         self._prefill_fn = self._make_prefill()
         self._launch_fn = self._make_launch()
-        self._save_fn, self._restore_fn = self._make_state_copies()
+        self._save_fn, self._restore_fn = (
+            self._make_state_copies() if self._states else (None, None)
+        )
         self._logits_launch_fn = None  # made when first asked for
         self.captured = None  # what the last ``launch(logits_of=)`` handed back
         self._fresh()
+
+    @property
+    def model(self):
+        """The model's module: the one that defines the config's class."""
+        return sys.modules[type(self.cfg).__module__]
+
+    @property
+    def _states(self) -> list:
+        """Shapes of the state a row keeps beside the pages, if any."""
+        return self.model.state_planes(self.cfg)
 
     # -- state ----------------------------------------------------------------
     def _fresh(self) -> None:
@@ -128,17 +149,16 @@ class LMDecodeRuntime:
         self.prefix_cache = SnapshotCache(
             self.mem_pool, self.snapshot_capacity, self.page_size
         )
-        self.cache = sala_lm.new_cache(
+        self.cache = self.model.new_cache(
             cfg, rows=self.max_active, num_pages=self.num_pages,
             device=self.device,
         )
         self.snapshots = [
             jnp.zeros(
-                (self.prefix_cache.num_slots, cfg.lightning_heads,
-                 cfg.lightning_head_dim, cfg.lightning_head_dim),
-                jnp.float32, device=self.device,
+                (self.prefix_cache.num_slots, *shape), jnp.float32,
+                device=self.device,
             )
-            for _ in range(cfg.lightning_layers)
+            for shape in self._states
         ]
         r = self.max_active
         self._tables = np.full((r, self.table_width), NULL_PAGE, np.int32)
@@ -155,14 +175,15 @@ class LMDecodeRuntime:
         self.counters = dict(
             prompt_tokens=0, resumed_tokens=0, prefill_chunks=0,
             snapshots_taken=0, selected_share_sum=0.0, selected_share_n=0,
+            launches_unequal=0,
         )
 
     # -- compiled programs ------------------------------------------------------
     def _make_prefill(self):
-        cfg = self.cfg
+        cfg, model = self.cfg, self.model
 
         def paged_prefill(params, cache, tokens, table, row, start, length, dense):
-            return sala_lm.prefill_chunk(
+            return model.prefill_chunk(
                 params, cfg, cache, tokens, table, row, start, length, dense
             )
 
@@ -175,29 +196,22 @@ class LMDecodeRuntime:
         ``logits_of [n]``, it also returns those rows' ``logits [steps, n,
         V]`` and ``selected [steps, sparse layers, n, kv heads, topk]``:
         another compiled program, kept apart from the serving one."""
-        cfg = self.cfg
-        steps, max_new, block = self.steps_per_launch, self.max_new_tokens, cfg.sparse.block
-        eos = cfg.eos_id
+        cfg, model = self.cfg, self.model
+        steps, max_new = self.steps_per_launch, self.max_new_tokens
+        eos, names = cfg.eos_id, model.COUNTS
 
         def paged_launch(params, cache, token, pos, count, finished, tables,
                          dense, logits_of=None):
             def step(carry, _):
                 cache, token, pos, count, finished = carry
                 active = ~finished
-                logits, cache, chosen = sala_lm.decode_step(
+                logits, cache, chosen, counts = model.decode_step(
                     params, cfg, cache, token, pos, tables, active, dense
                 )
                 emit = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 emit = jnp.where(finished, NO_TOKEN, emit)
                 # key positions attended over context positions, a row
-                held = jnp.clip(
-                    pos[None, :, None, None] + 1 - chosen * block, 0, block
-                )
-                held = jnp.where(chosen >= 0, held, 0)
-                attended = jnp.sum(held, axis=-1).mean(axis=(0, 2)) if (
-                    chosen.shape[0]
-                ) else jnp.zeros(pos.shape, jnp.float32)
-                share = jnp.where(dense, 1.0, attended / (pos + 1.0))
+                share = model.selected_share(cfg, chosen, pos, dense)
                 share_sum = jnp.sum(jnp.where(active, share, 0.0))
                 step_n = active.astype(jnp.int32)
                 pos, count = pos + step_n, count + step_n
@@ -209,16 +223,21 @@ class LMDecodeRuntime:
                     logits[logits_of], chosen[:, logits_of]
                 )
                 return (cache, token, pos, count, finished), (
-                    emit, share_sum, jnp.sum(step_n), *read
+                    emit, share_sum, jnp.sum(step_n),
+                    *(counts[name] for name in names), *read
                 )
 
             carry, outs = jax.lax.scan(
                 step, (cache, token, pos, count, finished), None, length=steps
             )
             cache, token, pos, count, finished = carry
-            emits, share_sum, share_n, *read = outs
+            emits, share_sum, share_n, *rest = outs
+            summed, read = rest[:len(names)], rest[len(names):]
             host = jnp.stack([token, pos, count, finished.astype(jnp.int32)])
-            stats = jnp.stack([jnp.sum(share_sum), jnp.sum(share_n).astype(jnp.float32)])
+            stats = jnp.stack([
+                jnp.sum(share_sum), jnp.sum(share_n).astype(jnp.float32),
+                *(jnp.sum(c).astype(jnp.float32) for c in summed),
+            ])
             return cache, emits, host, stats, *read
 
         return jax.jit(paged_launch, donate_argnums=(1,) if self._donate else ())
@@ -249,15 +268,18 @@ class LMDecodeRuntime:
         )
 
     def jit_fns(self) -> list:
-        return [self._prefill_fn, self._launch_fn, self._save_fn, self._restore_fn]
+        return [self._prefill_fn, self._launch_fn] + (
+            [self._save_fn, self._restore_fn] if self._states else []
+        )
 
     def warmup(self) -> int:
-        """Compile the four programs against the live planes (null-page
-        targets, slot 0, no row active). Returns the program count."""
-        self.snapshots = self._save_fn(
-            self.snapshots, self.cache["states"], np.int32(0), np.int32(0)
-        )
-        self._restore(0, 0)
+        """Compile the programs against the live planes (null-page targets,
+        slot 0, no row active). Returns the program count."""
+        if self._states:
+            self.snapshots = self._save_fn(
+                self.snapshots, self.cache["states"], np.int32(0), np.int32(0)
+            )
+            self._restore(0, 0)
         self._run_chunk(
             np.zeros(self.prefill_chunk, np.int32), self._tables[0], 0, 0, 1, False
         )
@@ -300,7 +322,8 @@ class LMDecodeRuntime:
         prefill the rest chunk by chunk into pages of its own, and arm the
         row. Returns ``(kind, computed, real)`` (``kind`` "hit" where a
         snapshot was resumed; ``computed`` the chunk-padded positions
-        prefilled) or None where the pool cannot hold the request now."""
+        prefilled, ``real`` the positions among them) or None where the pool
+        cannot hold the request now."""
         ids = np.asarray(req.ids, np.int32)
         n, chunk, page = len(ids), self.prefill_chunk, self.page_size
         entry = self.prefix_cache.lookup(ids, n - 1, owner=req.id)
@@ -319,7 +342,8 @@ class LMDecodeRuntime:
         table[len(shared): len(shared) + len(own)] = own
         self._alloc[row] = len(shared) + len(own)
         dense = self.cfg.is_dense(n + self.max_new_tokens)
-        self._restore(entry["slot"] if entry else 0, row)
+        if self._states:
+            self._restore(entry["slot"] if entry else 0, row)
 
         # Prefill positions resumed .. n - 2. A long one stops at the last
         # page boundary first, where its snapshot is taken.
@@ -351,15 +375,16 @@ class LMDecodeRuntime:
         self._token[row] = ids[-1]
         self._finished[row] = False
         self._dense[row] = dense
-        return ("hit" if entry else "miss"), computed, n
+        return ("hit" if entry else "miss"), computed, end - resumed
 
     def _take_snapshot(self, ids, table, row: int) -> None:
         slot = self.prefix_cache.reserve_slot()
         if slot is None:
             return
-        self.snapshots = self._save_fn(
-            self.snapshots, self.cache["states"], np.int32(row), np.int32(slot)
-        )
+        if self._states:
+            self.snapshots = self._save_fn(
+                self.snapshots, self.cache["states"], np.int32(row), np.int32(slot)
+            )
         pages = [int(p) for p in table[: len(ids) // self.page_size]]
         if self.prefix_cache.put(ids, pages, slot):
             self.counters["snapshots_taken"] += 1
@@ -430,6 +455,12 @@ class LMDecodeRuntime:
         self._finished = host[3].astype(bool)
         self.counters["selected_share_sum"] += float(share[0])
         self.counters["selected_share_n"] += int(share[1])
+        counts = dict(zip(self.model.COUNTS, (int(c) for c in share[2:])))
+        for name, value in counts.items():
+            self.counters[name] = self.counters.get(name, 0) + value
+        self.counters["launches_unequal"] += int(any(
+            counts[a] != counts[b] for a, b in self.model.PAIRED_COUNTS
+        ))
         completed, first_emits, real, rows = [], [], 0, 0
         eos = self.cfg.eos_id
         with annotate("serving.launch.fold") as phase:
@@ -458,7 +489,7 @@ class LMDecodeRuntime:
                 self._pos[r] for r, q in enumerate(self._req_of_row) if q is not None
             ))
             phase.set(rows=rows, real_tokens=real, completed=len(completed),
-                      context=context)
+                      context=context, **counts)
         return LaunchResult(
             completed=completed, first_emits=first_emits, real_tokens=real,
             computed_slots=self.max_active * self.steps_per_launch,

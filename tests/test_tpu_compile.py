@@ -158,3 +158,65 @@ def test_served_language_model_programs_compile_in_place(one_chip, program):
         if " copy(" in line and f"= {plane}" in line
     ]
     assert "reduce-window" not in text
+
+
+@pytest.mark.parametrize("program", ["launch", "prefill"])
+def test_served_latent_attention_programs_compile_in_place(one_chip, program):
+    """``models.dsa_lm``'s two hot programs at the benchmark's published
+    widths and engine sizes (``deepseek_v32_exp``): they fit the chip beside
+    the weights and pages, the latent and index planes are written in place
+    (no copy of a whole plane: latent rows of 576 lanes cost five 582 MB
+    copies a launch, hence rows of 640), and the index scan forms no
+    ``[queries, 64 heads, context]`` array (538 MB a layer in the launch)."""
+    from benchmark import manifest, weights_dsa_lm
+    from machine_learning_apache_spark_tpu.models import dsa_lm
+    from machine_learning_apache_spark_tpu.serving.lm_runtime import (
+        LMDecodeRuntime,
+    )
+
+    cfg = manifest.load_config(manifest.load_manifest(), "deepseek_v32_exp")
+    model, engine = weights_dsa_lm.model_config(cfg), cfg["engine"]
+    runtime = object.__new__(LMDecodeRuntime)  # the programs, no planes
+    runtime.cfg, runtime._donate = model, True
+    runtime.steps_per_launch = engine["steps_per_launch"]
+    runtime.max_new_tokens = engine["max_new_tokens"]
+    rows, chunk = engine["max_active"], engine["prefill_chunk"]
+    width = -(-engine["max_context"] // 64) + chunk // 64
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = described(jax.eval_shape(lambda: weights_dsa_lm.make_params(1, cfg)))
+    cache = described(jax.eval_shape(
+        lambda: dsa_lm.new_cache(model, rows=rows, num_pages=engine["num_pages"])
+    ))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
+    flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=one_chip)  # noqa: E731
+    if program == "launch":
+        lowered = runtime._make_launch().lower(
+            params, cache, i32(rows), i32(rows), i32(rows), flag(rows),
+            i32(rows, width), flag(rows),
+        )
+    else:
+        lowered = runtime._make_prefill().lower(
+            params, cache, i32(chunk), i32(width), i32(), i32(), i32(), flag()
+        )
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+    assert memory.alias_size_in_bytes > 3.5e9  # the cache is donated
+    text = compiled.as_text()
+    positions = engine["num_pages"] * 64
+    for plane in (f"bf16[{positions},640]", f"bf16[{positions},128]"):
+        assert f"= {plane}" in text
+        assert not [
+            line for line in text.splitlines()
+            if " copy(" in line and f"= {plane}" in line
+        ], plane
+    queries = rows if program == "launch" else 64
+    context = -(-width // 64) * 64 * 64  # the table in whole passes of 4,096
+    assert f"f32[{queries},64,{context}]" not in text
+    assert f"f32[{queries},64,4096]" in text  # one pass of the scan
