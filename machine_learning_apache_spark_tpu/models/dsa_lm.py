@@ -61,9 +61,12 @@ from machine_learning_apache_spark_tpu.ops.positional import (
 NULL_PAGE = 0
 #: A decode step's counters, summed over the expert layers (assignments to
 #: held experts as the router chose them and as the grouped products were
-#: given them, held experts with any assignment), and the pair of them that
-#: has to agree in a launch (``serving.lm_runtime``).
-COUNTS = ("moe_assignments_local", "moe_assignments_computed", "moe_experts_touched")
+#: given them, held experts with any assignment) and over every layer's
+#: index scan (pages of keys the path it took fetched, and what the XLA
+#: scan fetches at the same step: ``ops.dsa_index.pages_read``), and the
+#: pair of them that has to agree in a launch (``serving.lm_runtime``).
+COUNTS = ("moe_assignments_local", "moe_assignments_computed",
+          "moe_experts_touched", "index_pages_read", "index_pages_padded")
 PAIRED_COUNTS = (("moe_assignments_local", "moe_assignments_computed"),)
 
 
@@ -423,8 +426,9 @@ def decode_step(params, cfg: DSALMConfig, cache: dict, token, pos, tables,
     tables ``tables [R, Pmax]``; rows not ``active`` write to the null page
     and their outputs mean nothing. Returns ``(logits [R, V] float32, new
     cache, chosen [layers, R, index_topk] (-1 where a slot is empty),
-    counts)``, the counts summed over the expert layers: assignments local
-    and computed, and held experts with any assignment."""
+    counts)``, the counts summed over the expert layers (assignments local
+    and computed, and held experts with any assignment) and over every
+    layer's index scan (pages read, and pages the XLA scan reads)."""
     del dense
     page = cfg.page
     slot = jnp.take_along_axis(tables, (pos // page)[:, None], axis=1)[:, 0]
@@ -443,6 +447,10 @@ def decode_step(params, cfg: DSALMConfig, cache: dict, token, pos, tables,
                 block=cfg.index_block, topk=cfg.index_topk,
                 site="dsa_index_decode",
             )
+            read, padded = dsa_index.pages_read(
+                iq, cache["index"][i], tables, t, page=page, block=cfg.index_block
+            )
+        layer_counts = dict(index_pages_read=read, index_pages_padded=padded)
         with jax.named_scope("lm.mla"):
             cache["latent"][i] = latent_attention.write_rows(
                 cache["latent"][i], at, latent
@@ -453,8 +461,8 @@ def decode_step(params, cfg: DSALMConfig, cache: dict, token, pos, tables,
             )
         chosen_all.append(jnp.where(valid, chosen, -1))
         x = x + _attn_out(p, cfg, o, w_uv)
-        out, layer_counts = _ffn(p, cfg, x)
-        for name, value in layer_counts.items():
+        out, moe_counts = _ffn(p, cfg, x)
+        for name, value in {**layer_counts, **moe_counts}.items():
             counts[name] = counts.get(name, 0) + value
         x = x + out
     return _head(params, cfg, x), cache, jnp.stack(chosen_all), counts

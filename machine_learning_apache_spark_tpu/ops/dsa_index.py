@@ -25,8 +25,20 @@ would). The counts that compaction needs are running sums taken as products
 with triangular matrices of ones (``_tile_counts``): a row-wide cumulative
 sum lowers on the TPU to a ``reduce-window`` as wide as the row.
 
-Everything here is plain XLA; which path a site took is its
-``ops.dsa_index_dispatch`` record.
+The scores take one of two paths, chosen from what the site shows (no option
+chooses), and each site's ``ops.dsa_index_dispatch`` record says which:
+
+* **``pallas_paged``** — a block table a row (decode) on the TPU, ``d`` whole
+  lanes, ``page`` whole bfloat16 tiles, bfloat16 operands:
+  ``ops.pallas_dsa_index`` (``dsa_index_scan``), where each row reads its own
+  pages through ``t`` and no further.
+* **``xla_scan``** — everything else (``_kernel_refusal`` names why): the
+  passes of ``paged_scores`` below, which gather every row's pages while the
+  furthest row needs a pass. A prefill chunk's one shared table reads its
+  keys once for all of its queries, so it has nothing to skip.
+
+``pages_read`` counts what the path a decode site took fetched, beside what
+the XLA scan fetches at the same step. The selection is plain XLA on both.
 """
 
 from __future__ import annotations
@@ -45,6 +57,46 @@ def record_dispatch(site: str, impl: str, reason: str, **shape) -> None:
     telemetry.annotate(
         "ops.dsa_index_dispatch", site=site, impl=impl, reason=reason, **shape
     )
+
+
+def _backend() -> str:
+    """The backend a site is traced for: it decides whether the site takes
+    the kernel."""
+    return jax.default_backend()
+
+
+def _kernel_refusal(q, plane, tables, *, page: int, block: int) -> str | None:
+    """Why this site keeps ``xla_scan``; ``None`` where it can be seen to
+    take ``dsa_index_scan``."""
+    if _backend() != "tpu":
+        return f"backend {_backend()}"
+    if tables.ndim != 2:
+        return "one table for every query: its keys are read once for all"
+    if q.dtype != jnp.bfloat16 or plane.dtype != jnp.bfloat16:
+        return f"{q.dtype.name} queries over {plane.dtype.name} keys, not bfloat16"
+    if plane.shape[-1] % _LANES:
+        return f"index head dim {plane.shape[-1]} not a multiple of {_LANES}"
+    if page % 16:
+        return f"page {page} not a multiple of bfloat16's 16 sublanes"
+    return None
+
+
+def _check_pass(page: int, block: int) -> None:
+    if block % page or block % _LANES:
+        raise ValueError(f"a pass of {block} positions is not whole pages "
+                         f"of {page} and tiles of {_LANES}")
+
+
+def pages_read(q, plane, tables, t, *, page: int, block: int):
+    """Pages of keys a decode site's scan fetches at ``t [N]``, and what the
+    XLA scan fetches at the same step: ``(read, padded)`` int32. The XLA scan
+    gathers ``block / page`` pages a row a pass, for every row, through the
+    furthest row's position; the kernel ``t // page + 1`` a row."""
+    n = tables.shape[0]
+    padded = n * ((jnp.max(t) + block) // block) * (block // page)
+    if _kernel_refusal(q, plane, tables, page=page, block=block):
+        return padded, padded
+    return jnp.sum(t // page + 1), padded
 
 
 def index_weights(w, heads: int, head_dim: int):
@@ -77,9 +129,7 @@ def paged_scores(q, w, plane, tables, t, *, page: int, block: int):
     query: a prefill chunk). Returns ``[N, T]`` float32, ``T`` = ``Pmax``
     rounded up to a pass, times ``page``; ``-inf`` past each query's
     position and in the passes not run."""
-    if block % page or block % _LANES:
-        raise ValueError(f"a pass of {block} positions is not whole pages "
-                         f"of {page} and tiles of {_LANES}")
+    _check_pass(page, block)
     bp = block // page
     shared = tables.ndim == 1
     tables = _padded_tables(tables, bp)
@@ -187,11 +237,27 @@ def select(q, w, plane, tables, t, *, page: int, block: int, topk: int,
            site: str):
     """Scores and selection in one call: ``(positions [N, topk], plane rows
     [N, topk], valid [N, topk])`` of queries ``q [N, H, d]`` at ``t [N]``."""
-    record_dispatch(
-        site, "xla_scan", "the one path", queries=q.shape[0], heads=q.shape[1],
-        head_dim=q.shape[2], topk=topk, pages=tables.shape[-1],
-        positions_a_pass=block,
-    )
-    scores = paged_scores(q, w, plane, tables, t, page=page, block=block)
-    rows = plane_rows(_padded_tables(tables, block // page), page)
-    return select_top(scores, topk, rows)
+    shape = dict(queries=q.shape[0], heads=q.shape[1], head_dim=q.shape[2],
+                 topk=topk, pages=tables.shape[-1], positions_a_pass=block)
+    _check_pass(page, block)
+    refusal = _kernel_refusal(q, plane, tables, page=page, block=block)
+    padded = _padded_tables(tables, block // page)
+    if refusal:
+        record_dispatch(site, "xla_scan", refusal, **shape)
+        scores = paged_scores(q, w, plane, tables, t, page=page, block=block)
+    else:
+        from machine_learning_apache_spark_tpu.ops import pallas_dsa_index as k
+
+        n, total = q.shape[0], padded.shape[-1] * page
+        rows = k.rows_a_step(n)
+        vmem = k.vmem_bytes(rows, q.shape[1], q.shape[2], block, total,
+                            plane.dtype.itemsize)
+        record_dispatch(
+            site, "pallas_paged",
+            f"dsa_index_scan: passes of {block} positions ({block // page} "
+            f"pages of {page}), grid {n // rows} x {rows} rows, vmem "
+            f"{vmem / 2**20:.1f} MiB reckoned", **shape,
+        )
+        scores = k.scan_scores(q, w, plane, padded, t, page=page, block=block,
+                               interpret=_backend() != "tpu")
+    return select_top(scores, topk, plane_rows(padded, page))

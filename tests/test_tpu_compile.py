@@ -103,21 +103,24 @@ def test_gated_delta_chunk_kernels_compile(one_chip, monkeypatch, site):
         assert gone not in text, f"{gone} is in the compiled program"
 
 
-@pytest.mark.parametrize("program", ["launch", "prefill"])
-def test_served_language_model_programs_compile_in_place(one_chip, program):
-    """The decoder-only runtime's two hot programs at the benchmark's
-    published widths and engine sizes (``minicpm_sala_9b``): they fit the
-    chip, the page store is updated in place (no copy of a whole plane: the
-    first layout, ``[heads, pages, page, d]``, cost four such copies a layer
-    a step), and no softmax maximum became a row-wide ``reduce-window``."""
-    from benchmark import manifest, weights_sala_lm
-    from machine_learning_apache_spark_tpu.models import sala_lm
+def _served_program(one_chip, config: str, program: str):
+    """A served language model's hot program (``launch`` or ``prefill``) at
+    the benchmark configuration's published widths and engine sizes, lowered
+    for the described chip, and the configuration's engine sizes."""
+    import importlib
+
+    from benchmark import manifest
     from machine_learning_apache_spark_tpu.serving.lm_runtime import (
         LMDecodeRuntime,
     )
 
-    cfg = manifest.load_config(manifest.load_manifest(), "minicpm_sala_9b")
-    model, engine = weights_sala_lm.model_config(cfg), cfg["engine"]
+    kind = {"minicpm_sala_9b": "sala_lm", "deepseek_v32_exp": "dsa_lm"}[config]
+    weights = importlib.import_module(f"benchmark.weights_{kind}")
+    model_module = importlib.import_module(
+        f"machine_learning_apache_spark_tpu.models.{kind}"
+    )
+    cfg = manifest.load_config(manifest.load_manifest(), config)
+    model, engine = weights.model_config(cfg), cfg["engine"]
     runtime = object.__new__(LMDecodeRuntime)  # the programs, no planes
     runtime.cfg, runtime._donate = model, True
     runtime.steps_per_launch = engine["steps_per_launch"]
@@ -131,9 +134,9 @@ def test_served_language_model_programs_compile_in_place(one_chip, program):
             tree,
         )
 
-    params = described(jax.eval_shape(lambda: weights_sala_lm.make_params(1, cfg)))
+    params = described(jax.eval_shape(lambda: weights.make_params(1, cfg)))
     cache = described(jax.eval_shape(
-        lambda: sala_lm.new_cache(model, rows=rows, num_pages=engine["num_pages"])
+        lambda: model_module.new_cache(model, rows=rows, num_pages=engine["num_pages"])
     ))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
     flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=one_chip)  # noqa: E731
@@ -146,6 +149,17 @@ def test_served_language_model_programs_compile_in_place(one_chip, program):
         lowered = runtime._make_prefill().lower(
             params, cache, i32(chunk), i32(width), i32(), i32(), i32(), flag()
         )
+    return lowered, engine
+
+
+@pytest.mark.parametrize("program", ["launch", "prefill"])
+def test_served_language_model_programs_compile_in_place(one_chip, program):
+    """The decoder-only runtime's two hot programs at the benchmark's
+    published widths and engine sizes (``minicpm_sala_9b``): they fit the
+    chip, the page store is updated in place (no copy of a whole plane: the
+    first layout, ``[heads, pages, page, d]``, cost four such copies a layer
+    a step), and no softmax maximum became a row-wide ``reduce-window``."""
+    lowered, engine = _served_program(one_chip, "minicpm_sala_9b", program)
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
@@ -168,42 +182,9 @@ def test_served_latent_attention_programs_compile_in_place(one_chip, program):
     (no copy of a whole plane: latent rows of 576 lanes cost five 582 MB
     copies a launch, hence rows of 640), and the index scan forms no
     ``[queries, 64 heads, context]`` array (538 MB a layer in the launch)."""
-    from benchmark import manifest, weights_dsa_lm
-    from machine_learning_apache_spark_tpu.models import dsa_lm
-    from machine_learning_apache_spark_tpu.serving.lm_runtime import (
-        LMDecodeRuntime,
-    )
-
-    cfg = manifest.load_config(manifest.load_manifest(), "deepseek_v32_exp")
-    model, engine = weights_dsa_lm.model_config(cfg), cfg["engine"]
-    runtime = object.__new__(LMDecodeRuntime)  # the programs, no planes
-    runtime.cfg, runtime._donate = model, True
-    runtime.steps_per_launch = engine["steps_per_launch"]
-    runtime.max_new_tokens = engine["max_new_tokens"]
+    lowered, engine = _served_program(one_chip, "deepseek_v32_exp", program)
     rows, chunk = engine["max_active"], engine["prefill_chunk"]
     width = -(-engine["max_context"] // 64) + chunk // 64
-
-    def described(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            tree,
-        )
-
-    params = described(jax.eval_shape(lambda: weights_dsa_lm.make_params(1, cfg)))
-    cache = described(jax.eval_shape(
-        lambda: dsa_lm.new_cache(model, rows=rows, num_pages=engine["num_pages"])
-    ))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
-    flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=one_chip)  # noqa: E731
-    if program == "launch":
-        lowered = runtime._make_launch().lower(
-            params, cache, i32(rows), i32(rows), i32(rows), flag(rows),
-            i32(rows, width), flag(rows),
-        )
-    else:
-        lowered = runtime._make_prefill().lower(
-            params, cache, i32(chunk), i32(width), i32(), i32(), i32(), flag()
-        )
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
@@ -220,3 +201,42 @@ def test_served_latent_attention_programs_compile_in_place(one_chip, program):
     context = -(-width // 64) * 64 * 64  # the table in whole passes of 4,096
     assert f"f32[{queries},64,{context}]" not in text
     assert f"f32[{queries},64,4096]" in text  # one pass of the scan
+
+
+def test_the_decode_launch_scans_the_index_in_the_paged_kernel(one_chip, monkeypatch):
+    """The decode launch traced for the TPU (the backend here is the CPU,
+    so the test says which it is) takes ``dsa_index_scan`` at every layer,
+    fits the chip, still writes both planes in place, and gathers no pass
+    of every row's index keys (``bf16[2048,64,128]`` at 32 rows)."""
+    from machine_learning_apache_spark_tpu.ops import dsa_index
+
+    monkeypatch.setattr(dsa_index, "_backend", lambda: "tpu")
+    lowered, engine = _served_program(one_chip, "deepseek_v32_exp", "launch")
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+    text = compiled.as_text()
+    assert "%dsa_index_scan" in text
+    positions = engine["num_pages"] * 64
+    for plane in (f"bf16[{positions},640]", f"bf16[{positions},128]"):
+        assert not [
+            line for line in text.splitlines()
+            if " copy(" in line and f"= {plane}" in line
+        ], plane
+    rows, per_pass = engine["max_active"], 4096 // 64
+    assert f"bf16[{rows * per_pass},64,128]" not in text
+    assert f"f32[{rows},64,4096]" not in text
+
+
+@pytest.mark.parametrize("program", ["launch", "prefill"])
+def test_the_sala_programs_do_not_see_the_index_dispatch(one_chip, monkeypatch, program):
+    """``minicpm_sala_9b``'s two hot programs lower to the same text
+    whichever path the token indexer's dispatch would take: nothing of
+    ``ops.dsa_index`` reaches them."""
+    from machine_learning_apache_spark_tpu.ops import dsa_index
+
+    before = _served_program(one_chip, "minicpm_sala_9b", program)[0].as_text()
+    monkeypatch.setattr(dsa_index, "_backend", lambda: "tpu")
+    after = _served_program(one_chip, "minicpm_sala_9b", program)[0].as_text()
+    assert after == before
+    assert "dsa_index" not in after
