@@ -95,6 +95,13 @@ class HybridLMConfig:
         return (layer + 1) % self.full_attention_interval == 0
 
 
+# The block's two norms, the final norm and the residual adds: a scope of
+# their own at the block's level, apart from the mixers' and the expert
+# layer's scopes (``q_norm`` / ``k_norm`` and the gated norm sit under those),
+# so that no operation of a step is under two of them.
+RESIDUAL_NORM = "lm.residual_norm"
+
+
 def _rms(x, eps):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
@@ -259,11 +266,18 @@ class HybridBlock(nn.Module):
         )
 
     def mixer_half(self, x):
-        return x + self.mixer(self.input_norm(x))
+        with jax.named_scope(RESIDUAL_NORM):
+            normed = self.input_norm(x)
+        out = self.mixer(normed)
+        with jax.named_scope(RESIDUAL_NORM):
+            return x + out
 
     def expert_half(self, h):
-        out, stats = self.moe(self.post_norm(h))
-        return h + out, stats
+        with jax.named_scope(RESIDUAL_NORM):
+            normed = self.post_norm(h)
+        out, stats = self.moe(normed)
+        with jax.named_scope(RESIDUAL_NORM):
+            return h + out, stats
 
     def __call__(self, x):
         return self.expert_half(self.mixer_half(x))
@@ -302,7 +316,8 @@ class HybridLM(nn.Module):
             "lm_head", nn.initializers.lecun_normal(),
             (cfg.hidden_size, cfg.vocab_size),
         )
-        x = embedding[tokens].astype(cfg.dtype)
+        with jax.named_scope("lm.embed"):
+            x = embedding[tokens].astype(cfg.dtype)
         block_cls = hybrid_block_class(cfg)
         per_layer = []
         for i in range(cfg.num_layers):
@@ -318,7 +333,8 @@ class HybridLM(nn.Module):
             "assignments_local": jnp.sum(stacked["assignments_local"]),
             "assignments_computed": jnp.sum(stacked["assignments_computed"]),
         }
-        x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
+        with jax.named_scope(RESIDUAL_NORM):
+            x = RMSNorm(cfg.rms_eps, name="final_norm")(x)
         with jax.named_scope("lm.head_loss"):
             if labels is None:
                 logits = jnp.dot(

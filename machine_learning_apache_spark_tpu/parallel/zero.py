@@ -86,6 +86,7 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from machine_learning_apache_spark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from machine_learning_apache_spark_tpu.train.state import UPDATE_SCOPE
 from machine_learning_apache_spark_tpu.utils import env as envcfg
 
 # Environment contract (launcher gang plumbing: the driver sets these on
@@ -621,8 +622,9 @@ def make_zero1_step(
         ]
         p_shard = jnp.concatenate(p_pieces)
 
-        updates, new_opt = tx.update(g_shard, opt_state, p_shard)
-        new_p_shard = optax.apply_updates(p_shard, updates)
+        with jax.named_scope(UPDATE_SCOPE):
+            updates, new_opt = tx.update(g_shard, opt_state, p_shard)
+            new_p_shard = optax.apply_updates(p_shard, updates)
 
         # Allgather per bucket piece: tiled gather in device order
         # reconstructs each bucket segment contiguously.
@@ -704,8 +706,9 @@ def make_zero1_step(
                 ),
                 opt_state,
             )
-            updates_k, new_opt_k = tx.update(g_pieces[k], opt_k, p_piece)
-            new_piece = optax.apply_updates(p_piece, updates_k)
+            with jax.named_scope(UPDATE_SCOPE):
+                updates_k, new_opt_k = tx.update(g_pieces[k], opt_k, p_piece)
+                new_piece = optax.apply_updates(p_piece, updates_k)
             gathered.append(jax.lax.all_gather(new_piece, axis, tiled=True))
             new_opt_buckets.append(new_opt_k)
             shard_offset += piece_len
@@ -853,8 +856,9 @@ def _make_hybrid_step(
         flat_p = jax.lax.with_sharding_constraint(
             _flatten(zstate.params, plan, constrain=replicated), flat_sharding
         )
-        updates, new_opt = tx.update(flat_g, zstate.opt_state, flat_p)
-        new_flat = optax.apply_updates(flat_p, updates)
+        with jax.named_scope(UPDATE_SCOPE):
+            updates, new_opt = tx.update(flat_g, zstate.opt_state, flat_p)
+            new_flat = optax.apply_updates(flat_p, updates)
         new_flat = jax.lax.with_sharding_constraint(new_flat, replicated)
         new_params = jax.tree.map(
             jax.lax.with_sharding_constraint,

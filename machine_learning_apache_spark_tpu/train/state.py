@@ -19,6 +19,12 @@ import jax
 import optax
 from flax import struct
 
+#: The named scope of the optimizer's work (``tx.update`` and the applied
+#: updates) in every train step that updates parameters: this one and
+#: ``parallel.zero``'s sharded updates. A scope is metadata on the compiled
+#: instructions (the ``tf_op`` a device trace shows), not an instruction.
+UPDATE_SCOPE = "train.update"
+
 
 def make_schedule(
     learning_rate: float,
@@ -147,9 +153,10 @@ class TrainState(struct.PyTreeNode):
         )
 
     def apply_gradients(self, grads) -> "TrainState":
-        updates, new_opt = self.tx.update(grads, self.opt_state, self.params)
-        return self.replace(
-            step=self.step + 1,
-            params=optax.apply_updates(self.params, updates),
-            opt_state=new_opt,
-        )
+        with jax.named_scope(UPDATE_SCOPE):
+            updates, new_opt = self.tx.update(grads, self.opt_state, self.params)
+            return self.replace(
+                step=self.step + 1,
+                params=optax.apply_updates(self.params, updates),
+                opt_state=new_opt,
+            )

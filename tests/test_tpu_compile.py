@@ -8,6 +8,8 @@ test lives in this one file: only the worker that is given the file loads
 the TPU compiler.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -240,3 +242,81 @@ def test_the_sala_programs_do_not_see_the_index_dispatch(one_chip, monkeypatch, 
     after = _served_program(one_chip, "minicpm_sala_9b", program)[0].as_text()
     assert after == before
     assert "dsa_index" not in after
+
+
+def _q3next_period_step(one_chip):
+    """``q3next_train_full_4k``'s train step for the described chip, one
+    period (three Gated DeltaNet layers and a gated attention layer, the
+    experts behind them) at reduced widths and a row of 512 positions:
+    the scan's and the flash kernels' gates pass, as on the chip."""
+    from benchmark import manifest, weights_hybrid_lm
+    from benchmark.kinds import train_lm
+    from machine_learning_apache_spark_tpu.models.hybrid_lm import HybridLM
+    from machine_learning_apache_spark_tpu.ops.attention import attention_impl
+    from machine_learning_apache_spark_tpu.recipes import make_lm_loss
+    from machine_learning_apache_spark_tpu.train.loop import make_train_step
+    from machine_learning_apache_spark_tpu.train.state import (
+        TrainState,
+        make_optimizer,
+    )
+
+    cfg = manifest.load_config(manifest.load_manifest(), "qwen3_next_80b_a3b")
+    cfg.update(
+        vocab_size=512, hidden_size=256, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=128, linear_num_key_heads=1,
+        linear_num_value_heads=2, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, router_width=32,
+        experts_held=[0, 4], num_experts_per_tok=4,
+    )
+    model = HybridLM(train_lm.model_config(cfg))
+    opt = cfg["optimizer"]
+    tx = make_optimizer(opt["name"], opt["learning_rate"], b1=opt["b1"],
+                        b2=opt["b2"], eps=opt["eps"])
+    params = jax.eval_shape(lambda: weights_hybrid_lm.make_params(1, cfg))
+    state = jax.eval_shape(
+        lambda p: TrainState.create(apply_fn=model.apply, params=p, tx=tx), params
+    )
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), state
+    )
+    step = make_train_step(train_lm.with_step_verdict(make_lm_loss(model)))
+    with attention_impl("flash"):  # the backend here is the CPU
+        lowered = step.lower(
+            state, jax.ShapeDtypeStruct((1, 513), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        )
+    return lowered.compile().as_text()
+
+
+def _instructions(text: str) -> str:
+    """The computations of a compiled module without their metadata (the
+    ``op_name`` a scope writes, source lines), every instruction and
+    computation renamed by the order in which it first appears (XLA numbers
+    a name after the names it has met, which may be metadata's): the
+    instructions alone."""
+    text = text[min(text.index("\n%"), text.index("\nENTRY")):]
+    text = re.sub(r",? metadata=\{[^{}]*\}", "", text)
+    names = {}
+    return re.sub(
+        r"%[\w.\-]+",
+        lambda m: names.setdefault(m[0], f"%v{len(names)}"), text,
+    )
+
+
+def test_the_train_steps_scopes_compile_to_the_same_instructions(one_chip, monkeypatch):
+    """A named scope is metadata: the hybrid language model's train step
+    compiled with its scopes (the model's ``lm.*``, the optimizer's
+    ``train.update``) and with ``jax.named_scope`` a null context holds the
+    same instructions, fusions and schedule, and differs in ``op_name``
+    alone. So the scopes cost nothing when the step is not traced."""
+    import contextlib
+
+    monkeypatch.setattr(gated_delta, "_backend", lambda: "tpu")
+    scoped = _q3next_period_step(one_chip)
+    for scope in ("train.update", "lm.embed", "lm.residual_norm", "lm.gdn_scan"):
+        assert f"/{scope}/" in scoped, scope
+    assert "%gdn_chunk_fwd" in scoped and "%flash_fwd" in scoped
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    unscoped = _q3next_period_step(one_chip)
+    assert "lm.residual_norm" not in unscoped and "train.update" not in unscoped
+    assert _instructions(scoped) == _instructions(unscoped)
